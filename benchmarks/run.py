@@ -15,9 +15,10 @@
   sharded — dp x tp mesh cluster vs 1 device at equal cache/device
   spec    — speculative vs target-only decode (tok/step at equal bytes)
 
-``--devices N`` forces N host-platform devices; it must be applied
-before anything imports jax, so the benchmark modules are imported
-inside ``main`` after the flag is parsed.
+``--devices N`` forces N host-platform (CPU) devices, for the sharded
+section on a host without chips; it must be applied before anything
+imports jax, so the benchmark modules are imported inside ``main``
+after the flag is parsed.  Without it nothing is forced.
 """
 from __future__ import annotations
 
@@ -25,6 +26,7 @@ import argparse
 import time
 import traceback
 
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import ensure_host_devices
 
 
@@ -190,14 +192,17 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("only", nargs="?", default=None,
                     help="run just this section")
-    ap.add_argument("--devices", type=int, default=4,
+    ap.add_argument("--devices", type=int, default=None,
                     help="host-platform device count to force before jax "
-                         "initializes (the sharded section needs tp*dp)")
+                         "initializes (on CPU the sharded section needs "
+                         "tp*dp)")
     ap.add_argument("--tp", type=int, default=2)
     ap.add_argument("--dp", type=int, default=2)
     args = ap.parse_args()
     MESH["tp"], MESH["dp"] = args.tp, args.dp
-    ensure_host_devices(max(args.devices, args.tp * args.dp))
+    if args.devices is not None:
+        ensure_host_devices(max(args.devices, args.tp * args.dp))
+    use_compile_cache()
     failures = 0
     for name, fn in SECTIONS:
         if args.only and name != args.only:

@@ -17,19 +17,18 @@ execution — and the closed jaxpr is walked recursively:
   headroom over the measured count).  The budget catches quadratic
   trace blowups (an unrolled Python loop over layers or slots) long
   before they show up as compile-time regressions.
-* **donation applied** — the step is ``.lower().compile()``d under a
-  warnings trap; any "donated buffers were not usable" warning fails
-  the audit (the KV cache and SlotState must alias, not copy — the
-  same check ``core.jitutil.strict_jit`` enforces at runtime under
+* **donation applied** — the step is ``.lower().compile()``d and every
+  donated input must appear in the executable's input/output alias
+  table (the KV cache and SlotState must alias, not copy — the same
+  check ``core.jitutil.strict_jit`` enforces at runtime under
   ``REPRO_STRICT=1``).
 """
 from __future__ import annotations
 
-import warnings
 from typing import Any, Iterable
 
 from repro.analysis.census import MatrixPoint, _point_by_name, build_engine
-from repro.core.jitutil import _is_donation_warning, platform_donates
+from repro.core.jitutil import unaliased_donations
 
 # Primitives that re-enter Python from inside a traced computation.
 CALLBACK_PRIMITIVES = frozenset({
@@ -107,16 +106,11 @@ def audit_jaxpr(jaxpr, *, budget: int | None = None,
 
 
 def audit_donation(eng) -> list[str]:
-    """Compile the fused decode step and trap donation warnings."""
-    if not platform_donates():
-        return []
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        eng._decode.lower(eng.params, eng.cache, eng.state,
-                          eng.block_tables).compile()
-    bad = [str(w.message) for w in caught
-           if _is_donation_warning(w.message)]
-    return [f"decode: donation not applied: {m}" for m in bad]
+    """Compile the fused decode step and check its alias table."""
+    compiled = eng._decode.lower(eng.params, eng.cache, eng.state,
+                                 eng.block_tables).compile()
+    return [f"decode: donation not applied: {a}"
+            for a in unaliased_donations(compiled)]
 
 
 def audit_point(name: str, *, budget: int | None = None) -> list[str]:

@@ -14,17 +14,6 @@ import jax.numpy as jnp
 from jax import lax
 
 
-def axis_size(axis_name: str) -> int:
-    """Size of a mapped axis inside a shard_map/pmap region.
-
-    ``lax.axis_size`` only exists in newer JAX; ``psum(1, axis)`` is the
-    portable spelling (constant-folded to a concrete int at trace time).
-    """
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
 def _ring_perm(n: int) -> list[tuple[int, int]]:
     return [(i, (i + 1) % n) for i in range(n)]
 
@@ -39,7 +28,7 @@ def ring_reduce_scatter(x: jax.Array, axis_name: str) -> jax.Array:
     previous chunk can overlap the transfer of the next — the gradient
     analogue of the paper's load-weights-while-PEs-compute overlap.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x
     idx = lax.axis_index(axis_name)
@@ -76,7 +65,7 @@ def ring_allreduce(x: jax.Array, axis_name: str) -> jax.Array:
     overlap them with compute (the paper's 'load weights while the PEs
     compute' discipline, §3.6.1, applied to gradients).
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x
     flat = x.reshape(-1)

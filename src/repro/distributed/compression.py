@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.distributed.collectives import axis_size
-
 
 class EFState(NamedTuple):
     """Per-leaf error-feedback residual, shaped like the local grad shard."""
@@ -47,7 +45,7 @@ def compressed_allreduce(g: jax.Array, ef: EFState, axis_name: str,
     The EF residual has the shape of the local reduce-scatter shard
     (padded flat size / axis size).
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     flat = g.reshape(-1).astype(jnp.float32)
     pad = (-flat.size) % n
     if pad:

@@ -20,13 +20,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.distributed.collectives import axis_size
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
 def _shift_right(x: jax.Array, axis_name: str) -> jax.Array:
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     return lax.ppermute(x, axis_name, [(i, (i + 1) % n) for i in range(n)])
 
 
@@ -38,7 +36,7 @@ def pipeline_forward(stage_fn: Callable, stage_params, x: jax.Array, *,
     by shard_map).  x: [n_micro, mb, ...] microbatched input, replicated.
     Returns [n_micro, mb, ...] outputs of the *last* stage, replicated.
     """
-    n_stages = axis_size(axis_name)
+    n_stages = lax.axis_size(axis_name)
     stage_idx = lax.axis_index(axis_name)
     n_micro = x.shape[0]
     total = n_micro + n_stages - 1
@@ -82,12 +80,12 @@ def make_pipelined_apply(stage_fn: Callable, mesh: Mesh, *,
     apply: f(stacked_params [S, ...], x [n_micro, mb, ...]) -> outputs."""
     pspec = param_spec if param_spec is not None else P(axis_name)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(pipeline_forward, stage_fn, axis_name=axis_name),
         mesh=mesh,
         in_specs=(pspec, P()),   # pspec is a pytree-prefix for the params
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
 
     def apply(stacked_params, x):

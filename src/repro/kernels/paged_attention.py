@@ -61,12 +61,15 @@ def _paged_kernel(scale: float, bs: int, masked_heads: bool,
     k = k_ref[0, 0]                    # [bs, hdp] (one pool block)
     v = v_ref[0, 0]
     if quantized:
-        # dequant fused at the tile: one scale per block entry (row)
         q = q.astype(jnp.float32)
-        k = k.astype(jnp.float32) * ks_ref[0, 0][:, None]
-        v = v.astype(jnp.float32) * vs_ref[0, 0][:, None]
+        k = k.astype(jnp.float32)
+        v = v.astype(jnp.float32)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
+    if quantized:
+        # dequant fused at the tile: K's per-entry scale is a per-column
+        # factor of the scores ([1, bs] row broadcast over the queries)
+        s = s * ks_ref[0, 0]
     # token position of each column = logical block j * bs + offset; the
     # block table already routed us to the right *physical* block, so
     # only the live-length mask remains (null-block columns are >= len)
@@ -79,8 +82,11 @@ def _paged_kernel(scale: float, bs: int, masked_heads: bool,
     p = jnp.exp(s - m_new)
     l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     m_s[...] = m_new
+    # V's per-entry scale weights the probabilities of its column; the
+    # softmax denominator above sums the unscaled probabilities
+    pv = p * vs_ref[0, 0] if quantized else p
     acc[...] = acc[...] * alpha + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        pv.astype(v.dtype), v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
     @pl.when(j == pl.num_programs(2) - 1)
@@ -148,12 +154,10 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     if masked_heads:
         q_map = lambda b, g, j, bt, ln, lv: (b, g, 0, 0)
         kv_map = lambda b, g, j, bt, ln, lv: (bt[b, j], g, 0, 0)
-        sc_map = lambda b, g, j, bt, ln, lv: (bt[b, j], g, 0)
         prefetch = (block_tables, lengths, live_kv)
     else:
         q_map = lambda b, g, j, bt, ln: (b, g, 0, 0)
         kv_map = lambda b, g, j, bt, ln: (bt[b, j], g, 0, 0)
-        sc_map = lambda b, g, j, bt, ln: (bt[b, j], g, 0)
         prefetch = (block_tables, lengths)
     in_specs = [
         pl.BlockSpec((1, 1, R, hdp), q_map),
@@ -162,11 +166,13 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     ]
     operands = [qg, kp, vp]
     if quantized:
-        # scales in the same kv-major layout as the pool; the BlockSpec
-        # rides the identical scalar-prefetched table walk
-        in_specs += [pl.BlockSpec((1, 1, bs), sc_map),
-                     pl.BlockSpec((1, 1, bs), sc_map)]
-        operands += [k_scale.swapaxes(1, 2), v_scale.swapaxes(1, 2)]
+        # scales kv-major as [NB, kv, 1, bs]: a (1, bs) tile spans both
+        # trailing dims whole, which Mosaic accepts at any block size, and
+        # it rides the pool's own block-table index map
+        in_specs += [pl.BlockSpec((1, 1, 1, bs), kv_map),
+                     pl.BlockSpec((1, 1, 1, bs), kv_map)]
+        operands += [k_scale.swapaxes(1, 2)[:, :, None],
+                     v_scale.swapaxes(1, 2)[:, :, None]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(B, kv, nblk),

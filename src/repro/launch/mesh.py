@@ -61,16 +61,18 @@ def ensure_host_devices(n: int, *, allow_oversubscribe: bool = True) -> int:
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     import jax
+    from jax.sharding import AxisType
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes))
 
 
 def make_dev_mesh():
     """Whatever this process actually has (CPU smoke / examples)."""
     import jax
+    from jax.sharding import AxisType
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return jax.make_mesh((n, 1), ("data", "model"), (AxisType.Auto,) * 2)
 
 
 def mesh_device_count(mesh) -> int:

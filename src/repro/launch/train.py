@@ -14,6 +14,7 @@ import jax
 from repro.configs import REGISTRY, reduced
 from repro.data.pipeline import SyntheticLMStream
 from repro.distributed import sharding as shd
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_dev_mesh
 from repro.models.model import Model, ModelOptions
 from repro.training import checkpoint as ckpt
@@ -37,6 +38,7 @@ def main() -> None:
                     help="use the full (not reduced) architecture config")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = REGISTRY[args.arch]
     if not args.full:

@@ -74,6 +74,7 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.distributed import sharding as shd
@@ -1494,7 +1495,10 @@ class ServingEngine:
 
     def _dispatch(self) -> None:
         if self.paging is not None and self._tables_dirty:
-            self.block_tables = jnp.asarray(self._tables, jnp.int32)
+            # host-built step inputs go straight to this replica's
+            # placement, like every other operand of the step
+            self.block_tables = jax.device_put(
+                np.asarray(self._tables, np.int32), self._placement)
             self._tables_dirty = False
         if self.scheduler == "chunked":
             grants = self._grant_chunks()
@@ -1510,7 +1514,8 @@ class ServingEngine:
             if granted:
                 cache, self.state = self._step(
                     params, cache, self.state, self.block_tables,
-                    jnp.asarray(grants, jnp.int32))
+                    jax.device_put(np.asarray(grants, np.int32),
+                                   self._placement))
             else:
                 # steady state (no prompt work anywhere): the one-lane
                 # fused decode is the W == 1 special case of the mixed

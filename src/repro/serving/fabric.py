@@ -513,8 +513,8 @@ class DecodeFabric:
     def decode_step(self, table: dict, cache: KVCache, tokens: jax.Array,
                     index: jax.Array, topo: jax.Array,
                     block_tables: jax.Array | None = None,
-                    paged_attn_impl: str = "gather",
-                    interpret: bool = True) -> tuple[jax.Array, KVCache]:
+                    paged_attn_impl: str = "gather", *,
+                    interpret: bool) -> tuple[jax.Array, KVCache]:
         """tokens [B, 1] + per-slot registers topo [B, N_REGS] -> (masked
         logits [B, 1, V_max], new cache).  One topology per slot; register
         values are data, so this traces exactly once."""
@@ -590,8 +590,8 @@ class DecodeFabric:
     def mixed_step(self, table: dict, cache: KVCache, tokens: jax.Array,
                    start: jax.Array, n_live: jax.Array, topo: jax.Array,
                    block_tables: jax.Array | None = None,
-                   paged_attn_impl: str = "gather",
-                   interpret: bool = True) -> tuple[jax.Array, KVCache]:
+                   paged_attn_impl: str = "gather", *,
+                   interpret: bool) -> tuple[jax.Array, KVCache]:
         """tokens [B, W] + per-slot registers topo [B, N_REGS] -> (masked
         logits [B, W, V_max], new cache).
 

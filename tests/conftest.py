@@ -6,9 +6,9 @@ import os
 # are unaffected (unsharded jit still runs on device 0).
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
-# The whole suite runs with strict donation: any "donated buffers were
-# not usable" warning from a strict_jit site raises instead of silently
-# doubling cache/optimizer memory (core.jitutil).
+# The whole suite runs with strict donation: a strict_jit site whose
+# compiled program does not alias a donated buffer raises instead of
+# silently doubling cache/optimizer memory (core.jitutil).
 os.environ.setdefault("REPRO_STRICT", "1")
 
 import jax  # noqa: E402

@@ -222,16 +222,7 @@ def test_mid_prefill_preemption_dense_forced(params):
 # ---------------------------------------------------------------------------
 # Donation: the fused step updates cache + SlotState in place
 # ---------------------------------------------------------------------------
-def _platform_donates() -> bool:
-    x = jnp.arange(16, dtype=jnp.float32)
-    f = jax.jit(lambda a: a + 1.0, donate_argnums=(0,))
-    p = x.unsafe_buffer_pointer()
-    return f(x).unsafe_buffer_pointer() == p
-
-
 def test_fused_step_donates_cache_and_state(params):
-    if not _platform_donates():
-        pytest.skip("backend does not alias donated buffers")
     eng = _engine(params, "chunked")
     eng.submit([1, 2, 3], max_new_tokens=10)
     eng.step()   # admission + compile

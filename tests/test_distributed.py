@@ -89,7 +89,8 @@ import json
 import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
+from jax.sharding import AxisType
 
 B, S = int(sys.argv[1]), int(sys.argv[2])
 train_only = len(sys.argv) > 3 and sys.argv[3] == "train_only"
@@ -113,7 +114,7 @@ batch = {"tokens": toks[:, :-1], "targets": toks[:, 1:]}
 single = jax.jit(make_step_fn(model, TrainStepConfig(optimizer=oc)))
 s1, m1 = single(state, batch)
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"), (AxisType.Auto,) * 2)
 strategy = shd.strategy_for_mesh(mesh)
 specs = {k: jax.ShapeDtypeStruct(v.shape, v.dtype) for k, v in batch.items()}
 jitted, st_sh, b_sh = make_train_step(model, mesh, strategy,
@@ -133,7 +134,7 @@ if train_only:
 
 # --- 2. ring collectives == native psum ------------------------------------
 from repro.distributed.collectives import ring_allreduce, ring_reduce_scatter
-m8 = jax.make_mesh((8,), ("d",))
+m8 = jax.make_mesh((8,), ("d",), (AxisType.Auto,))
 x = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
 f = shard_map(lambda xs: ring_reduce_scatter(xs[0], "d")[None],
               mesh=m8, in_specs=(P("d", None),), out_specs=P("d", None))
@@ -145,7 +146,7 @@ results["ring_ar_err"] = float(jnp.max(jnp.abs(
 
 # --- 3. pipeline forward/grad == sequential ---------------------------------
 from repro.distributed.pipeline import make_pipelined_apply
-mesh_pp = jax.make_mesh((8,), ("stage",))
+mesh_pp = jax.make_mesh((8,), ("stage",), (AxisType.Auto,))
 S, D, NM, MB = 8, 16, 16, 4
 ws = jax.random.normal(jax.random.PRNGKey(0), (S, D, D)) / jnp.sqrt(D)
 bs = jax.random.normal(jax.random.PRNGKey(1), (S, D)) * 0.1
@@ -179,7 +180,7 @@ def one_round(g, resid):
             *compressed_allreduce(gg[0], init_ef_state((shard,))._replace(
                 residual=rr[0]), "d")),
         mesh=m8, in_specs=(P("d", None), P("d", None)),
-        out_specs=(P("d", None), P("d", None)), check_rep=False)
+        out_specs=(P("d", None), P("d", None)), check_vma=False)
     return f(g, resid)
 
 resid = jnp.zeros((8, shard))

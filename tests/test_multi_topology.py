@@ -17,6 +17,7 @@ import pytest
 
 from conftest import reduced_cfg
 from repro.core.spec import MemorySpec, RuntimeSpec, maxima_for
+from repro.kernels.runtime import interpret_default
 from repro.models.model import Model
 from repro.serving.engine import ServingEngine
 from repro.serving.fabric import DecodeFabric
@@ -155,7 +156,8 @@ def test_fabric_matches_zoo_model_numerically(cfg, seed):
         t = jnp.asarray([[tok]], jnp.int32)
         lg_f, cache_f = fab.decode_step(table, cache_f, t,
                                         jnp.asarray([idx], jnp.int32),
-                                        topo[None])
+                                        topo[None],
+                                        interpret=interpret_default())
         lg_m, cache_m = model.decode_step(params, cache_m, t, jnp.int32(idx))
         np.testing.assert_allclose(np.asarray(lg_f[:, :, :v]),
                                    np.asarray(lg_m), atol=5e-2, rtol=5e-2)
