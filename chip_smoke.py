@@ -148,6 +148,7 @@ def serve_phase(name: str, spec, params, prompts, meter, device) -> None:
           f"prefill_compilations={comp['prefill']} "
           f"finished={len(done)}/{len(uids)} "
           f"tokens={sum(lens.values())} "
+          f"fused_steps={eng.stats['decode_steps']} "
           f"tpu_custom_calls={kernels} "
           f"peak_bytes_in_use={_peak_bytes(device)} "
           f"smoke_wall_s={wall:.2f} (compile included; not a benchmark)",

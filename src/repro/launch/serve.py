@@ -1,9 +1,9 @@
 """Serving driver: ``python -m repro.launch.serve --arch <id> [...]``.
 
 Builds one ``core.spec.RuntimeSpec`` from the CLI flags (the single
-configuration surface), spins up the serving engine on a reduced config
-(``--full``: the published one), submits a demo request mix, and reports
-tokens/s + the compile-once accounting.
+configuration surface), spins up the serving engine on a reduced config,
+submits a demo request mix, and reports tokens/s + the compile-once
+accounting.
 
 Multi-topology mode: ``--fleet qwen1.5-0.5b,codeqwen1.5-7b`` serves
 several architectures from ONE compiled decode step — shared maxima are
@@ -29,9 +29,6 @@ from repro.launch.mesh import ensure_host_devices
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b")
-    ap.add_argument("--full", action="store_true",
-                    help="serve the published (not reduced) architecture "
-                         "config")
     ap.add_argument("--devices", type=int, default=None,
                     help="force this many host-platform devices (must be "
                          "set before jax initializes — which is why every "
@@ -125,8 +122,7 @@ def main() -> None:
     from repro.serving.sampling import SamplingParams
 
     names = (args.fleet.split(",") if args.fleet else [args.arch])
-    shape = (lambda c: c) if args.full else reduced
-    cfgs = [shape(REGISTRY[n]) for n in names]
+    cfgs = [reduced(REGISTRY[n]) for n in names]
     maxima = (maxima_for(*cfgs, seq_max=args.max_len)
               if args.fleet else None)
     # string dtype names flow straight into the spec — ExecutionSpec
@@ -160,7 +156,7 @@ def main() -> None:
         speculation = draft_cfg = None
         if args.spec_k:
             from repro.core.spec import SpeculationSpec
-            draft_cfg = (shape(REGISTRY[args.draft]) if args.draft
+            draft_cfg = (reduced(REGISTRY[args.draft]) if args.draft
                          else cfgs[0])
             # a temperature > 0 demo mix needs the rejection-sampling
             # accept path; greedy runs take the exact argmax-match path

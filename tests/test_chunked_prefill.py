@@ -342,6 +342,47 @@ def test_chunked_kernel_matches_gather_reference():
                                   np.asarray(out[0, :, :n_rep]))
 
 
+def test_chunked_kernel_int8_pool_matches_dequantized_reference():
+    """int8 pool + per-(entry, kv-head) scales: the kernel's fused dequant
+    equals chunk attention over the dequantized pool, and null-block
+    entries past the live span, poisoned scales included, contribute
+    nothing."""
+    from repro.core.kv_quant import CacheCodec
+    from repro.core.paging import NULL_BLOCK
+    from repro.kernels.chunked_prefill import chunked_prefill_attention
+    codec = CacheCodec("int8")
+    B, W, h, kv, hd = 3, 4, 8, 2, 16
+    bs, nblk, NB = 8, 8, 12
+    ks = jax.random.split(jax.random.PRNGKey(1), 6)
+    q = jax.random.normal(ks[0], (B, W, h, hd), jnp.float32)
+    # rows of very different magnitude, so a dropped or misrouted scale
+    # shows up far above the tolerance
+    mag = jnp.exp(jax.random.normal(ks[4], (NB, bs, kv, 1)))
+    kq, k_s = codec.encode(jax.random.normal(ks[1], (NB, bs, kv, hd)) * mag)
+    vq, v_s = codec.encode(jax.random.normal(ks[2], (NB, bs, kv, hd)) * mag)
+    kq, vq = kq.at[NULL_BLOCK].set(127), vq.at[NULL_BLOCK].set(127)
+    k_s, v_s = k_s.at[NULL_BLOCK].set(1e4), v_s.at[NULL_BLOCK].set(1e4)
+    bt = jax.random.randint(ks[3], (B, nblk), 1, NB).astype(jnp.int32)
+    bt = bt.at[1, 2:].set(NULL_BLOCK)       # slot 1 spans two blocks
+    start = jnp.asarray([5, 9, 20], jnp.int32)
+    out = chunked_prefill_attention(q, kq, vq, bt, start, k_scale=k_s,
+                                    v_scale=v_s, interpret=True)
+
+    t_max = nblk * bs
+    n_rep = h // kv
+    kd = codec.decode(kq, k_s, jnp.float32)
+    vd = codec.decode(vq, v_s, jnp.float32)
+    kf = jnp.repeat(kd[bt].reshape(B, t_max, kv, hd), n_rep, axis=2)
+    vf = jnp.repeat(vd[bt].reshape(B, t_max, kv, hd), n_rep, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, kf) / np.sqrt(hd)
+    qpos = start[:, None] + jnp.arange(W)[None]
+    mask = jnp.arange(t_max)[None, None, :] <= qpos[:, :, None]
+    s = jnp.where(mask[:, None], s, -1e30)
+    ref = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), vf)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+
+
 # ---------------------------------------------------------------------------
 # Prefix cache x chunked prefill (PR 7)
 # ---------------------------------------------------------------------------

@@ -119,6 +119,37 @@ def test_paged_kernel_ignores_null_block_entries():
     assert bool(jnp.all(jnp.abs(out) < 1e3))
 
 
+@pytest.mark.parametrize("h,kv", [(4, 4), (8, 2)])
+def test_paged_kernel_int8_pool_matches_dequantized_reference(h, kv):
+    """int8 pool + per-(entry, kv-head) scales: the kernel's fused dequant
+    (K scale on the score columns, V scale on the probabilities) equals
+    attention over the dequantized pool; a poisoned null block, scales
+    included, contributes nothing."""
+    from repro.core.kv_quant import CacheCodec
+    codec = CacheCodec("int8")
+    rng = np.random.RandomState(2)
+    B, hd, bs, nblk = 3, 16, 8, 4
+    NB = 1 + B * nblk
+    q = jnp.asarray(rng.randn(B, h, hd), jnp.float32)
+    # rows of very different magnitude, so a dropped or misrouted scale
+    # shows up far above the tolerance
+    mag = jnp.asarray(np.exp(rng.randn(NB, bs, kv, 1)), jnp.float32)
+    kq, ks = codec.encode(jnp.asarray(rng.randn(NB, bs, kv, hd)) * mag)
+    vq, vs = codec.encode(jnp.asarray(rng.randn(NB, bs, kv, hd)) * mag)
+    kq, vq = kq.at[NULL_BLOCK].set(127), vq.at[NULL_BLOCK].set(127)
+    ks, vs = ks.at[NULL_BLOCK].set(1e4), vs.at[NULL_BLOCK].set(1e4)
+    perm = rng.permutation(np.arange(1, NB)).reshape(B, nblk)
+    perm[0, 2:] = NULL_BLOCK                # slot 0 holds two blocks
+    tables = jnp.asarray(perm, jnp.int32)
+    lengths = jnp.asarray([11, 17, nblk * bs], jnp.int32)
+    out = paged_decode_attention(q, kq, vq, tables, lengths,
+                                 k_scale=ks, v_scale=vs, interpret=True)
+    ref = _reference(q, codec.decode(kq, ks, jnp.float32),
+                     codec.decode(vq, vs, jnp.float32), tables, lengths)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+
+
 # ---------------------------------------------------------------------------
 # Model-level cache-layout interface
 # ---------------------------------------------------------------------------
