@@ -1,0 +1,7 @@
+"""Device ms per step-program execution under the paged KV pool's
+scopes (``attn.kv_write``, ``attn.kv_gather``), self time."""
+from bench.program_trace import KV_POOL, scope_ms
+
+
+def read(rec):
+    return scope_ms(getattr(rec, "program_trace", None), KV_POOL)

@@ -1,0 +1,7 @@
+"""Device ms per step-program execution under the weight matmuls'
+scopes (``attn.qkv``, ``attn.out``, ``ffn``, ``head``), self time."""
+from bench.program_trace import MATMUL, scope_ms
+
+
+def read(rec):
+    return scope_ms(getattr(rec, "program_trace", None), MATMUL)

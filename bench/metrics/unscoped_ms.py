@@ -1,0 +1,7 @@
+"""Device ms per step-program execution in ops under no named scope,
+self time."""
+from bench.program_trace import unscoped_ms
+
+
+def read(rec):
+    return unscoped_ms(getattr(rec, "program_trace", None))

@@ -198,6 +198,8 @@ class _ReqState:
 def reduce_events(events: list[EngineEvent],
                   slo: SLO | None = None) -> HarnessMetrics:
     """Scan an event stream (in emission order) into :class:`HarnessMetrics`."""
+    # dispatch events belong to no request (serving.events)
+    events = [e for e in events if e.kind != "dispatch"]
     if not events:
         raise ValueError("reduce_events needs a non-empty event stream")
     reqs: dict[int, _ReqState] = {}

@@ -184,6 +184,7 @@ def chunked_prefill_attention(q: jax.Array, k_pool: jax.Array,
         out_shape=jax.ShapeDtypeStruct((B, kv, W, R, hdp),
                                        jnp.float32 if quantized else q.dtype),
         interpret=interpret,
+        name="chunked_prefill_attention",
     )(*prefetch, *operands)
     return out[:, :, :, :n_rep, :hd].transpose(0, 2, 1, 3, 4) \
         .reshape(B, W, h, hd).astype(q.dtype)

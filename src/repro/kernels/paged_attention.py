@@ -188,6 +188,7 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         out_shape=jax.ShapeDtypeStruct((B, kv, R, hdp),
                                        jnp.float32 if quantized else q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(*prefetch, *operands)
     return out[:, :, :n_rep, :hd].reshape(B, h, hd).astype(q.dtype)
 

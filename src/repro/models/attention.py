@@ -403,29 +403,36 @@ def gqa_decode_paged(x: jax.Array, p: dict, cfg: ArchConfig, cache: KVCache,
     kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
     bs = cache.k.shape[1]
     idx_vec = as_index_vector(cache_index, b_)
-    q, k_new, v_new = gqa_qkv(x, p, cfg, idx_vec[:, None])
-    blk, off = paged_write_slot(idx_vec, block_tables, bs)
-    kq, ks = codec.store(k_new[:, 0], cache.k.dtype)
-    vq, vs = codec.store(v_new[:, 0], cache.v.dtype)
-    k, k_sc = cache_put(cache.k, cache.k_scale, (blk, off), kq, ks)
-    v, v_sc = cache_put(cache.v, cache.v_scale, (blk, off), vq, vs)
+    with jax.named_scope("attn.qkv"):
+        q, k_new, v_new = gqa_qkv(x, p, cfg, idx_vec[:, None])
+    with jax.named_scope("attn.kv_write"):
+        blk, off = paged_write_slot(idx_vec, block_tables, bs)
+        kq, ks = codec.store(k_new[:, 0], cache.k.dtype)
+        vq, vs = codec.store(v_new[:, 0], cache.v.dtype)
+        k, k_sc = cache_put(cache.k, cache.k_scale, (blk, off), kq, ks)
+        v, v_sc = cache_put(cache.v, cache.v_scale, (blk, off), vq, vs)
     t_max = block_tables.shape[1] * bs
     if impl == "pallas":
         from repro.kernels.paged_attention import paged_decode_attention
-        lengths = jnp.minimum(idx_vec + 1, t_max)
-        o = paged_decode_attention(
-            q[:, 0], k, v, block_tables, lengths,
-            k_scale=k_sc, v_scale=v_sc,
-            interpret=interpret_default())
-        o = o.reshape(b_, one, cfg.num_heads * hd)
+        with jax.named_scope("attn.core"):
+            lengths = jnp.minimum(idx_vec + 1, t_max)
+            o = paged_decode_attention(
+                q[:, 0], k, v, block_tables, lengths,
+                k_scale=k_sc, v_scale=v_sc,
+                interpret=interpret_default())
+            o = o.reshape(b_, one, cfg.num_heads * hd)
     else:
-        kg = gather_view(codec, k, k_sc, block_tables,
-                          (b_, t_max, kv, hd), x.dtype)
-        vg = gather_view(codec, v, v_sc, block_tables,
-                          (b_, t_max, kv, hd), x.dtype)
-        live = jnp.arange(t_max)[None, :] <= idx_vec[:, None]
-        o = _gqa_attend(q, kg, vg, live, cfg, grouped)
-    return apply_dense(o, p["wo"]), KVCache(k, v, k_sc, v_sc)
+        with jax.named_scope("attn.kv_gather"):
+            kg = gather_view(codec, k, k_sc, block_tables,
+                              (b_, t_max, kv, hd), x.dtype)
+            vg = gather_view(codec, v, v_sc, block_tables,
+                              (b_, t_max, kv, hd), x.dtype)
+        with jax.named_scope("attn.core"):
+            live = jnp.arange(t_max)[None, :] <= idx_vec[:, None]
+            o = _gqa_attend(q, kg, vg, live, cfg, grouped)
+    with jax.named_scope("attn.out"):
+        o = apply_dense(o, p["wo"])
+    return o, KVCache(k, v, k_sc, v_sc)
 
 
 # ---------------------------------------------------------------------------
@@ -486,31 +493,38 @@ def gqa_mixed_paged(x: jax.Array, p: dict, cfg: ArchConfig, cache: KVCache,
     kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
     bs = cache.k.shape[1]
     positions = start[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]
-    q, k_new, v_new = gqa_qkv(x, p, cfg, positions)
+    with jax.named_scope("attn.qkv"):
+        q, k_new, v_new = gqa_qkv(x, p, cfg, positions)
     t_max = block_tables.shape[1] * bs
-    # dead lanes -> index t_max -> the null block absorbs them
-    idx_w = jnp.where(masking.lane_mask(w, n_live), positions, t_max)
-    blk, off = paged_write_slot(idx_w, block_tables, bs)
-    kq, ks = codec.store(k_new, cache.k.dtype)
-    vq, vs = codec.store(v_new, cache.v.dtype)
-    k, k_sc = cache_put(cache.k, cache.k_scale, (blk, off), kq, ks)
-    v, v_sc = cache_put(cache.v, cache.v_scale, (blk, off), vq, vs)
+    with jax.named_scope("attn.kv_write"):
+        # dead lanes -> index t_max -> the null block absorbs them
+        idx_w = jnp.where(masking.lane_mask(w, n_live), positions, t_max)
+        blk, off = paged_write_slot(idx_w, block_tables, bs)
+        kq, ks = codec.store(k_new, cache.k.dtype)
+        vq, vs = codec.store(v_new, cache.v.dtype)
+        k, k_sc = cache_put(cache.k, cache.k_scale, (blk, off), kq, ks)
+        v, v_sc = cache_put(cache.v, cache.v_scale, (blk, off), vq, vs)
     if impl == "pallas":
         from repro.kernels.chunked_prefill import chunked_prefill_attention
         if interpret is None:
             interpret = interpret_default()
-        o = chunked_prefill_attention(q, k, v, block_tables, start,
-                                      k_scale=k_sc, v_scale=v_sc,
-                                      interpret=interpret)
-        o = o.reshape(b_, w, cfg.num_heads * hd)
+        with jax.named_scope("attn.core"):
+            o = chunked_prefill_attention(q, k, v, block_tables, start,
+                                          k_scale=k_sc, v_scale=v_sc,
+                                          interpret=interpret)
+            o = o.reshape(b_, w, cfg.num_heads * hd)
     else:
-        kg = gather_view(codec, k, k_sc, block_tables,
-                          (b_, t_max, kv, hd), x.dtype)
-        vg = gather_view(codec, v, v_sc, block_tables,
-                          (b_, t_max, kv, hd), x.dtype)
-        live = masking.chunk_causal_mask(t_max, start, w)
-        o = _gqa_attend(q, kg, vg, live, cfg, grouped)
-    return apply_dense(o, p["wo"]), KVCache(k, v, k_sc, v_sc)
+        with jax.named_scope("attn.kv_gather"):
+            kg = gather_view(codec, k, k_sc, block_tables,
+                              (b_, t_max, kv, hd), x.dtype)
+            vg = gather_view(codec, v, v_sc, block_tables,
+                              (b_, t_max, kv, hd), x.dtype)
+        with jax.named_scope("attn.core"):
+            live = masking.chunk_causal_mask(t_max, start, w)
+            o = _gqa_attend(q, kg, vg, live, cfg, grouped)
+    with jax.named_scope("attn.out"):
+        o = apply_dense(o, p["wo"])
+    return o, KVCache(k, v, k_sc, v_sc)
 
 
 # ---------------------------------------------------------------------------
@@ -607,7 +621,8 @@ def _mla_attend(x: jax.Array, p: dict, cfg: ArchConfig, q_nope: jax.Array,
     wv = jnp.transpose(p["v_up"]["kernel"].reshape(m.kv_lora_rank, h, m.v_head_dim),
                        (1, 0, 2)).astype(x.dtype)
     o = jnp.einsum("bqhl,hld->bqhd", o_lat, wv)
-    return apply_dense(o.reshape(b_, one, h * m.v_head_dim), p["wo"])
+    with jax.named_scope("attn.out"):
+        return apply_dense(o.reshape(b_, one, h * m.v_head_dim), p["wo"])
 
 
 def mla_decode(x: jax.Array, p: dict, cfg: ArchConfig, cache: MLACache,
@@ -649,21 +664,26 @@ def mla_decode_paged(x: jax.Array, p: dict, cfg: ArchConfig, cache: MLACache,
     bs = cache.c_kv.shape[1]
     idx_vec = as_index_vector(cache_index, b_)
     positions = idx_vec[:, None]
-    q_nope, q_rope = _mla_q(x, p, m, h, positions, cfg.rope_theta)
-    c_new, kr_new = _mla_latent(x, p, m, positions, cfg.rope_theta)
-    blk, off = paged_write_slot(idx_vec, block_tables, bs)
-    cq, cs = codec.store(c_new[:, 0], cache.c_kv.dtype)
-    rq, rs = codec.store(kr_new[:, 0], cache.k_rope.dtype)
-    c_kv, c_sc = cache_put(cache.c_kv, cache.c_scale, (blk, off), cq, cs)
-    k_rope, r_sc = cache_put(cache.k_rope, cache.r_scale, (blk, off),
-                              rq, rs)
+    with jax.named_scope("attn.qkv"):
+        q_nope, q_rope = _mla_q(x, p, m, h, positions, cfg.rope_theta)
+        c_new, kr_new = _mla_latent(x, p, m, positions, cfg.rope_theta)
+    with jax.named_scope("attn.kv_write"):
+        blk, off = paged_write_slot(idx_vec, block_tables, bs)
+        cq, cs = codec.store(c_new[:, 0], cache.c_kv.dtype)
+        rq, rs = codec.store(kr_new[:, 0], cache.k_rope.dtype)
+        c_kv, c_sc = cache_put(cache.c_kv, cache.c_scale, (blk, off),
+                                cq, cs)
+        k_rope, r_sc = cache_put(cache.k_rope, cache.r_scale, (blk, off),
+                                  rq, rs)
     t_max = block_tables.shape[1] * bs
-    ckv_g = gather_view(codec, c_kv, c_sc, block_tables,
-                         (b_, t_max, m.kv_lora_rank), x.dtype)
-    kr_g = gather_view(codec, k_rope, r_sc, block_tables,
-                        (b_, t_max, m.qk_rope_head_dim), x.dtype)
-    live = (jnp.arange(t_max)[None] <= idx_vec[:, None])[:, None, None, :]
-    out = _mla_attend(x, p, cfg, q_nope, q_rope, ckv_g, kr_g, live)
+    with jax.named_scope("attn.kv_gather"):
+        ckv_g = gather_view(codec, c_kv, c_sc, block_tables,
+                             (b_, t_max, m.kv_lora_rank), x.dtype)
+        kr_g = gather_view(codec, k_rope, r_sc, block_tables,
+                            (b_, t_max, m.qk_rope_head_dim), x.dtype)
+    with jax.named_scope("attn.core"):   # attn.out inside
+        live = (jnp.arange(t_max)[None] <= idx_vec[:, None])[:, None, None, :]
+        out = _mla_attend(x, p, cfg, q_nope, q_rope, ckv_g, kr_g, live)
     return out, MLACache(c_kv, k_rope, c_sc, r_sc)
 
 
@@ -705,20 +725,25 @@ def mla_mixed_paged(x: jax.Array, p: dict, cfg: ArchConfig, cache: MLACache,
     b_, w, _ = x.shape
     bs = cache.c_kv.shape[1]
     positions = start[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]
-    q_nope, q_rope = _mla_q(x, p, m, h, positions, cfg.rope_theta)
-    c_new, kr_new = _mla_latent(x, p, m, positions, cfg.rope_theta)
+    with jax.named_scope("attn.qkv"):
+        q_nope, q_rope = _mla_q(x, p, m, h, positions, cfg.rope_theta)
+        c_new, kr_new = _mla_latent(x, p, m, positions, cfg.rope_theta)
     t_max = block_tables.shape[1] * bs
-    idx_w = jnp.where(masking.lane_mask(w, n_live), positions, t_max)
-    blk, off = paged_write_slot(idx_w, block_tables, bs)
-    cq, cs = codec.store(c_new, cache.c_kv.dtype)
-    rq, rs = codec.store(kr_new, cache.k_rope.dtype)
-    c_kv, c_sc = cache_put(cache.c_kv, cache.c_scale, (blk, off), cq, cs)
-    k_rope, r_sc = cache_put(cache.k_rope, cache.r_scale, (blk, off),
-                              rq, rs)
-    ckv_g = gather_view(codec, c_kv, c_sc, block_tables,
-                         (b_, t_max, m.kv_lora_rank), x.dtype)
-    kr_g = gather_view(codec, k_rope, r_sc, block_tables,
-                        (b_, t_max, m.qk_rope_head_dim), x.dtype)
-    live = masking.chunk_causal_mask(t_max, start, w)[:, None]
-    out = _mla_attend(x, p, cfg, q_nope, q_rope, ckv_g, kr_g, live)
+    with jax.named_scope("attn.kv_write"):
+        idx_w = jnp.where(masking.lane_mask(w, n_live), positions, t_max)
+        blk, off = paged_write_slot(idx_w, block_tables, bs)
+        cq, cs = codec.store(c_new, cache.c_kv.dtype)
+        rq, rs = codec.store(kr_new, cache.k_rope.dtype)
+        c_kv, c_sc = cache_put(cache.c_kv, cache.c_scale, (blk, off),
+                                cq, cs)
+        k_rope, r_sc = cache_put(cache.k_rope, cache.r_scale, (blk, off),
+                                  rq, rs)
+    with jax.named_scope("attn.kv_gather"):
+        ckv_g = gather_view(codec, c_kv, c_sc, block_tables,
+                             (b_, t_max, m.kv_lora_rank), x.dtype)
+        kr_g = gather_view(codec, k_rope, r_sc, block_tables,
+                            (b_, t_max, m.qk_rope_head_dim), x.dtype)
+    with jax.named_scope("attn.core"):   # attn.out inside
+        live = masking.chunk_causal_mask(t_max, start, w)[:, None]
+        out = _mla_attend(x, p, cfg, q_nope, q_rope, ckv_g, kr_g, live)
     return out, MLACache(c_kv, k_rope, c_sc, r_sc)

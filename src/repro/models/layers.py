@@ -34,9 +34,10 @@ def layernorm(x: jax.Array, weight: jax.Array, bias: jax.Array,
 
 
 def apply_norm(x: jax.Array, p: dict, kind: str) -> jax.Array:
-    if kind == "rmsnorm":
-        return rmsnorm(x, p["scale"])
-    return layernorm(x, p["scale"], p["bias"])
+    with jax.named_scope("norm"):
+        if kind == "rmsnorm":
+            return rmsnorm(x, p["scale"])
+        return layernorm(x, p["scale"], p["bias"])
 
 
 def build_norm(b, d: int, kind: str) -> dict:
@@ -149,9 +150,11 @@ def embed(tokens: jax.Array, p: dict, dtype=jnp.bfloat16) -> jax.Array:
     from repro.core.quant import QTensor
 
     t = p["table"]
-    if isinstance(t, QTensor):  # per-row int8: gather rows + row scales
-        return t.values[tokens].astype(dtype) * t.scale[tokens].astype(dtype)
-    return t.astype(dtype)[tokens]
+    with jax.named_scope("embed"):
+        if isinstance(t, QTensor):  # per-row int8: gather rows + row scales
+            return (t.values[tokens].astype(dtype)
+                    * t.scale[tokens].astype(dtype))
+        return t.astype(dtype)[tokens]
 
 
 def unembed(x: jax.Array, p: dict) -> jax.Array:

@@ -401,11 +401,12 @@ class Model:
         return constrain(x, ("batch", None, None)), positions
 
     def _unembed(self, params, x):
-        x = layers.apply_norm(x, params["final_norm"], self.cfg.norm)
-        table = params["embed"]["table"] if self.cfg.tie_embeddings \
-            else params["lm_head"]["table"]
-        logits = layers.unembed(x, {"table": table})
-        return constrain(logits, ("batch", None, "vocab"))
+        with jax.named_scope("head"):
+            x = layers.apply_norm(x, params["final_norm"], self.cfg.norm)
+            table = params["embed"]["table"] if self.cfg.tie_embeddings \
+                else params["lm_head"]["table"]
+            logits = layers.unembed(x, {"table": table})
+            return constrain(logits, ("batch", None, "vocab"))
 
     # ------------------------------------------------------------------
     # Forward (train / prefill)

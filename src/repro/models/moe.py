@@ -43,13 +43,14 @@ def build_ffn(b, cfg: ArchConfig, d_ff: int, use_bias: bool = False) -> dict:
 
 
 def apply_ffn(x: jax.Array, p: dict, activation: str) -> jax.Array:
-    h = apply_dense(x, p["w1"])
-    if is_gated(activation):
-        h = layers.activate(apply_dense(x, p["wg"]), activation) * h
-    else:
-        h = layers.activate(h, activation)
-    h = constrain(h, ("batch",) + (None,) * (h.ndim - 2) + ("ffn",))
-    return apply_dense(h, p["w2"])
+    with jax.named_scope("ffn"):
+        h = apply_dense(x, p["w1"])
+        if is_gated(activation):
+            h = layers.activate(apply_dense(x, p["wg"]), activation) * h
+        else:
+            h = layers.activate(h, activation)
+        h = constrain(h, ("batch",) + (None,) * (h.ndim - 2) + ("ffn",))
+        return apply_dense(h, p["w2"])
 
 
 def build_moe(b, cfg: ArchConfig) -> dict:
@@ -121,14 +122,16 @@ def apply_moe(x: jax.Array, p: dict, cfg: ArchConfig) -> jax.Array:
     m = cfg.moe
     b_, s, d = x.shape
     cap = capacity(s, m)
-    top_w, top_i = route(x, p["router"], m)
-    routed = jax.vmap(
-        lambda xi, wi, ii: _dispatch_one(xi, wi, ii, p, m, cfg.activation, cap)
-    )(x, top_w, top_i)
-    routed = constrain(routed, ("batch", None, None))
-    if "shared" in p:
-        routed = routed + apply_ffn(x, p["shared"], cfg.activation)
-    return routed
+    with jax.named_scope("ffn"):
+        top_w, top_i = route(x, p["router"], m)
+        routed = jax.vmap(
+            lambda xi, wi, ii: _dispatch_one(xi, wi, ii, p, m,
+                                             cfg.activation, cap)
+        )(x, top_w, top_i)
+        routed = constrain(routed, ("batch", None, None))
+        if "shared" in p:
+            routed = routed + apply_ffn(x, p["shared"], cfg.activation)
+        return routed
 
 
 def load_balance_loss(x: jax.Array, router_w: jax.Array, m: MoEConfig) -> jax.Array:

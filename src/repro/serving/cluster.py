@@ -30,6 +30,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Any
 
+from jax.profiler import TraceAnnotation
+
 from repro.core.paging import blocks_for_tokens
 from repro.core.spec import MeshSpec, RuntimeSpec
 from repro.serving.engine import Request, ServingEngine
@@ -76,6 +78,11 @@ class EngineCluster:
 
         def cb(e: EngineEvent) -> None:
             if not self.events.active:
+                return
+            if e.kind == "dispatch":   # no request: say whose step it was
+                self.events.publish(EngineEvent(
+                    e.kind, e.uid, self.stats["decode_steps"], e.t,
+                    {**e.data, "replica": idx}))
                 return
             uid = self._maps[idx].get(e.uid)
             if uid is None:        # event for a request we didn't route
@@ -145,7 +152,9 @@ class EngineCluster:
             if not self._busy(eng):
                 continue
             stepped = True
-            for req in eng.step():
+            with TraceAnnotation("engine.replica", replica=i):
+                finished = eng.step()
+            for req in finished:
                 req.uid = self._maps[i].pop(req.uid)
                 done.append(req)
         if stepped:

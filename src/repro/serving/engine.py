@@ -69,12 +69,14 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import warnings
 from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ArchConfig
 from repro.distributed import sharding as shd
@@ -99,9 +101,21 @@ from repro.serving.sampling import (SamplingParams, fold_in_keys,
 # per-request or per-step now flows through the structured event surface
 # (``serving.events`` / ``engine.events``) instead of growing this dict.
 _STAT_KEYS = ("decode_steps", "device_gets", "harvest_elems", "preemptions",
-              "prefill_tokens", "max_step_prefill_tokens", "prefix_hits",
+              "max_step_prefill_tokens", "prefix_hits",
               "prefix_hit_tokens", "cow_forks", "prefix_evictions",
               "spec_steps", "spec_accepted")
+
+
+def _span(name: str):
+    """Run the decorated method inside the host span ``name``
+    (``serving.events.SPAN_NAMES``)."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapped(*args, **kwargs):
+            with TraceAnnotation(name):
+                return fn(*args, **kwargs)
+        return wrapped
+    return deco
 
 
 @dataclasses.dataclass
@@ -671,6 +685,7 @@ class ServingEngine:
         self._fleet_rows[mid] = self.fabric.topo_row(arch, mid)
         return mid
 
+    @_span("engine.submit")
     def submit(self, prompt: list[int], max_new_tokens: int = 32,
                eos_id: int | None = None,
                sampling: SamplingParams | None = None,
@@ -878,30 +893,33 @@ class ServingEngine:
                 logits, cache = self._traced_model.decode_step(
                     params, cache, state.last, state.index,
                     block_tables=block_tables)
-            toks = sample_per_slot(logits[:, 0], keys, state.temp,
-                                   state.top_k, state.top_p)
+            with jax.named_scope("sample"):
+                toks = sample_per_slot(logits[:, 0], keys, state.temp,
+                                       state.top_k, state.top_p)
 
-            act = state.active
-            act_i = act.astype(jnp.int32)
-            rows = jnp.arange(self.max_batch)
-            pos = jnp.minimum(state.count, self.max_len - 1)
-            buf = state.buf.at[rows, pos].set(
-                jnp.where(act, toks, state.buf[rows, pos]))
-            count = state.count + act_i
-            index = state.index + act_i
-            hit_eos = act & (state.eos >= 0) & (toks == state.eos)
-            # cache-full is index >= max_len: position max_len-1 is a real,
-            # usable slot (the historical `max_len - 1` check wasted it)
-            finish = act & (hit_eos | (count >= state.budget)
-                            | (index >= self.max_len))
-            state = state._replace(
-                last=jnp.where(act[:, None], toks[:, None], state.last),
-                index=index,
-                active=act & ~finish,
-                done=state.done | finish,
-                count=count,
-                buf=buf,
-                rng=rng)
+            with jax.named_scope("slot_update"):
+                act = state.active
+                act_i = act.astype(jnp.int32)
+                rows = jnp.arange(self.max_batch)
+                pos = jnp.minimum(state.count, self.max_len - 1)
+                buf = state.buf.at[rows, pos].set(
+                    jnp.where(act, toks, state.buf[rows, pos]))
+                count = state.count + act_i
+                index = state.index + act_i
+                hit_eos = act & (state.eos >= 0) & (toks == state.eos)
+                # cache-full is index >= max_len: position max_len-1 is a
+                # real, usable slot (the historical `max_len - 1` check
+                # wasted it)
+                finish = act & (hit_eos | (count >= state.budget)
+                                | (index >= self.max_len))
+                state = state._replace(
+                    last=jnp.where(act[:, None], toks[:, None], state.last),
+                    index=index,
+                    active=act & ~finish,
+                    done=state.done | finish,
+                    count=count,
+                    buf=buf,
+                    rng=rng)
             return self._pin_outputs(cache, state)
 
     def _mixed_impl(self, params, cache, state: SlotState, block_tables,
@@ -947,32 +965,35 @@ class ServingEngine:
             # sampling lane: a completing prompt's last live lane, else 0
             completes = prefilling & \
                 (state.pf_pos + chunk_len >= state.prompt_len)
-            sel = jnp.where(completes, chunk_len - 1, 0)
-            lsel = jnp.take_along_axis(logits, sel[:, None, None],
-                                       axis=1)[:, 0]
-            toks_s = sample_per_slot(lsel, keys, state.temp, state.top_k,
-                                     state.top_p)
+            with jax.named_scope("sample"):
+                sel = jnp.where(completes, chunk_len - 1, 0)
+                lsel = jnp.take_along_axis(logits, sel[:, None, None],
+                                           axis=1)[:, 0]
+                toks_s = sample_per_slot(lsel, keys, state.temp,
+                                         state.top_k, state.top_p)
 
-            emit = decoding | completes   # slots producing a token now
-            rows = jnp.arange(B)
-            pos = jnp.minimum(state.count, self.max_len - 1)
-            buf = state.buf.at[rows, pos].set(
-                jnp.where(emit, toks_s, state.buf[rows, pos]))
-            count = state.count + emit.astype(jnp.int32)
-            index = state.index + n_live
-            pf_pos = state.pf_pos + jnp.where(prefilling, chunk_len, 0)
-            hit_eos = emit & (state.eos >= 0) & (toks_s == state.eos)
-            finish = emit & (hit_eos | (count >= state.budget)
-                             | (index >= self.max_len))
-            state = state._replace(
-                last=jnp.where(emit[:, None], toks_s[:, None], state.last),
-                index=index,
-                active=state.active & ~finish,
-                done=state.done | finish,
-                count=count,
-                buf=buf,
-                rng=rng,
-                pf_pos=pf_pos)
+            with jax.named_scope("slot_update"):
+                emit = decoding | completes   # slots producing a token now
+                rows = jnp.arange(B)
+                pos = jnp.minimum(state.count, self.max_len - 1)
+                buf = state.buf.at[rows, pos].set(
+                    jnp.where(emit, toks_s, state.buf[rows, pos]))
+                count = state.count + emit.astype(jnp.int32)
+                index = state.index + n_live
+                pf_pos = state.pf_pos + jnp.where(prefilling, chunk_len, 0)
+                hit_eos = emit & (state.eos >= 0) & (toks_s == state.eos)
+                finish = emit & (hit_eos | (count >= state.budget)
+                                 | (index >= self.max_len))
+                state = state._replace(
+                    last=jnp.where(emit[:, None], toks_s[:, None],
+                                   state.last),
+                    index=index,
+                    active=state.active & ~finish,
+                    done=state.done | finish,
+                    count=count,
+                    buf=buf,
+                    rng=rng,
+                    pf_pos=pf_pos)
             return self._pin_outputs(cache, state)
 
     def _spec_impl(self, params, cache, state: SlotState, block_tables,
@@ -1163,6 +1184,7 @@ class ServingEngine:
     # ------------------------------------------------------------------
     # host-side control (dispatch-only between syncs)
     # ------------------------------------------------------------------
+    @_span("engine.admit")
     def _admit(self) -> None:
         if self.scheduler == "chunked":
             self._admit_chunked()
@@ -1376,6 +1398,7 @@ class ServingEngine:
         """Most cache positions this slot can ever need (then it finishes)."""
         return min(self._plen[slot] + self._budget[slot] - 1, self.max_len)
 
+    @_span("engine.capacity")
     def _ensure_capacity(self, horizon: int) -> None:
         """Pre-reserve blocks so the next ``horizon`` fused steps cannot
         write outside a slot's blocks (the fused step itself never talks
@@ -1494,15 +1517,21 @@ class ServingEngine:
         self._emit("preempt", req.uid, banked=len(req.prefix))
 
     def _dispatch(self) -> None:
-        if self.paging is not None and self._tables_dirty:
-            # host-built step inputs go straight to this replica's
-            # placement, like every other operand of the step
-            self.block_tables = jax.device_put(
-                np.asarray(self._tables, np.int32), self._placement)
-            self._tables_dirty = False
-        if self.scheduler == "chunked":
-            grants = self._grant_chunks()
+        """Launch one step program: the mixed step when some prompt has a
+        chunk granted, else the one-lane decode step (the bucketed
+        scheduler prefills at admission, so all its steps decode)."""
+        with TraceAnnotation("engine.dispatch") as span:
+            if self.paging is not None and self._tables_dirty:
+                # host-built step inputs go straight to this replica's
+                # placement, like every other operand of the step
+                self.block_tables = jax.device_put(
+                    np.asarray(self._tables, np.int32), self._placement)
+                self._tables_dirty = False
+            grants = (self._grant_chunks() if self.scheduler == "chunked"
+                      else [0] * self.max_batch)
             granted = sum(grants)
+            program = "mixed" if granted else "decode"
+            span.set_metadata(program=program)
             # under speculation the draft rides inside the same dispatch:
             # the jitted step takes (target, draft) pairs for params and
             # cache, and the donated tuple comes back the same shape
@@ -1528,10 +1557,20 @@ class ServingEngine:
             else:
                 self.cache = cache
             self.stats["decode_steps"] += 1
-            self.stats["prefill_tokens"] += granted
             self.stats["max_step_prefill_tokens"] = max(
                 self.stats["max_step_prefill_tokens"], granted)
-            for slot in self._occupied():
+            occ = self._occupied()
+            if self.events.active:
+                # a decoding slot computes spec_horizon lanes (1 without
+                # speculation); a prefilling slot its grant
+                n_dec = sum(not grants[s] and self._pf[s] >= self._plen[s]
+                            for s in occ)
+                width = self.chunk_size if granted else self.spec_horizon
+                self._emit("dispatch", -1, program=program, slots=len(occ),
+                           prefill_tokens=granted,
+                           lanes=self.max_batch * width,
+                           live_lanes=granted + n_dec * self.spec_horizon)
+            for slot in occ:
                 if grants[slot]:
                     self._pf[slot] += grants[slot]
                     self._idx_ub[slot] = self._pf[slot]
@@ -1547,13 +1586,6 @@ class ServingEngine:
                         self._slot_token_cap(slot))
             if self.prefix_cache is not None:
                 self._register_prefixes()
-            return
-        self.cache, self.state = self._decode(self.params, self.cache,
-                                              self.state, self.block_tables)
-        self.stats["decode_steps"] += 1
-        for slot in self._occupied():
-            self._idx_ub[slot] = min(self._idx_ub[slot] + 1,
-                                     self._slot_token_cap(slot))
 
     def _register_prefixes(self) -> None:
         """Register every slot whose prefill just completed: its whole
@@ -1574,19 +1606,21 @@ class ServingEngine:
                                          self._slot_blocks[slot][:n_full])
             self._reg_done[slot] = True
 
+    @_span("engine.harvest")
     def _harvest(self) -> list[Request]:
         """One bulk device_get of the done/count vectors; token buffers are
         pulled (one more bulk get) only for slots that actually finished,
         sliced to the longest finished stream — the transfer scales with
         the tokens produced, not with max_len."""
-        if self.speculation is not None:
-            done_h, count_h, acc_h, ss_h = jax.device_get(
-                (self.state.done, self.state.count, self.state.acc,
-                 self.state.spec_steps))
-        else:
-            done_h, count_h = jax.device_get(
-                (self.state.done, self.state.count))
-            acc_h = ss_h = None
+        with TraceAnnotation("engine.harvest.wait"):
+            if self.speculation is not None:
+                done_h, count_h, acc_h, ss_h = jax.device_get(
+                    (self.state.done, self.state.count, self.state.acc,
+                     self.state.spec_steps))
+            else:
+                done_h, count_h = jax.device_get(
+                    (self.state.done, self.state.count))
+                acc_h = ss_h = None
         self.stats["device_gets"] += 1
         occ = self._occupied()
         slots = [i for i in occ if done_h[i]]
@@ -1615,8 +1649,9 @@ class ServingEngine:
         if not slots:
             return []
         maxc = max(int(count_h[i]) for i in slots)
-        bufs = jax.device_get(
-            self.state.buf[jnp.asarray(slots, jnp.int32), :maxc])
+        with TraceAnnotation("engine.harvest.fetch"):
+            bufs = jax.device_get(
+                self.state.buf[jnp.asarray(slots, jnp.int32), :maxc])
         self.stats["device_gets"] += 1
         self.stats["harvest_elems"] += len(slots) * maxc
         finished = []
@@ -1637,12 +1672,13 @@ class ServingEngine:
     def step(self) -> list[Request]:
         """Admit waiting requests, advance every active slot one token.
         Returns requests completed this step."""
-        self._admit()
-        if not self._occupied():
-            return []
-        self._ensure_capacity(1)
-        self._dispatch()
-        return self._harvest()
+        with TraceAnnotation("engine.step", step=self.stats["decode_steps"]):
+            self._admit()
+            if not self._occupied():
+                return []
+            self._ensure_capacity(1)
+            self._dispatch()
+            return self._harvest()
 
     def run_to_completion(self, max_steps: int = 10_000,
                           sync_every: int = 1) -> list[Request]:

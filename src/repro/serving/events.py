@@ -36,6 +36,20 @@ Lifecycle of one request::
 * ``preempt``      — the slot was recompute-preempted; the request
   re-enters admission later.  data: ``banked`` (tokens carried over).
 
+One more kind belongs to no request (``uid == -1``):
+
+* ``dispatch``     — one per step program launched, right after the
+  launch (its stamp is the host's, not the device's).  data:
+  ``program`` (``"mixed"`` or ``"decode"``), ``slots`` (occupied
+  slots), ``prefill_tokens`` (prompt tokens granted to this dispatch),
+  ``lanes`` (query lanes the program computes: ``max_batch x W`` with
+  W the chunk size for mixed, 1 — or the speculative horizon — for
+  decode) and ``live_lanes`` (granted prompt tokens plus W per
+  decoding slot; a slot that finished since the last harvest still
+  counts).  It carries the same ``step`` as the ``progress`` events
+  of the harvest that follows it.  An :class:`EngineCluster` relays
+  it with ``replica`` added.
+
 Every event carries the engine's logical clock (``step`` = fused
 dispatches so far) and a ``time.perf_counter()`` wall stamp.  Step
 arithmetic is bit-reproducible across runs; wall stamps are not — the
@@ -52,15 +66,55 @@ from dataclasses import dataclass, field
 from typing import Any, Callable
 
 EVENT_KINDS = ("submit", "admit", "first_token", "progress", "finish",
-               "preempt")
+               "preempt", "dispatch")
+
+# Host spans (``jax.profiler.TraceAnnotation``) the engine opens around
+# its phases.  They cost ~1 µs each while no profiler runs; under one
+# they land in the host plane on the device planes' clock, so a trace
+# can say which phase the host was in while the device sat idle.
+SPAN_NAMES = (
+    "engine.step",           # ServingEngine.step; kwarg step = dispatches
+                             # before it (its own dispatch's events carry
+                             # step + 1 on the events' logical clock)
+    "engine.submit",         # ServingEngine.submit
+    "engine.admit",          # seating queued requests: block allocation,
+                             # prefix lookup, the bucketed prefill
+    "engine.capacity",       # block reservation, preemption included
+    "engine.dispatch",       # table upload, chunk grants, the step
+                             # program's launch, prefix registration;
+                             # kwarg program = mixed | decode
+    "engine.harvest",        # the sync: progress, finished rows, release
+    "engine.harvest.wait",   # in harvest: the blocking read of done/count,
+                             # where the host waits on the device
+    "engine.harvest.fetch",  # in harvest: the read of finished token rows
+    "engine.replica",        # EngineCluster.step, one replica's step;
+                             # kwarg replica = its index
+)
+
+# ``jax.named_scope`` names in the step programs: compile-time metadata
+# (each HLO op's ``op_name``), free at run time.  A profiler trace ties
+# an op's device time to the innermost of these in its ``op_name``.
+SCOPE_NAMES = (
+    "embed",             # token embedding (models.layers.embed)
+    "norm",              # every norm (models.layers.apply_norm)
+    "attn.qkv",          # paged attention: q/k/v projections and rope
+    "attn.kv_write",     # paged attention: codec store + pool write
+    "attn.kv_gather",    # paged attention: block-table view of the pool
+    "attn.core",         # scores and values, or the Pallas kernel
+    "attn.out",          # the output projection
+    "ffn",               # dense FFN or MoE
+    "head",              # final norm + vocabulary head (Model._unembed)
+    "sample",            # the sampler over the step's logits
+    "slot_update",       # the SlotState writes after sampling
+)
 
 
 @dataclass(frozen=True)
 class EngineEvent:
-    """One lifecycle transition of one request."""
+    """One lifecycle transition of one request, or one dispatch."""
 
     kind: str                 # one of EVENT_KINDS
-    uid: int                  # engine request uid
+    uid: int                  # engine request uid (-1: dispatch)
     step: int                 # engine logical clock (fused dispatches)
     t: float                  # wall stamp (time.perf_counter())
     data: dict[str, Any] = field(default_factory=dict)
@@ -96,7 +150,7 @@ class EventBus:
 
 
 class EventLog:
-    """The standard subscriber: an append-only list with per-uid views."""
+    """The standard subscriber: an append-only list."""
 
     def __init__(self) -> None:
         self.events: list[EngineEvent] = []
@@ -106,12 +160,6 @@ class EventLog:
 
     def __len__(self) -> int:
         return len(self.events)
-
-    def of_kind(self, kind: str) -> list[EngineEvent]:
-        return [e for e in self.events if e.kind == kind]
-
-    def of_uid(self, uid: int) -> list[EngineEvent]:
-        return [e for e in self.events if e.uid == uid]
 
 
 def now() -> float:

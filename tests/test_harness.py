@@ -285,9 +285,13 @@ def test_lifecycle_events_exactly_once(layout, policy, fleet):
     res = replay(eng, scripted_trace(rows, name="lifecycle"))
     m = res.metrics
     assert m.n_finished == len(rows)
+    # one dispatch event (no request) per step program launched
+    assert sum(e.kind == "dispatch" for e in res.events) == \
+        eng.stats["decode_steps"]
     by_uid = {}
     for e in res.events:
-        by_uid.setdefault(e.uid, []).append(e)
+        if e.kind != "dispatch":
+            by_uid.setdefault(e.uid, []).append(e)
     assert len(by_uid) == len(rows)
     for uid, evs in by_uid.items():
         kinds = [e.kind for e in evs]

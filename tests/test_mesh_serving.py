@@ -93,11 +93,15 @@ def test_dp2_cluster_streams_bit_identical_and_events_merge(params, trace):
     # every replica kept the compile-once discipline
     for comp in cl.compilations:
         assert comp["prefill"] == 1 and comp["decode"] == 1
-    # merged EventLog: every request's full lifecycle under cluster uids
-    uids = {e.uid for e in rc.events}
+    # merged EventLog: every request's full lifecycle under cluster uids,
+    # and each replica's dispatches under its index
+    req_events = [e for e in rc.events if e.kind != "dispatch"]
+    uids = {e.uid for e in req_events}
     assert uids == set(rc.uid_to_rid)
+    assert sorted({e.data["replica"] for e in rc.events
+                   if e.kind == "dispatch"}) == [0, 1]
     for uid in uids:
-        kinds = [e.kind for e in rc.events if e.uid == uid]
+        kinds = [e.kind for e in req_events if e.uid == uid]
         assert kinds[0] == "submit" and kinds[-1] == "finish"
         assert "admit" in kinds and "first_token" in kinds
     # the reduced metrics see the same completions as the single engine
