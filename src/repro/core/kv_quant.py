@@ -107,18 +107,21 @@ class CacheCodec:
     # Cache construction
     # ------------------------------------------------------------------
     def cache_arrays(self, shape: tuple[int, ...], *,
+                     scale_shape: tuple[int, ...] | None = None,
                      compute_dtype: Any = jnp.bfloat16,
                      abstract: bool = False):
         """(values, scales-or-None) leaves for one cache tensor whose
-        trailing dim is the quantized feature dim."""
+        trailing dim is the quantized feature dim.  Scales are shaped
+        ``scale_shape`` (default: the values minus that dim)."""
         vd = self.storage_dtype(compute_dtype)
+        sshape = shape[:-1] if scale_shape is None else scale_shape
         if abstract:
             vals = jax.ShapeDtypeStruct(shape, vd)
-            sc = jax.ShapeDtypeStruct(shape[:-1], jnp.float32) \
+            sc = jax.ShapeDtypeStruct(sshape, jnp.float32) \
                 if self.quantized else None
         else:
             vals = jnp.zeros(shape, vd)
-            sc = jnp.zeros(shape[:-1], jnp.float32) if self.quantized else None
+            sc = jnp.zeros(sshape, jnp.float32) if self.quantized else None
         return vals, sc
 
     def bytes_per_feature_row(self, d: int, compute_dtype: Any = jnp.bfloat16
@@ -134,12 +137,16 @@ FLOAT_CODEC = CacheCodec("compute")
 
 
 def cache_put(values: jax.Array, scales: jax.Array | None, idx: tuple,
-              new_vals: jax.Array, new_scales: jax.Array | None
+              new_vals: jax.Array, new_scales: jax.Array | None,
+              layer: jax.Array | int | None = None
               ) -> tuple[jax.Array, jax.Array | None]:
     """Scatter codec-stored (values, scales) at ``idx`` — the one write
     primitive shared by every cache layout (dense rows, paged blocks,
     chunk lanes) and every attention variant; scales are None end-to-end
-    in compute mode."""
+    in compute mode.  ``layer`` addresses one layer of a layer-stacked
+    cache in place (it is prepended to ``idx``)."""
+    if layer is not None:
+        idx = (layer, *idx)
     out_v = values.at[idx].set(new_vals)
     out_s = scales if new_scales is None else scales.at[idx].set(new_scales)
     return out_v, out_s
@@ -163,12 +170,22 @@ def fork_block(cache, src: jax.Array, dst: jax.Array):
 
 def gather_view(codec: CacheCodec, values: jax.Array,
                 scales: jax.Array | None, block_tables: jax.Array,
-                shape: tuple[int, ...], dtype) -> jax.Array:
+                shape: tuple[int, ...], dtype,
+                layer: jax.Array | int | None = None) -> jax.Array:
     """Block-table gather of a pooled cache into sequence-major ``shape``,
     dequantized on the way out (the fused-on-TPU read half of the
-    codec)."""
-    g = values[block_tables].reshape(shape)
+    codec).  ``layer`` reads one layer of a layer-stacked pool: one
+    gather at ``(layer, block_tables)``, no per-layer pool array."""
+    idx = block_tables if layer is None else (layer, block_tables)
+    g = values[idx].reshape(shape)
     if not codec.quantized:
         return g
-    sg = scales[block_tables].reshape(shape[:-1])
+    sg = scales[idx].reshape(shape[:-1])
     return codec.decode(g, sg, dtype)
+
+
+def layer_view(a: jax.Array | None, layer: jax.Array | int
+               ) -> jax.Array | None:
+    """One layer of a layer-stacked cache leaf; an absent leaf (a
+    compute-mode scale) stays None."""
+    return None if a is None else a[layer]

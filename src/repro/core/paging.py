@@ -56,6 +56,19 @@ def blocks_for_tokens(num_tokens: int, block_size: int) -> int:
     return max(-(-num_tokens // block_size), 0)
 
 
+def pool_row(kv_heads: int, head_dim: int) -> tuple[int, ...]:
+    """Trailing dims of one position's K (or V) row in the paged GQA pool.
+
+    A head dim that fills whole 128-lane TPU tiles keeps its own axis,
+    ``(kv_heads, head_dim)``.  A narrower one shares the row with the
+    other heads, ``(kv_heads * head_dim,)``: a ``[.., kv, 64]`` pool
+    pads to 128 lanes, the chip then stores it blocks-minor, and the
+    layer loop copies the whole pool in and out to scatter into it."""
+    if head_dim % 128 == 0:
+        return (kv_heads, head_dim)
+    return (kv_heads * head_dim,)
+
+
 @dataclasses.dataclass(frozen=True)
 class PagingConfig:
     """Pool geometry (the 'synthesis parameters' of the KV memory).

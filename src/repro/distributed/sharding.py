@@ -20,6 +20,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.core.paging import pool_row
+
 
 @dataclasses.dataclass(frozen=True)
 class ShardingStrategy:
@@ -129,23 +131,36 @@ def tp_mesh(devices) -> Mesh:
     return Mesh(np.asarray(devs).reshape(1, len(devs)), ("data", "model"))
 
 
-def kv_cache_shardings(mesh: Mesh, cache, strategy: ShardingStrategy):
+def kv_cache_shardings(mesh: Mesh, cache, strategy: ShardingStrategy,
+                       kv_heads: int | None = None,
+                       head_dim: int | None = None):
     """Shardings for a decode-cache pytree, mirroring ``param_rules``'
     kv_heads rule with the same per-leaf divisibility fallback.
 
     KV caches are recognized structurally (a NamedTuple whose first two
     fields are ``k``/``v`` — ``models.attention.KVCache`` and the
     fabric's synthesis-time cache; importing them here would cycle):
-    value leaves ``[L, rows, cols, n_kv, hd]`` shard the kv-head axis
-    (-2) over the TP axis, int8 scale rows (``values.shape[:-1]``)
-    shard their trailing kv-head axis, and everything else — MLA
-    latents (no kv-head axis), recurrent state, hybrid per-layer
-    entries that aren't attention — replicates.  A kv-head count that
-    does not divide the TP extent replicates that leaf, so every arch
-    lowers on every mesh."""
+    value leaves ``[..., n_kv, hd]`` shard the kv-head axis (-2) over
+    the TP axis; given ``kv_heads``/``head_dim``, a paged pool whose
+    rows merge a position's heads (``core.paging.pool_row``: ``[L, NB,
+    bs, n_kv * hd]``) splits along that row (-1) when ``kv_heads``
+    divides the TP extent; int8 scale rows (``[..., n_kv]``) shard their
+    trailing kv-head axis, and everything else — MLA latents (no
+    kv-head axis), recurrent state, hybrid per-layer entries that
+    aren't attention — replicates.  A kv-head count that does not
+    divide the TP extent replicates that leaf, so every arch lowers on
+    every mesh."""
     tp = strategy.tp_axis
     tp_n = mesh.shape.get(tp, 1) if tp is not None else 1
     rep = NamedSharding(mesh, P())
+
+    def values_spec(leaf) -> NamedSharding:
+        heads = (kv_heads, head_dim)
+        if kv_heads is None or leaf.shape[-2:] == heads \
+                or leaf.shape[-1:] != pool_row(*heads):
+            return axis_spec(leaf, -2)
+        # a merged row splits only where whole heads land on each device
+        return axis_spec(leaf, -1) if kv_heads % tp_n == 0 else rep
 
     def axis_spec(leaf, axis: int) -> NamedSharding:
         if tp_n > 1 and leaf.ndim > axis % leaf.ndim \
@@ -163,7 +178,7 @@ def kv_cache_shardings(mesh: Mesh, cache, strategy: ShardingStrategy):
         fields = getattr(node, "_fields", None)
         if fields is not None and fields[:2] == ("k", "v"):
             return type(node)(
-                axis_spec(node.k, -2), axis_spec(node.v, -2),
+                values_spec(node.k), values_spec(node.v),
                 *(None if s is None else axis_spec(s, -1)
                   for s in node[2:]))
         if isinstance(node, dict):

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig, MLAConfig
 from repro.core import masking
 from repro.core.kv_quant import (FLOAT_CODEC, CacheCodec, cache_put,
-                                 gather_view)
+                                 gather_view, layer_view)
 from repro.core.paging import NULL_BLOCK
 from repro.distributed.sharding import constrain
 from repro.kernels.runtime import interpret_default
@@ -52,6 +52,12 @@ class KVCache(NamedTuple):
     * paged — ``[num_blocks, block_size, n_kv, hd]``: a pooled cache of
       fixed-size token blocks; a slot's sequence is scattered across the
       pool and addressed through its block table (``core.paging``).
+      Where ``hd`` is not a multiple of the TPU's 128 lanes a position's
+      heads share one row, ``[num_blocks, block_size, n_kv * hd]``
+      (``core.paging.pool_row``).  The decode and mixed steps hand every
+      paged attention layer the whole layer-stacked pool (``[L, ...]``
+      leaves) and its ``layer`` index: each layer writes and reads its
+      own rows in place.
 
     Two storage codecs share it too (``core.kv_quant.CacheCodec``):
     under ``kv_dtype="int8"`` the ``k``/``v`` values are int8 and the
@@ -362,6 +368,11 @@ def gqa_decode(x: jax.Array, p: dict, cfg: ArchConfig, cache: KVCache,
     return apply_dense(o, p["wo"]), KVCache(k, v, k_sc, v_sc)
 
 
+def _pool_rows(x: jax.Array, pool: jax.Array) -> jax.Array:
+    """New K/V rows ``[..., kv, hd]`` in the paged pool's row shape."""
+    return x.reshape(*x.shape[:-2], *pool.shape[3:])
+
+
 def paged_write_slot(idx_vec: jax.Array, block_tables: jax.Array,
                      block_size: int) -> tuple[jax.Array, jax.Array]:
     """(physical block, in-block offset) for each slot's next cache write.
@@ -383,12 +394,16 @@ def paged_write_slot(idx_vec: jax.Array, block_tables: jax.Array,
 
 
 def gqa_decode_paged(x: jax.Array, p: dict, cfg: ArchConfig, cache: KVCache,
-                     cache_index: jax.Array, block_tables: jax.Array, *,
+                     cache_index: jax.Array, block_tables: jax.Array,
+                     layer: jax.Array | int, *,
                      grouped: bool = False,
                      impl: str = "gather",
                      codec: CacheCodec | None = None
                      ) -> tuple[jax.Array, KVCache]:
-    """One-token decode against the pooled [NB, bs, kv, hd] cache.
+    """One-token decode against layer ``layer`` of the layer-stacked pool
+    [L, NB, bs, ...]: one scatter at (layer, block, offset) writes the
+    new row in place and one gather at (layer, block_tables) reads the
+    slot's view, so no per-layer pool array is formed.
 
     ``block_tables``: [B, blocks_per_slot] int32 — logical block i of a
     slot lives in pool row ``block_tables[slot, i]`` (0 = null block).
@@ -401,7 +416,7 @@ def gqa_decode_paged(x: jax.Array, p: dict, cfg: ArchConfig, cache: KVCache,
     codec = codec or FLOAT_CODEC
     b_, one, _ = x.shape
     kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
-    bs = cache.k.shape[1]
+    bs = cache.k.shape[2]
     idx_vec = as_index_vector(cache_index, b_)
     with jax.named_scope("attn.qkv"):
         q, k_new, v_new = gqa_qkv(x, p, cfg, idx_vec[:, None])
@@ -409,24 +424,29 @@ def gqa_decode_paged(x: jax.Array, p: dict, cfg: ArchConfig, cache: KVCache,
         blk, off = paged_write_slot(idx_vec, block_tables, bs)
         kq, ks = codec.store(k_new[:, 0], cache.k.dtype)
         vq, vs = codec.store(v_new[:, 0], cache.v.dtype)
-        k, k_sc = cache_put(cache.k, cache.k_scale, (blk, off), kq, ks)
-        v, v_sc = cache_put(cache.v, cache.v_scale, (blk, off), vq, vs)
+        k, k_sc = cache_put(cache.k, cache.k_scale, (blk, off),
+                            _pool_rows(kq, cache.k), ks, layer)
+        v, v_sc = cache_put(cache.v, cache.v_scale, (blk, off),
+                            _pool_rows(vq, cache.v), vs, layer)
     t_max = block_tables.shape[1] * bs
     if impl == "pallas":
         from repro.kernels.paged_attention import paged_decode_attention
         with jax.named_scope("attn.core"):
             lengths = jnp.minimum(idx_vec + 1, t_max)
+            pool = (*k.shape[1:3], kv, hd)
             o = paged_decode_attention(
-                q[:, 0], k, v, block_tables, lengths,
-                k_scale=k_sc, v_scale=v_sc,
+                q[:, 0], k[layer].reshape(pool), v[layer].reshape(pool),
+                block_tables, lengths,
+                k_scale=layer_view(k_sc, layer),
+                v_scale=layer_view(v_sc, layer),
                 interpret=interpret_default())
             o = o.reshape(b_, one, cfg.num_heads * hd)
     else:
         with jax.named_scope("attn.kv_gather"):
             kg = gather_view(codec, k, k_sc, block_tables,
-                              (b_, t_max, kv, hd), x.dtype)
+                             (b_, t_max, kv, hd), x.dtype, layer)
             vg = gather_view(codec, v, v_sc, block_tables,
-                              (b_, t_max, kv, hd), x.dtype)
+                             (b_, t_max, kv, hd), x.dtype, layer)
         with jax.named_scope("attn.core"):
             live = jnp.arange(t_max)[None, :] <= idx_vec[:, None]
             o = _gqa_attend(q, kg, vg, live, cfg, grouped)
@@ -476,12 +496,15 @@ def gqa_mixed(x: jax.Array, p: dict, cfg: ArchConfig, cache: KVCache,
 
 def gqa_mixed_paged(x: jax.Array, p: dict, cfg: ArchConfig, cache: KVCache,
                     start: jax.Array, n_live: jax.Array,
-                    block_tables: jax.Array, *, grouped: bool = False,
+                    block_tables: jax.Array, layer: jax.Array | int, *,
+                    grouped: bool = False,
                     impl: str = "gather",
                     interpret: bool | None = None,
                     codec: CacheCodec | None = None
                     ) -> tuple[jax.Array, KVCache]:
-    """W-lane chunk/decode attention against the pooled block cache.
+    """W-lane chunk/decode attention against layer ``layer`` of the
+    layer-stacked block pool (written and gathered in place, as in
+    ``gqa_decode_paged``).
 
     ``impl="gather"`` materializes the block-table view and reuses the
     dense contraction (bit-identical to ``gqa_mixed``); ``"pallas"``
@@ -491,7 +514,7 @@ def gqa_mixed_paged(x: jax.Array, p: dict, cfg: ArchConfig, cache: KVCache,
     codec = codec or FLOAT_CODEC
     b_, w, _ = x.shape
     kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
-    bs = cache.k.shape[1]
+    bs = cache.k.shape[2]
     positions = start[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]
     with jax.named_scope("attn.qkv"):
         q, k_new, v_new = gqa_qkv(x, p, cfg, positions)
@@ -502,23 +525,29 @@ def gqa_mixed_paged(x: jax.Array, p: dict, cfg: ArchConfig, cache: KVCache,
         blk, off = paged_write_slot(idx_w, block_tables, bs)
         kq, ks = codec.store(k_new, cache.k.dtype)
         vq, vs = codec.store(v_new, cache.v.dtype)
-        k, k_sc = cache_put(cache.k, cache.k_scale, (blk, off), kq, ks)
-        v, v_sc = cache_put(cache.v, cache.v_scale, (blk, off), vq, vs)
+        k, k_sc = cache_put(cache.k, cache.k_scale, (blk, off),
+                            _pool_rows(kq, cache.k), ks, layer)
+        v, v_sc = cache_put(cache.v, cache.v_scale, (blk, off),
+                            _pool_rows(vq, cache.v), vs, layer)
     if impl == "pallas":
         from repro.kernels.chunked_prefill import chunked_prefill_attention
         if interpret is None:
             interpret = interpret_default()
         with jax.named_scope("attn.core"):
-            o = chunked_prefill_attention(q, k, v, block_tables, start,
-                                          k_scale=k_sc, v_scale=v_sc,
+            pool = (*k.shape[1:3], kv, hd)
+            o = chunked_prefill_attention(q, k[layer].reshape(pool),
+                                          v[layer].reshape(pool),
+                                          block_tables, start,
+                                          k_scale=layer_view(k_sc, layer),
+                                          v_scale=layer_view(v_sc, layer),
                                           interpret=interpret)
             o = o.reshape(b_, w, cfg.num_heads * hd)
     else:
         with jax.named_scope("attn.kv_gather"):
             kg = gather_view(codec, k, k_sc, block_tables,
-                              (b_, t_max, kv, hd), x.dtype)
+                             (b_, t_max, kv, hd), x.dtype, layer)
             vg = gather_view(codec, v, v_sc, block_tables,
-                              (b_, t_max, kv, hd), x.dtype)
+                             (b_, t_max, kv, hd), x.dtype, layer)
         with jax.named_scope("attn.core"):
             live = masking.chunk_causal_mask(t_max, start, w)
             o = _gqa_attend(q, kg, vg, live, cfg, grouped)
@@ -654,14 +683,16 @@ def mla_decode(x: jax.Array, p: dict, cfg: ArchConfig, cache: MLACache,
 
 def mla_decode_paged(x: jax.Array, p: dict, cfg: ArchConfig, cache: MLACache,
                      cache_index: jax.Array, block_tables: jax.Array,
+                     layer: jax.Array | int,
                      codec: CacheCodec | None = None
                      ) -> tuple[jax.Array, MLACache]:
-    """MLA decode against pooled latent blocks ([NB, bs, rank] c_kv and
-    [NB, bs, rope_dim] k_rope addressed through the same block tables)."""
+    """MLA decode against layer ``layer`` of the pooled latent blocks
+    ([L, NB, bs, rank] c_kv and [L, NB, bs, rope_dim] k_rope addressed
+    through the same block tables, in place)."""
     codec = codec or FLOAT_CODEC
     m, h = cfg.mla, cfg.num_heads
     b_, one, _ = x.shape
-    bs = cache.c_kv.shape[1]
+    bs = cache.c_kv.shape[2]
     idx_vec = as_index_vector(cache_index, b_)
     positions = idx_vec[:, None]
     with jax.named_scope("attn.qkv"):
@@ -672,15 +703,15 @@ def mla_decode_paged(x: jax.Array, p: dict, cfg: ArchConfig, cache: MLACache,
         cq, cs = codec.store(c_new[:, 0], cache.c_kv.dtype)
         rq, rs = codec.store(kr_new[:, 0], cache.k_rope.dtype)
         c_kv, c_sc = cache_put(cache.c_kv, cache.c_scale, (blk, off),
-                                cq, cs)
+                               cq, cs, layer)
         k_rope, r_sc = cache_put(cache.k_rope, cache.r_scale, (blk, off),
-                                  rq, rs)
+                                 rq, rs, layer)
     t_max = block_tables.shape[1] * bs
     with jax.named_scope("attn.kv_gather"):
         ckv_g = gather_view(codec, c_kv, c_sc, block_tables,
-                             (b_, t_max, m.kv_lora_rank), x.dtype)
+                            (b_, t_max, m.kv_lora_rank), x.dtype, layer)
         kr_g = gather_view(codec, k_rope, r_sc, block_tables,
-                            (b_, t_max, m.qk_rope_head_dim), x.dtype)
+                           (b_, t_max, m.qk_rope_head_dim), x.dtype, layer)
     with jax.named_scope("attn.core"):   # attn.out inside
         live = (jnp.arange(t_max)[None] <= idx_vec[:, None])[:, None, None, :]
         out = _mla_attend(x, p, cfg, q_nope, q_rope, ckv_g, kr_g, live)
@@ -716,14 +747,15 @@ def mla_mixed(x: jax.Array, p: dict, cfg: ArchConfig, cache: MLACache,
 
 def mla_mixed_paged(x: jax.Array, p: dict, cfg: ArchConfig, cache: MLACache,
                     start: jax.Array, n_live: jax.Array,
-                    block_tables: jax.Array,
+                    block_tables: jax.Array, layer: jax.Array | int,
                     codec: CacheCodec | None = None
                     ) -> tuple[jax.Array, MLACache]:
-    """W-lane chunk/decode MLA against the pooled latent block cache."""
+    """W-lane chunk/decode MLA against layer ``layer`` of the pooled
+    latent block cache (in place)."""
     codec = codec or FLOAT_CODEC
     m, h = cfg.mla, cfg.num_heads
     b_, w, _ = x.shape
-    bs = cache.c_kv.shape[1]
+    bs = cache.c_kv.shape[2]
     positions = start[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]
     with jax.named_scope("attn.qkv"):
         q_nope, q_rope = _mla_q(x, p, m, h, positions, cfg.rope_theta)
@@ -735,14 +767,14 @@ def mla_mixed_paged(x: jax.Array, p: dict, cfg: ArchConfig, cache: MLACache,
         cq, cs = codec.store(c_new, cache.c_kv.dtype)
         rq, rs = codec.store(kr_new, cache.k_rope.dtype)
         c_kv, c_sc = cache_put(cache.c_kv, cache.c_scale, (blk, off),
-                                cq, cs)
+                               cq, cs, layer)
         k_rope, r_sc = cache_put(cache.k_rope, cache.r_scale, (blk, off),
-                                  rq, rs)
+                                 rq, rs, layer)
     with jax.named_scope("attn.kv_gather"):
         ckv_g = gather_view(codec, c_kv, c_sc, block_tables,
-                             (b_, t_max, m.kv_lora_rank), x.dtype)
+                            (b_, t_max, m.kv_lora_rank), x.dtype, layer)
         kr_g = gather_view(codec, k_rope, r_sc, block_tables,
-                            (b_, t_max, m.qk_rope_head_dim), x.dtype)
+                           (b_, t_max, m.qk_rope_head_dim), x.dtype, layer)
     with jax.named_scope("attn.core"):   # attn.out inside
         live = masking.chunk_causal_mask(t_max, start, w)[:, None]
         out = _mla_attend(x, p, cfg, q_nope, q_rope, ckv_g, kr_g, live)

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.core.kv_quant import CacheCodec
-from repro.core.paging import PagingConfig
+from repro.core.paging import PagingConfig, pool_row
 from repro.core.spec import CHUNKABLE_FAMILIES, KV_QUANTIZABLE_FAMILIES
 from repro.distributed.sharding import constrain
 from repro.models import attention as attn
@@ -282,25 +282,71 @@ class Model:
             outs.append(c)
         return x, jax.tree.map(lambda *ls: jnp.stack(ls), *outs)
 
-    def _run_prefix_then_stack(self, body, x, params, cache):
-        """Cache-threading layer loop with the MoE dense prefix: the
-        unrolled prefix layers hold their own cache slices at the front
-        of the stacked cache, the scanned main stack follows, and the
-        prefix caches are re-stacked on the way out.  Shared by
-        ``decode_step`` and ``mixed_step`` (all attention variants)."""
+    def _run_cache_layers(self, attend, x, params, cache, paged: bool):
+        """Layer loop of the attention-cache decode and mixed steps;
+        ``attend(hn, attn_params, cache, layer) -> (o, cache)``.
+
+        ``paged``: the whole layer-stacked pool rides the loop carry and
+        each layer writes and reads its own rows through its layer
+        index, so no per-layer pool array is sliced out, copied or
+        re-stacked and the donated pool aliases the output.  The MoE
+        dense prefix holds layers ``0..k-1`` of the same pool and the
+        stacked main layers follow; ``unroll_layers`` runs the same body
+        with static indices.
+
+        Dense ``[L, B, S]`` rows instead go through the scan as per-layer
+        ``xs``/``ys`` slices (``layer`` None): carried, a ``[.., kv, 64]``
+        row is copied whole in and out of the loop at 2x padding, which
+        does not fit qwen1.5-0.5b's 32 x 2048 serving cache on one v5e."""
+        cfg = self.cfg
+
+        def body(h, lp, c, layer):
+            hn = layers.apply_norm(h, lp["ln1"], cfg.norm)
+            o, c = attend(hn, lp["attn"], c, layer)
+            h = h + o
+            hn = layers.apply_norm(h, lp["ln2"], cfg.norm)
+            if "moe" in lp:
+                return h + moe.apply_moe(hn, lp["moe"], cfg), c
+            return h + moe.apply_ffn(hn, lp["ffn"], cfg.activation), c
+
         prefix = params.get("dense_prefix", [])
+        stacked = params["layers"]
+        if not paged:
+            return self._run_prefix_then_stack(body, x, prefix, stacked,
+                                               cache)
+        for i, lp in enumerate(prefix):
+            x, cache = body(x, lp, cache, i)
+        n = jax.tree.leaves(stacked)[0].shape[0]
+        if self.opt.unroll_layers:
+            for i in range(n):
+                x, cache = body(x, jax.tree.map(lambda l, i=i: l[i], stacked),
+                                cache, len(prefix) + i)
+            return x, cache
+
+        def step(carry, inp):
+            (h, c), (lp, layer) = carry, inp
+            return body(h, lp, c, layer), None
+
+        ids = jnp.arange(n, dtype=jnp.int32) + len(prefix)
+        (x, cache), _ = jax.lax.scan(step, (x, cache), (stacked, ids))
+        return x, cache
+
+    def _run_prefix_then_stack(self, body, x, prefix, stacked, cache):
+        """Dense-cache layer loop: the unrolled MoE dense prefix layers
+        hold their own cache slices at the front of the stacked cache,
+        the scanned main stack follows, and the prefix caches are
+        re-stacked on the way out."""
+        def step(h, inp):
+            return body(h, *inp, None)
+
         if not prefix:
-            return self._run_stack_cache(body, x, params["layers"], cache)
-        npref = len(prefix)
-        pref_cache = jax.tree.map(lambda l: l[:npref], cache)
-        main_cache = jax.tree.map(lambda l: l[npref:], cache)
+            return self._run_stack_cache(step, x, stacked, cache)
         new_pref = []
         for i, lp in enumerate(prefix):
-            ci = jax.tree.map(lambda l, i=i: l[i], pref_cache)
-            x, c2 = body(x, (lp, ci))
-            new_pref.append(c2)
-        x, new_main = self._run_stack_cache(body, x, params["layers"],
-                                            main_cache)
+            x, c = body(x, lp, jax.tree.map(lambda l, i=i: l[i], cache), None)
+            new_pref.append(c)
+        x, new_main = self._run_stack_cache(
+            step, x, stacked, jax.tree.map(lambda l: l[len(prefix):], cache))
         stacked_pref = jax.tree.map(lambda *ls: jnp.stack(ls), *new_pref)
         return x, jax.tree.map(lambda a, b_: jnp.concatenate([a, b_]),
                                stacked_pref, new_main)
@@ -596,10 +642,15 @@ class Model:
                 (cfg.num_layers, pb, bs, m.qk_rope_head_dim),
                 abstract=abstract)
             return MLACache(cv, rv, cs, rs)
-        shape = (cfg.num_layers, pb, bs, cfg.num_kv_heads,
-                 cfg.resolved_head_dim)
-        kvals, ksc = codec.cache_arrays(shape, abstract=abstract)
-        vvals, vsc = codec.cache_arrays(shape, abstract=abstract)
+        # int8 scales stay one per (position, kv head)
+        kv = cfg.num_kv_heads
+        shape = (cfg.num_layers, pb, bs,
+                 *pool_row(kv, cfg.resolved_head_dim))
+        scales = shape[:3] + (kv,)
+        kvals, ksc = codec.cache_arrays(shape, scale_shape=scales,
+                                        abstract=abstract)
+        vvals, vsc = codec.cache_arrays(shape, scale_shape=scales,
+                                        abstract=abstract)
         return KVCache(kvals, vvals, ksc, vsc)
 
     @_with_backend
@@ -717,26 +768,15 @@ class Model:
                 return h + out, st2
             x, new_cache = self._run_stack_cache(body, x, params["layers"], cache)
         elif cfg.mla is not None:
-            def body(h, inp):
-                lp, c = inp
-                hn = layers.apply_norm(h, lp["ln1"], cfg.norm)
+            def attend(hn, ap, c, layer):
                 if block_tables is not None:
-                    o, c2 = attn.mla_decode_paged(hn, lp["attn"], cfg, c,
-                                                  cache_index, block_tables,
-                                                  codec=self.codec)
-                else:
-                    o, c2 = attn.mla_decode(hn, lp["attn"], cfg, c,
-                                            cache_index, codec=self.codec)
-                h = h + o
-                hn = layers.apply_norm(h, lp["ln2"], cfg.norm)
-                if "moe" in lp:
-                    h = h + moe.apply_moe(hn, lp["moe"], cfg)
-                else:
-                    h = h + moe.apply_ffn(hn, lp["ffn"], cfg.activation)
-                return h, c2
-            # dense prefix layers hold their own caches at the front
-            x, new_cache = self._run_prefix_then_stack(body, x, params,
-                                                       cache)
+                    return attn.mla_decode_paged(hn, ap, cfg, c, cache_index,
+                                                 block_tables, layer,
+                                                 codec=self.codec)
+                return attn.mla_decode(hn, ap, cfg, c, cache_index,
+                                       codec=self.codec)
+            x, new_cache = self._run_cache_layers(attend, x, params, cache,
+                                                  block_tables is not None)
         elif cfg.family == "hybrid":
             new_cache = []
             for lp, kind, st in zip(params["layers"], self._hybrid_kinds(), cache):
@@ -767,28 +807,17 @@ class Model:
                 body, x, params["layers"], (cache["self"], cache["cross"]))
             new_cache = {"self": new_cache[0], "cross": new_cache[1]}
         else:
-            def body(h, inp):
-                lp, c = inp
-                hn = layers.apply_norm(h, lp["ln1"], cfg.norm)
+            def attend(hn, ap, c, layer):
                 if block_tables is not None:
-                    o, c2 = attn.gqa_decode_paged(
-                        hn, lp["attn"], cfg, c, cache_index, block_tables,
+                    return attn.gqa_decode_paged(
+                        hn, ap, cfg, c, cache_index, block_tables, layer,
                         grouped=self.opt.grouped_gqa,
                         impl=self.opt.paged_attn_impl, codec=self.codec)
-                else:
-                    o, c2 = attn.gqa_decode(hn, lp["attn"], cfg, c,
-                                            cache_index,
-                                            grouped=self.opt.grouped_gqa,
-                                            codec=self.codec)
-                h = h + o
-                hn = layers.apply_norm(h, lp["ln2"], cfg.norm)
-                if "moe" in lp:
-                    h = h + moe.apply_moe(hn, lp["moe"], cfg)
-                else:
-                    h = h + moe.apply_ffn(hn, lp["ffn"], cfg.activation)
-                return h, c2
-            x, new_cache = self._run_prefix_then_stack(body, x, params,
-                                                       cache)
+                return attn.gqa_decode(hn, ap, cfg, c, cache_index,
+                                       grouped=self.opt.grouped_gqa,
+                                       codec=self.codec)
+            x, new_cache = self._run_cache_layers(attend, x, params, cache,
+                                                  block_tables is not None)
         return self._unembed(params, x), new_cache
 
     @_with_backend
@@ -830,47 +859,25 @@ class Model:
                 & (positions < cfg.frontend.num_tokens)[..., None]
             x = jnp.where(fm, jnp.zeros_like(x), x)
 
-        if cfg.mla is not None:
-            def body(h, inp):
-                lp, c = inp
-                hn = layers.apply_norm(h, lp["ln1"], cfg.norm)
-                if block_tables is not None:
-                    o, c2 = attn.mla_mixed_paged(hn, lp["attn"], cfg, c,
-                                                 start, n_live, block_tables,
-                                                 codec=self.codec)
-                else:
-                    o, c2 = attn.mla_mixed(hn, lp["attn"], cfg, c,
-                                           start, n_live, codec=self.codec)
-                h = h + o
-                hn = layers.apply_norm(h, lp["ln2"], cfg.norm)
-                if "moe" in lp:
-                    h = h + moe.apply_moe(hn, lp["moe"], cfg)
-                else:
-                    h = h + moe.apply_ffn(hn, lp["ffn"], cfg.activation)
-                return h, c2
-        else:
-            def body(h, inp):
-                lp, c = inp
-                hn = layers.apply_norm(h, lp["ln1"], cfg.norm)
-                if block_tables is not None:
-                    o, c2 = attn.gqa_mixed_paged(
-                        hn, lp["attn"], cfg, c, start, n_live, block_tables,
-                        grouped=self.opt.grouped_gqa,
-                        impl=self.opt.paged_attn_impl, codec=self.codec)
-                else:
-                    o, c2 = attn.gqa_mixed(hn, lp["attn"], cfg, c,
-                                           start, n_live,
-                                           grouped=self.opt.grouped_gqa,
-                                           codec=self.codec)
-                h = h + o
-                hn = layers.apply_norm(h, lp["ln2"], cfg.norm)
-                if "moe" in lp:
-                    h = h + moe.apply_moe(hn, lp["moe"], cfg)
-                else:
-                    h = h + moe.apply_ffn(hn, lp["ffn"], cfg.activation)
-                return h, c2
+        def attend(hn, ap, c, layer):
+            if cfg.mla is not None and block_tables is not None:
+                return attn.mla_mixed_paged(hn, ap, cfg, c, start, n_live,
+                                            block_tables, layer,
+                                            codec=self.codec)
+            if cfg.mla is not None:
+                return attn.mla_mixed(hn, ap, cfg, c, start, n_live,
+                                      codec=self.codec)
+            if block_tables is not None:
+                return attn.gqa_mixed_paged(
+                    hn, ap, cfg, c, start, n_live, block_tables, layer,
+                    grouped=self.opt.grouped_gqa,
+                    impl=self.opt.paged_attn_impl, codec=self.codec)
+            return attn.gqa_mixed(hn, ap, cfg, c, start, n_live,
+                                  grouped=self.opt.grouped_gqa,
+                                  codec=self.codec)
 
-        x, new_cache = self._run_prefix_then_stack(body, x, params, cache)
+        x, new_cache = self._run_cache_layers(attend, x, params, cache,
+                                              block_tables is not None)
         return self._unembed(params, x), new_cache
 
 

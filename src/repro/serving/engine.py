@@ -631,8 +631,10 @@ class ServingEngine:
         self.params = jax.device_put(
             self.params,
             shd.tree_param_shardings(mesh, axes, abstract, strategy))
-        self._cache_shardings = shd.kv_cache_shardings(mesh, self.cache,
-                                                       strategy)
+        arch = self.spec.arch
+        self._cache_shardings = shd.kv_cache_shardings(
+            mesh, self.cache, strategy, kv_heads=arch.num_kv_heads,
+            head_dim=arch.resolved_head_dim)
         self.cache = jax.device_put(self.cache, self._cache_shardings)
         if self.block_tables is not None:
             self.block_tables = jax.device_put(self.block_tables,
@@ -779,7 +781,7 @@ class ServingEngine:
         ids = table_row[:nchunks]
 
         def put(g, o):
-            chunks = o.reshape(o.shape[0], nchunks, bs, *o.shape[3:])
+            chunks = o.reshape(o.shape[0], nchunks, bs, *g.shape[3:])
             return g.at[:, ids].set(chunks)
         return jax.tree.map(put, pool, one_cache)
 

@@ -1,5 +1,6 @@
 import dataclasses
 import os
+import re
 
 # 8 fake CPU devices so the multi-device tests can build real meshes on a
 # single host.  Must be set before jax initializes; single-device tests
@@ -34,3 +35,17 @@ def rng():
 def reduced_cfg(name, lossless_moe=False):
     cfg = reduced(REGISTRY[name])
     return no_drop(cfg) if lossless_moe else cfg
+
+
+# Instructions that move a whole KV pool (or one layer of it) instead of
+# writing it in place: the layer loop's slices, restacks, fresh ys
+# buffers and layout copies.
+POOL_MOVES = {"copy", "dynamic-update-slice", "broadcast", "concatenate"}
+_HLO_INSTR = re.compile(r"= *\w+\[([\d,]*)\]\S* ([\w-]+)\(")
+
+
+def hlo_instructions(text):
+    """(shape, opcode) of every array-valued instruction in compiled HLO
+    text."""
+    return [(tuple(int(d) for d in dims.split(",") if d), op)
+            for dims, op in _HLO_INSTR.findall(text)]

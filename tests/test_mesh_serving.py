@@ -169,10 +169,12 @@ def test_tp2_cache_actually_sharded(params):
     eng = ServingEngine(_spec(mesh=MeshSpec(tp=2)))
     eng.load(params)
     k = jax.tree.leaves(eng.cache)[0]
-    # kv-head axis (-2) is split over the model axis: each device holds
-    # half the heads, and the global shape is unchanged
+    # the pool's kv-major row (-1, n_kv * hd) is split over the model
+    # axis: each device holds half the heads, and the global shape is
+    # unchanged
     shard = k.addressable_shards[0].data
-    assert shard.shape[-2] * 2 == k.shape[-2]
+    assert k.shape[-1] == CFG.num_kv_heads * CFG.resolved_head_dim
+    assert shard.shape[-1] * 2 == k.shape[-1]
     assert len(k.sharding.device_set) == 2
 
 
@@ -191,6 +193,38 @@ def test_mesh_divisibility_falls_back_to_replication():
     cache = [KV(jnp.zeros((2, 4, 8, 3, 16)), jnp.zeros((2, 4, 8, 3, 16)))]
     sh = shd.kv_cache_shardings(mesh, cache, strategy)
     assert sh[0].k.spec == jax.sharding.PartitionSpec()
+
+
+@pytest.mark.parametrize("case", ["hybrid-window", "dense", "paged-merged",
+                                  "paged-one-head"])
+def test_kv_cache_shardings_split_whole_heads(case):
+    """The kv-head axis is found from what each leaf is: [.., kv, hd]
+    leaves (dense rows, a hybrid model's window caches) split axis -2,
+    the paged pool's merged [.., kv * hd] row splits along it, and one
+    KV head cannot split at all."""
+    from repro.core.paging import PagingConfig
+    mesh = shd.tp_mesh(jax.devices()[:2])
+    strategy = shd.strategy_for_mesh(mesh)
+    name = "recurrentgemma-2b" if case == "hybrid-window" else "qwen1.5-0.5b"
+    kv = 1 if case == "paged-one-head" else 2
+    cfg = dataclasses.replace(reduced_cfg(name), num_kv_heads=kv)
+    paging = PagingConfig(block_size=8, num_blocks=16) \
+        if case.startswith("paged") else None
+    cache = Model(cfg).init_cache(4, 64, abstract=True, paging=paging)
+    sh = shd.kv_cache_shardings(mesh, cache, strategy, kv_heads=kv,
+                                head_dim=cfg.resolved_head_dim)
+    kvs = [c for c in (cache if isinstance(cache, list) else [cache])
+           if hasattr(c, "k")]
+    shs = [c for c in (sh if isinstance(sh, list) else [sh])
+           if hasattr(c, "k")]
+    assert kvs
+    P = jax.sharding.PartitionSpec
+    for c, s in zip(kvs, shs):
+        want = {"hybrid-window": P(None, None, "model"),
+                "dense": P(None, None, None, "model"),
+                "paged-merged": P(None, None, None, "model"),
+                "paged-one-head": P()}[case]
+        assert s.k.spec == want and s.v.spec == want, (c.k.shape, s.k.spec)
 
 
 def test_mesh_spec_validation():

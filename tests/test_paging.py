@@ -1,4 +1,5 @@
 """Paged KV-cache subsystem: allocator, kernel, admission, preemption."""
+import dataclasses
 import math
 
 import jax
@@ -10,7 +11,7 @@ from conftest import reduced_cfg
 from repro.core.paging import (NULL_BLOCK, BlockAllocator, PagingConfig,
                                blocks_for_tokens)
 from repro.kernels.paged_attention import paged_decode_attention
-from repro.models.model import Model
+from repro.models.model import Model, ModelOptions
 from repro.serving.engine import ServingEngine
 from repro.serving.sampling import SamplingParams, sample_per_slot
 
@@ -158,8 +159,12 @@ def test_init_cache_pool_shapes():
     model = Model(cfg)
     paging = PagingConfig(block_size=8, num_blocks=12)
     cache = model.init_cache(4, 64, abstract=True, paging=paging)
-    assert cache.k.shape == (cfg.num_layers, 13, 8, cfg.num_kv_heads,
-                             cfg.resolved_head_dim)   # +1 null block row
+    # +1 null block row; heads narrower than 128 lanes share one row
+    assert cache.k.shape == (cfg.num_layers, 13, 8,
+                             cfg.num_kv_heads * cfg.resolved_head_dim)
+    wide = Model(dataclasses.replace(cfg, head_dim=128))
+    cache = wide.init_cache(4, 64, abstract=True, paging=paging)
+    assert cache.k.shape == (cfg.num_layers, 13, 8, cfg.num_kv_heads, 128)
 
 
 def test_init_cache_paged_rejects_ssm():
@@ -345,6 +350,41 @@ def test_mla_paged_matches_dense():
         uid = eng.submit([5, 6, 7], max_new_tokens=5)
         done = eng.run_to_completion()
         streams[layout] = next(r for r in done if r.uid == uid).generated
+    assert streams["dense"] == streams["paged"]
+
+
+@pytest.mark.parametrize("name,kv_dtype,unroll", [
+    ("deepseek-v3-671b", "int8", False),     # MoE dense prefix, MLA codec
+    ("deepseek-v3-671b", "compute", True),
+    ("qwen1.5-0.5b", "int8", False),
+    ("qwen1.5-0.5b", "compute", True),
+])
+def test_paged_matches_dense_streams(name, kv_dtype, unroll):
+    """The paged pool carried through the layer loop (written and read in
+    place at each layer's index) serves the dense layout's greedy
+    streams: with a MoE dense prefix, under the int8 codec, and on the
+    unrolled layer loop."""
+    from repro.core.spec import MemorySpec, RuntimeSpec
+    cfg = reduced_cfg(name, lossless_moe=True)
+    params = Model(cfg).init(jax.random.PRNGKey(0))
+    reqs = [([5, 6, 7], 6), (list(range(1, 20)), 5), ([9, 8], 7)]
+    streams = {}
+    for layout in ("dense", "paged"):
+        if unroll:      # a Model instance keeps its build options
+            eng = ServingEngine(Model(cfg, ModelOptions(unroll_layers=True)),
+                                sampling=SamplingParams(), max_batch=2,
+                                max_len=64, cache_layout=layout,
+                                block_size=8)
+        else:
+            eng = ServingEngine(RuntimeSpec(arch=cfg, memory=MemorySpec(
+                cache_layout=layout, max_batch=2, max_len=64, block_size=8,
+                kv_dtype=kv_dtype)), sampling=SamplingParams())
+        eng.load(params)
+        assert eng.model.opt.unroll_layers == unroll
+        assert eng.model.codec.kv_dtype == kv_dtype
+        uids = [eng.submit(*r) for r in reqs]
+        done = {r.uid: r.generated for r in eng.run_to_completion()}
+        streams[layout] = [done[u] for u in uids]
     assert streams["dense"] == streams["paged"]
 
 
