@@ -1,55 +1,31 @@
 """Model FLOPs of the tokens a run served, from the configuration's shapes.
 
-Only the work a served token needs is counted: the projections and the
-FFN of every prompt token granted and of every decoding slot, attention
-over each token's live context (``4 * heads * head_dim`` per key and
-layer: scores and values), and the vocabulary head once per sampled
+Only the work a served token needs is counted: the weight matmuls of
+every prompt token granted and of every decoding slot, attention over
+each token's live context, and the vocabulary head once per sampled
 token.  Padded lanes, dead slots and the head over prompt lanes the
 sampler never reads do not count, so removing them raises the share of
-peak; the count can never exceed what the device computed.
+peak; the count can never exceed what the device computed.  What one
+token, one key and one head call cost is the architecture's: its family
+module gives ``matmul_per_token``, ``attention_per_key`` and ``head``.
 """
 from __future__ import annotations
 
 from collections import defaultdict
 
 
-def dims(cfg: dict) -> dict:
-    d, h = cfg["hidden_size"], cfg["num_attention_heads"]
-    return {"d": d, "h": h, "kv": cfg["num_key_value_heads"],
-            "hd": cfg.get("head_dim", d // h), "ff": cfg["intermediate_size"],
-            "layers": cfg["num_hidden_layers"], "vocab": cfg["vocab_size"]}
-
-
-def matmul_per_token(cfg: dict) -> int:
-    """Projections and FFN of one token through every layer."""
-    m = dims(cfg)
-    d, hd = m["d"], m["hd"]
-    per_layer = (d * m["h"] * hd + 2 * d * m["kv"] * hd + m["h"] * hd * d
-                 + 3 * d * m["ff"])
-    return 2 * m["layers"] * per_layer
-
-
-def attention_per_key(cfg: dict) -> int:
-    """Scores and weighted values of one query against one key, all layers."""
-    m = dims(cfg)
-    return 4 * m["layers"] * m["h"] * m["hd"]
-
-
-def head(cfg: dict) -> int:
-    m = dims(cfg)
-    return 2 * m["d"] * m["vocab"]
-
-
-def prompt_flops(cfg: dict, plen: int) -> int:
+def prompt_flops(family, cfg: dict, plen: int) -> int:
     """A whole prompt: position p attends to p + 1 keys; one head call
     for the token its last lane samples."""
-    return (matmul_per_token(cfg) * plen
-            + attention_per_key(cfg) * plen * (plen + 1) // 2 + head(cfg))
+    return (family.matmul_per_token(cfg) * plen
+            + family.attention_per_key(cfg) * plen * (plen + 1) // 2
+            + family.head(cfg))
 
 
-def decode_flops(cfg: dict, ctx: int) -> int:
+def decode_flops(family, cfg: dict, ctx: int) -> int:
     """One decoding lane attending ``ctx`` keys."""
-    return matmul_per_token(cfg) + attention_per_key(cfg) * ctx + head(cfg)
+    return (family.matmul_per_token(cfg) + family.attention_per_key(cfg) * ctx
+            + family.head(cfg))
 
 
 def step_flops(rec) -> dict[int, tuple[str, float]]:
@@ -61,7 +37,7 @@ def step_flops(rec) -> dict[int, tuple[str, float]]:
     program.  The engine reports no per-step grant, so a prompt's FLOPs
     are spread evenly over its prefill dispatches.
     """
-    cfg = rec.config
+    cfg, fam = rec.config, rec.family
     span = {}                       # uid -> [first, last] prefill dispatch
     for kind, uid, step, _, _ in rec.events:
         if kind == "admit":
@@ -73,7 +49,7 @@ def step_flops(rec) -> dict[int, tuple[str, float]]:
     for uid, (a, f) in span.items():
         if f is None:
             continue
-        share = prompt_flops(cfg, rec.prompt_len[uid]) / (f - a + 1)
+        share = prompt_flops(fam, cfg, rec.prompt_len[uid]) / (f - a + 1)
         for s in range(a, f + 1):
             per_step[s] += share
             prefilling[s] = True
@@ -87,7 +63,7 @@ def step_flops(rec) -> dict[int, tuple[str, float]]:
         last[uid] = c
         if c0 >= 1 and c > c0:
             per_step[step - 1] += sum(
-                decode_flops(cfg, rec.prompt_len[uid] + k)
+                decode_flops(fam, cfg, rec.prompt_len[uid] + k)
                 for k in range(c0, c))
     out = {}
     for s in {ev[2] - 1 for ev in rec.of("progress")

@@ -4,7 +4,9 @@ Everything that belongs to one configuration, one traffic mix or one
 per-layer metric sits in a file of its own, named after it:
 
 * ``bench/configs/<config>.json``   sizes and the deployment;
-* ``bench/reference/<name>.py``     the plain reference a config names;
+* ``bench/families/<name>.py``      the architecture a config names under
+  ``reference``: spec, weights, bytes and FLOPs (``bench/families``);
+* ``bench/reference/<name>.py``     its plain reference;
 * ``bench/traffic/<traffic>.json``  the parameters of one mix;
 * ``bench/limits/<cell>.json``      what ``correct`` holds a cell to;
 * ``bench/metrics/<metric>.py``     a reader with ``read(rec)``; a
@@ -36,6 +38,7 @@ class Cell:
     end_to_end: list[dict]     # the manifest's metrics this cell reports
     per_layer: list[dict]
     limits: dict               # compared number -> its limit
+    root: Path = CHECKOUT      # where its files were found
 
 
 def load_manifest(root: Path = CHECKOUT) -> dict:
@@ -61,7 +64,8 @@ def load_cell(name: str, root: Path = CHECKOUT) -> Cell:
         (root / "bench" / "limits" / f"{name}.json").read_text())
     e2e = [m for m in man["end_to_end"] if _reports(m, name)]
     layer = [m for m in man["per_layer"] if _reports(m, name)]
-    return Cell(name, wl["chips"], config, traffic, e2e, layer, limits)
+    return Cell(name, wl["chips"], config, traffic, e2e, layer, limits,
+                root)
 
 
 def _load_module(path: Path, label: str):
@@ -86,3 +90,9 @@ def reference_module(name: str, root: Path = CHECKOUT):
     """The plain reference a configuration names (``config["reference"]``)."""
     return _load_module(root / "bench" / "reference" / f"{name}.py",
                         f"bench_reference_{name}")
+
+
+def family_module(name: str, root: Path = CHECKOUT):
+    """The architecture family a configuration names under ``reference``."""
+    return _load_module(root / "bench" / "families" / f"{name}.py",
+                        f"bench_family_{name}")

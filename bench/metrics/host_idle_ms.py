@@ -1,6 +1,6 @@
-"""Device-idle ms per engine step under the host's own phases:
-``engine.admit``, ``engine.capacity``, ``engine.dispatch``, or
-``engine.harvest`` outside its reads from the device."""
+"""Device-idle ms per engine step while the host was inside
+``ServingEngine.step``: the round trip between two step programs (the
+harvest's read-back, admission, reservation and dispatch)."""
 from bench.program_trace import host_idle_ms
 
 

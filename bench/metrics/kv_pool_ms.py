@@ -1,5 +1,6 @@
-"""Device ms per step-program execution under the paged KV pool's
-scopes (``attn.kv_write``, ``attn.kv_gather``), self time."""
+"""Device ms per execution of a step program (the mean over the mixed
+and the decode step) under the paged KV pool's scopes
+(``attn.kv_write``, ``attn.kv_gather``), self time."""
 from bench.program_trace import KV_POOL, scope_ms
 
 

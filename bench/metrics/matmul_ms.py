@@ -1,5 +1,6 @@
-"""Device ms per step-program execution under the weight matmuls'
-scopes (``attn.qkv``, ``attn.out``, ``ffn``, ``head``), self time."""
+"""Device ms per execution of a step program (the mean over the mixed
+and the decode step) under the weight matmuls' scopes (``attn.qkv``,
+``attn.out``, ``ffn``, ``head``), self time."""
 from bench.program_trace import MATMUL, scope_ms
 
 

@@ -1,5 +1,5 @@
-"""Device ms per step-program execution in ops under no named scope,
-self time."""
+"""Device ms per execution of a step program (the mean over the mixed
+and the decode step) in ops under no named scope, self time."""
 from bench.program_trace import unscoped_ms
 
 

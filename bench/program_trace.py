@@ -14,30 +14,32 @@ marks the serving program leaves itself (``repro.serving.events``):
   ``op_name`` metadata, or to ``unscoped``;
 * the device's idle time, split by the innermost host span over it.
 
-Everything below ``load`` works on plain tuples
-``(plane, line, name, start_ns, duration_ns, stats)``, so a hand-built
-list checks the arithmetic without a trace file.  Nothing here imports
-the program: the scope names come in as an argument.
+Everything here but ``load_hlo`` works on plain tuples
+``(plane, line, name, start_ns, duration_ns, stats)``
+(``bench.trace.load_events``), so a hand-built list checks the
+arithmetic without a trace file.  Nothing here imports the program: the
+scope names come in as an argument.  ``bench/run.py --trace 1`` keeps
+the reduction in its record (``Record.program_trace``), where the
+metric files read it.
 
     python -m bench.program_trace --workload <cell> --seed <n> --seconds <s>
 
-runs the cell as ``bench/run.py --trace 1`` does, reduces the trace
-before the run removes it, and prints the result line with the
-end-to-end metrics of the traced run and the metrics below added.
+runs the cell as ``bench/run.py --trace 1`` does and reports the
+end-to-end metrics of the traced run beside the per-layer ones, so that
+a traced and an untraced run of one seed give the cost of tracing.
 """
 from __future__ import annotations
 
 import bisect
-import glob
 import re
 from collections import defaultdict
 from dataclasses import dataclass
+from pathlib import Path
 
-from bench.trace import (DEVICE_PREFIX, MODULES, OPS, WINDOW_SPAN, clip,
-                         length, merge)
+from bench.trace import (DEVICE_PREFIX, MODULES, WINDOW_SPAN, clip, length,
+                         merge, self_times)
 
 STEP_PROGRAMS = ("_mixed_impl", "_decode_impl")
-HOST_PREFIXES = ("engine.", "bench.")
 # the op stats that carry the HLO ``op_name`` metadata
 OP_NAME_STATS = ("tf_op", "op_name")
 UNSCOPED = "unscoped"
@@ -46,31 +48,13 @@ UNSCOPED = "unscoped"
 KV_POOL = ("attn.kv_write", "attn.kv_gather")
 ATTN_CORE = ("attn.core",)
 MATMUL = ("attn.qkv", "attn.out", "ffn", "head")
-HOST_PHASES = ("engine.admit", "engine.capacity", "engine.dispatch",
-               "engine.harvest")
-
-
-def load(trace_dir: str) -> list[tuple]:
-    """The device planes' module and op events and the host's
-    ``engine.*``/``bench.*`` spans of the newest ``.xplane.pb`` under
-    ``trace_dir``, each with its stats."""
-    from jax.profiler import ProfileData
-
-    paths = sorted(glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True))
-    if not paths:
-        raise RuntimeError(f"no trace written under {trace_dir}")
-    out = []
-    for plane in ProfileData.from_file(paths[-1]).planes:
-        device = plane.name.startswith(DEVICE_PREFIX)
-        for line in plane.lines:
-            if device and line.name not in (MODULES, OPS):
-                continue
-            for ev in line.events:
-                if device or ev.name.startswith(HOST_PREFIXES):
-                    out.append((plane.name, line.name, ev.name,
-                                float(ev.start_ns), float(ev.duration_ns),
-                                {k: str(v) for k, v in ev.stats}))
-    return out
+# every span of ``ServingEngine.step``: the idle gap between two step
+# programs opens under the harvest's read-back and closes in the next
+# dispatch, and the profiler's host and device clocks can place its two
+# ends up to ~0.7 ms apart from one run to the next (PERF.md, section 5)
+ENGINE_STEP = ("engine.step", "engine.admit", "engine.capacity",
+               "engine.dispatch", "engine.harvest", "engine.harvest.wait",
+               "engine.harvest.fetch")
 
 
 def hlo_op_names(text: str) -> dict[str, str]:
@@ -79,6 +63,15 @@ def hlo_op_names(text: str) -> dict[str, str]:
     return dict(re.findall(
         r'^\s*(?:ROOT )?%?([\w.\-]+) = [^\n]*?op_name="([^"]*)"', text,
         re.M))
+
+
+def load_hlo(dump_dir: Path) -> dict[str, dict[str, str]]:
+    """Step program -> ``hlo_op_names`` of the optimized HLO that XLA
+    wrote under ``dump_dir`` when it compiled the program."""
+    return {prog: {k: v for path in sorted(dump_dir.glob(
+                f"*{prog}*after_optimizations.txt"))
+                for k, v in hlo_op_names(path.read_text()).items()}
+            for prog in STEP_PROGRAMS}
 
 
 def op_name_of(name: str, stats: dict, hlo: dict) -> str:
@@ -97,28 +90,6 @@ def innermost_scope(op_name: str, scopes) -> str:
         if part in scopes:
             return part
     return UNSCOPED
-
-
-def self_times(ops) -> list[float]:
-    """Self time of each ``(start, end)`` op of one device line: its
-    length less the union of the ops nested in it."""
-    order = sorted(range(len(ops)), key=lambda i: (ops[i][0], -ops[i][1]))
-    own = [e - s for s, e in ops]
-    covered = [s for s, _ in ops]     # each op's children cover up to here
-    stack: list[int] = []
-    for i in order:
-        s, e = ops[i]
-        while stack and ops[stack[-1]][1] <= s:
-            stack.pop()
-        if stack:
-            p = stack[-1]
-            hi = min(e, ops[p][1])
-            lo = max(s, covered[p])
-            if hi > lo:
-                own[p] -= hi - lo
-                covered[p] = hi
-        stack.append(i)
-    return own
 
 
 def label_idle(idle, spans, outside: str = "host") -> dict[str, float]:
@@ -170,13 +141,15 @@ class ProgramTrace:
     no_op_name_ns: float = 0.0   # step-program self time of ops with no
                                  # op_name at all (part of unscoped)
 
-    def scope_ms(self, scopes) -> float | None:
-        """Self ms under ``scopes`` per step-program execution."""
-        n = sum(self.executions.values())
-        if not n:
-            return None
-        ns = sum(v for (_, sc), v in self.self_ns.items() if sc in scopes)
-        return ns / n / 1e6
+    def scope_ms(self, scopes, program: str | None = None) -> float | None:
+        """Self ms under ``scopes`` per execution of ``program``; with no
+        program, the mean of that over the step programs that ran, so that
+        each program counts alike whatever mix of them a seed drew."""
+        ms = [sum(v for (p, sc), v in self.self_ns.items()
+                  if p == prog and sc in scopes) / n / 1e6
+              for prog, n in self.executions.items()
+              if n and program in (None, prog)]
+        return sum(ms) / len(ms) if ms else None
 
     def idle_ms_per_step(self, labels) -> float | None:
         if not self.steps or not self.idle_ns:    # no step, or no device
@@ -266,10 +239,26 @@ def unscoped_ms(pt: ProgramTrace | None) -> float | None:
 
 
 def host_idle_ms(pt: ProgramTrace | None) -> float | None:
-    """Device-idle ms per engine step while the host was in a phase of
-    its own (admission, reservation, dispatch, the harvest's Python)
-    rather than waiting on the device or reading tokens back."""
-    return None if pt is None else pt.idle_ms_per_step(HOST_PHASES)
+    """Device-idle ms per engine step while the host was inside
+    ``ServingEngine.step``: the round trip from one step program's end to
+    the next one's start (reading back, admission, reservation, dispatch),
+    whatever span the trace puts each part of the gap under."""
+    return None if pt is None else pt.idle_ms_per_step(ENGINE_STEP)
+
+
+def roofline_share(pt: ProgramTrace | None, scopes, flops: float,
+                   bytes: float, peaks: dict) -> float | None:
+    """Share of its roofline, in %, that the ops under ``scopes`` reach:
+    the least time the chip needs for ``flops`` operations and ``bytes``
+    of HBM traffic per step-program execution (the larger of flops over
+    the bf16 peak and bytes over HBM bandwidth), over the scopes' device
+    self time per execution; both averaged over the step programs alike,
+    as ``scope_ms`` does.  ``None`` where no such op ran."""
+    ms = scope_ms(pt, scopes)
+    if not ms:
+        return None
+    least = max(flops / peaks["bf16_flops"], bytes / peaks["hbm_bytes_per_s"])
+    return 100.0 * least / (ms / 1e3)
 
 
 def diag(pt: ProgramTrace) -> list[str]:
@@ -305,63 +294,19 @@ def diag(pt: ProgramTrace) -> list[str]:
 def main(argv=None) -> None:
     import argparse
     import dataclasses
-    import json
-    import os
-    import sys
+
+    from bench import run
+    from bench.manifest import load_cell
 
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     args = ap.parse_args(argv)
-
-    from bench import run, trace
-    from bench.manifest import load_cell
-    from repro.serving.events import SCOPE_NAMES
-
-    # the step programs' optimized HLO, written when they compile in the
-    # set-up, names the ops of a trace whose op events carry no op_name
-    dump = run.CACHE / "hlo"
-    os.environ["XLA_FLAGS"] = " ".join([
-        os.environ.get("XLA_FLAGS", ""), f"--xla_dump_to={dump}",
-        "--xla_dump_hlo_as_text",
-        "--xla_dump_hlo_module_re=.*(" + "|".join(STEP_PROGRAMS) + ").*"])
-
-    # the traced run reads its end-to-end metrics too, so that a traced
-    # and an untraced run of one seed give the cost of tracing
     cell = load_cell(args.workload)
-    cell = dataclasses.replace(cell, per_layer=cell.per_layer
-                               + cell.end_to_end)
-    got = {}
-    outside = trace.load_events
-
-    def load_both(trace_dir: str):
-        hlo = {prog: {k: v for path in sorted(dump.glob(
-                   f"*{prog}*after_optimizations.txt"))
-                   for k, v in hlo_op_names(path.read_text()).items()}
-               for prog in STEP_PROGRAMS}
-        got["pt"] = reduce_program(load(trace_dir), SCOPE_NAMES, hlo)
-        return outside(trace_dir)
-
-    trace.load_events = load_both
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    run.use_cache()
-    device, _ = run.find_chips(cell.chips)
-    result, checks, lines = run.run_cell(cell, args.seed, args.seconds, True,
-                                         device.device_kind)
-    pt = got["pt"]
-    new = {"kv_pool_ms": pt.scope_ms(KV_POOL),
-           "attn_core_ms": pt.scope_ms(ATTN_CORE),
-           "matmul_ms": pt.scope_ms(MATMUL),
-           "unscoped_ms": unscoped_ms(pt),
-           "host_idle_ms": host_idle_ms(pt)}
-    for line in lines + diag(pt):
-        print(line, file=sys.stderr)
-    for name, c in checks.items():
-        print(f"{name} {c['value']} limit {c['limit']}", file=sys.stderr)
-    print(json.dumps({"correct": result["correct"],
-                      "metrics": result["metrics"], "program": new,
-                      "breakdown": result["breakdown"]}))
+    run.serve(dataclasses.replace(cell, per_layer=cell.per_layer
+                                  + cell.end_to_end),
+              args.seed, args.seconds, trace=True)
 
 
 if __name__ == "__main__":

@@ -7,8 +7,9 @@ RMSNorm -> SwiGLU FFN -> residual; a final RMSNorm and the vocabulary
 head (the embedding itself where tied).  No cache, no paging, no
 batching: one packed row of whole sequences at a time, each token
 attending to the earlier tokens of its own sequence.  Weights are the
-benchmark's (``bench.weights.make``), read in float32 with matmuls at
-the highest precision.  Imports nothing of the serving program.
+benchmark's (``bench.families.qwen2_dense.make``), read in float32 with
+matmuls at the highest precision.  Imports nothing of the serving
+program.
 """
 from __future__ import annotations
 

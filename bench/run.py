@@ -4,16 +4,21 @@
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell (``BENCHMARK.json``) names a configuration file and a traffic
-mix.  One process, on the chip it finds: it makes the weights from the
-seed, builds ``ServingEngine`` from the configuration, warms every
-program the cell's traffic will run, then drives ``submit`` and
-``step`` for ``--seconds`` and reads the end-to-end metrics (``--trace
-0``) or, from a profiler trace of the window, the per-layer metrics
-(``--trace 1``).  After the window it frees the program's state and
-checks a sample of the served tokens against the plain reference
-(``bench/check.py``).  The last line of standard output is one JSON
-object; the last lines of standard error give each number compared
-beside its limit.
+mix; the configuration names its architecture family
+(``bench/families``), which gives the program's spec, the weights and
+their parameter tree.  One process, on the chip it finds: it makes the
+weights from the seed, builds ``ServingEngine``, warms every program the
+cell's traffic will run, then drives ``submit`` and ``step`` for
+``--seconds`` and reads the end-to-end metrics (``--trace 0``) or, from a
+profiler trace of the window, the per-layer metrics (``--trace 1``): the
+trace is reduced both from outside (``bench/trace.py``) and by the
+program's own spans and named scopes (``bench/program_trace.py``), whose
+step-program ops are named from the optimized HLO that XLA dumps when the
+traced run compiles them (``compile_steps_here``).  After the window it frees
+the program's state and checks a sample of the served tokens against
+the plain reference (``bench/check.py``).  The last line of standard
+output is one JSON object; the last lines of standard error give each
+number compared beside its limit.
 
 It exits non-zero, printing no result, where JAX finds no TPU or fewer
 chips than the cell asks for.  Options the driver does not pass:
@@ -37,11 +42,12 @@ from pathlib import Path  # noqa: E402
 
 CHECKOUT = Path(__file__).resolve().parents[1]
 CACHE = CHECKOUT / ".bench_cache"
+SHARED = CACHE / "jax"   # the compile cache every run of the checkout reads
 sys.path[:0] = [str(CHECKOUT), str(CHECKOUT / "src")]
 
-from bench import check, stats, traffic  # noqa: E402
-from bench.manifest import (Cell, load_cell, metric_reader,  # noqa: E402
-                            reference_module)
+from bench import check, program_trace, stats, traffic, weights  # noqa: E402
+from bench.manifest import (Cell, family_module, load_cell,  # noqa: E402
+                            metric_reader, reference_module)
 
 HARVEST_WIDTHS = 5   # finished streams per harvest whose gathers are warmed
 WARM_STEPS = 16      # the warm-up request needs 4 (2 mixed, 2 decode)
@@ -73,15 +79,51 @@ class CompileMeter:
             self.compiles += 1
 
 
-def use_cache() -> None:
-    """JAX's persistent compilation cache at a fixed path in the checkout,
-    whatever the environment says; every program is kept."""
+def use_cache(path: Path) -> None:
+    """JAX's persistent compilation cache at ``path``, a fixed path in the
+    checkout, whatever the environment says; every program is kept."""
     import jax
-    path = str(CACHE / "jax")
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = path
-    jax.config.update("jax_compilation_cache_dir", path)
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(path)
+    jax.config.update("jax_compilation_cache_dir", str(path))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+
+
+def traced_dir(cell: Cell) -> Path:
+    """A traced run's own files: the profiler trace, the optimized HLO of
+    the step programs it compiles, and its view of the compile cache."""
+    return CACHE / "traced" / cell.name
+
+
+def compile_steps_here(own: Path, shared: Path) -> Path:
+    """Set a traced run up to compile its two step programs itself, with
+    XLA dumping their optimized HLO under ``own / "hlo"``: a program loaded
+    from the compile cache writes no dump, and its ops would go unnamed.
+    The run reads the ``shared`` cache through ``own / "jax"``, links to
+    every entry but the step programs'; returns that view.  Call it before
+    JAX starts its backend."""
+    os.environ["XLA_FLAGS"] = " ".join([
+        os.environ.get("XLA_FLAGS", ""), f"--xla_dump_to={own / 'hlo'}",
+        "--xla_dump_hlo_as_text",
+        "--xla_dump_hlo_module_re=.*("
+        + "|".join(program_trace.STEP_PROGRAMS) + ").*"]).strip()
+    view = own / "jax"
+    view.mkdir(parents=True)
+    steps = tuple(f"jit_{p}-" for p in program_trace.STEP_PROGRAMS)
+    for path in shared.glob("*"):
+        if not path.name.startswith(steps):
+            (view / path.name).symlink_to(path.resolve())
+    return view
+
+
+def adopt(view: Path, shared: Path) -> None:
+    """Move what a traced run compiled into its view of the cache over to
+    the shared cache, where the checkout's other runs find it.  The dump
+    flags are not part of a cache key, so the entries are the same."""
+    shared.mkdir(parents=True, exist_ok=True)
+    for path in view.iterdir():
+        if not path.is_symlink() and not (shared / path.name).exists():
+            path.replace(shared / path.name)
 
 
 def find_chips(n: int):
@@ -92,41 +134,6 @@ def find_chips(n: int):
             f"{devs[0].platform} device(s), the cell needs {n} TPU chip(s)")
         raise SystemExit(2)
     return devs[0], len(devs)
-
-
-def build_spec(cfg: dict, control: str | None):
-    from repro.configs.base import ArchConfig
-    from repro.core.spec import ExecutionSpec, MemorySpec, RuntimeSpec
-
-    d, h = cfg["hidden_size"], cfg["num_attention_heads"]
-    arch = ArchConfig(
-        name=cfg["name"], family="dense",
-        num_layers=cfg["num_hidden_layers"], d_model=d, num_heads=h,
-        num_kv_heads=cfg["num_key_value_heads"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        head_dim=cfg.get("head_dim", d // h), activation="swiglu",
-        norm="rmsnorm", qkv_bias=True, rope_theta=cfg["rope_theta"],
-        tie_embeddings=cfg["tie_word_embeddings"],
-        max_position_embeddings=cfg["max_position_embeddings"],
-        source=cfg["source"])
-    sv = cfg["serving"]
-    return RuntimeSpec(
-        arch=arch,
-        execution=ExecutionSpec(param_dtype=sv["param_dtype"],
-                                compute_dtype=sv["compute_dtype"],
-                                quant=control or "none"),
-        memory=MemorySpec(cache_layout="paged", max_batch=sv["max_batch"],
-                          max_len=sv["max_len"], block_size=sv["block_size"],
-                          kv_dtype="compute"))
-
-
-def make_weights(cfg: dict, seed: int):
-    import jax
-
-    from bench import weights
-    dtype = weights.DTYPES[cfg["serving"]["param_dtype"]]
-    return jax.jit(lambda k: weights.make(cfg, k, dtype))(
-        weights.seed_key(seed))
 
 
 def warm_up(engine, mix: dict, vocab: int) -> None:
@@ -253,11 +260,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     object without its ``device`` entry, and the stderr diagnostics."""
     import jax
 
-    from bench import weights
     from bench.trace import load_events, reduce_events
     from repro.models.model import Model
     from repro.serving.engine import ServingEngine
-    from repro.serving.events import EventLog
+    from repro.serving.events import SCOPE_NAMES, EventLog
     from repro.serving.sampling import SamplingParams
 
     cfg, mix = cell.config, dict(cell.traffic)
@@ -275,16 +281,12 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                              "bench/peaks.json")
         peaks = table[device_kind]
     meter = CompileMeter()
-    spec = build_spec(cfg, control)
-    w = make_weights(cfg, seed)
-    params = weights.to_program(cfg, w)
-    want = Model.from_spec(spec).abstract()
-    got = jax.eval_shape(lambda p: p, params)
-    if jax.tree.structure(want) != jax.tree.structure(got) or any(
-            (a.shape, a.dtype) != (b.shape, b.dtype)
-            for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got))):
-        raise SystemExit("the benchmark's weights do not fit the program's "
-                         "parameter tree")
+    fam = family_module(cfg["reference"], cell.root)
+    spec = fam.spec(cfg, control)
+    w = weights.from_seed(fam, cfg, seed)
+    params = fam.to_program(cfg, w)
+    weights.check_tree(Model.from_spec(spec).abstract(),
+                       jax.eval_shape(lambda p: p, params))
     engine = ServingEngine(spec, sampling=SamplingParams(temperature=0.0))
     engine.load(params)
     del w, params
@@ -294,7 +296,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     evlog = EventLog()
     engine.events.subscribe(evlog)
     served: dict = {}
-    trace_dir = CACHE / "trace" / cell.name
+    trace_dir = traced_dir(cell) / "trace"
     if trace:
         shutil.rmtree(trace_dir, ignore_errors=True)
         opts = jax.profiler.ProfileOptions()
@@ -320,7 +322,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         t0=t0, t1=t1, t_drained=t_drained, loop=mix["loop"],
         max_batch=sv["max_batch"],
         config=cfg, peaks=peaks, setup_s=setup_end - T_START,
-        pool_tokens=pool)
+        pool_tokens=pool, family=fam)
     diag = [f"setup: {setup_end - T_START:.3f} s to the window, of which "
             f"{setup_compile_s:.3f} s tracing/compiling "
             f"({setup_compiles} compilations)",
@@ -333,8 +335,13 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                     f"{stats.percentile(late, 50):.6f} p99 "
                     f"{stats.percentile(late, 99):.6f} max {max(late):.6f}")
     if trace:
-        rec.trace = reduce_events(load_events(str(trace_dir)))
+        events = load_events(str(trace_dir))
         shutil.rmtree(trace_dir, ignore_errors=True)
+        rec.trace = reduce_events(events)
+        rec.program_trace = program_trace.reduce_program(
+            events, SCOPE_NAMES,
+            program_trace.load_hlo(traced_dir(cell) / "hlo"))
+        del events
         progs: dict = {}
         for _, name, _, d in rec.trace.modules:
             progs[name] = progs.get(name, 0.0) + d / 1e9
@@ -342,11 +349,12 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                     + "; programs by device seconds: " + ", ".join(
                         f"{n} {s:.4f}" for n, s in sorted(
                             progs.items(), key=lambda kv: -kv[1])[:6]))
+        diag += program_trace.diag(rec.program_trace)
     names = [m["name"] for m in (cell.per_layer if trace else cell.end_to_end)]
     metrics = {}
     units = {m["name"]: m["unit"] for m in cell.per_layer + cell.end_to_end}
     for name in names:
-        v = metric_reader(name)(rec)
+        v = metric_reader(name, cell.root)(rec)
         if v is not None:
             metrics[name] = {"value": v, "unit": units[name]}
 
@@ -362,8 +370,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     t_ref = time.perf_counter()
     gaps: dict = {}
     if sample:
-        ref = reference_module(cfg["reference"])
-        w = make_weights(cfg, seed)
+        ref = reference_module(cfg["reference"], cell.root)
+        w = weights.from_seed(fam, cfg, seed)
         fn = jax.jit(lambda w, *a: ref.gaps(cfg, w, *a)[0])
         gaps = check.read_gaps(check.pack(sample, sv["max_len"]),
                                lambda *a: fn(w, *a))
@@ -395,29 +403,29 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     return result, checks, diag
 
 
-def main(argv=None) -> None:
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--workload", required=True)
-    ap.add_argument("--seed", type=int, required=True)
-    ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
-    ap.add_argument("--rate", type=float, default=None,
-                    help="override an open loop's arrival rate (req/s)")
-    ap.add_argument("--control", choices=("int8",), default=None,
-                    help="serve through the program's int8 weight path")
-    args = ap.parse_args(argv)
-    cell = load_cell(args.workload)
+def serve(cell: Cell, seed: int, seconds: float, trace: bool,
+          control: str | None = None, rate: float | None = None) -> None:
+    """One run of ``cell`` on the chip this process finds: the result
+    line on standard output, the diagnostics and each number compared
+    beside its limit as the last lines of standard error."""
     # the TPU runtime logs to /tmp unless told otherwise
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    use_cache()
-    device, count = find_chips(cell.chips)
-    result, checks, diag = run_cell(
-        cell, args.seed, args.seconds, bool(args.trace), device.device_kind,
-        control=args.control, rate=args.rate)
+    own = traced_dir(cell)
+    shutil.rmtree(own, ignore_errors=True)
+    use_cache(compile_steps_here(own, SHARED) if trace else SHARED)
+    try:
+        device, count = find_chips(cell.chips)
+        result, checks, diag = run_cell(
+            cell, seed, seconds, trace, device.device_kind,
+            control=control, rate=rate)
+    finally:
+        if trace:
+            adopt(own / "jax", SHARED)
+        shutil.rmtree(own, ignore_errors=True)
     dev = {"platform": device.platform, "kind": device.device_kind,
            "count": count,
            "memory_peak_bytes": result.pop("memory_peak_bytes")}
-    if args.trace:
+    if trace:
         dev["busy_s"], dev["window_s"] = result.pop("busy_s"), \
             result.pop("window_s")
     out = {"correct": result["correct"], "attempted": result["attempted"],
@@ -431,6 +439,21 @@ def main(argv=None) -> None:
     for name, c in checks.items():
         log(f"{name} {c['value']} limit {c['limit']}")
     print(json.dumps(out))
+
+
+def main(argv=None) -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--rate", type=float, default=None,
+                    help="override an open loop's arrival rate (req/s)")
+    ap.add_argument("--control", choices=("int8",), default=None,
+                    help="serve through the program's int8 weight path")
+    args = ap.parse_args(argv)
+    serve(load_cell(args.workload), args.seed, args.seconds,
+          bool(args.trace), control=args.control, rate=args.rate)
 
 
 if __name__ == "__main__":

@@ -41,6 +41,9 @@ class Record:
     max_batch: int
     config: dict            # the configuration file
     trace: object = None    # bench.trace.Reduced in a --trace 1 run
+    # bench.program_trace.ProgramTrace of the same trace
+    program_trace: object = None
+    family: object = None   # the configuration's bench/families module
     peaks: dict = field(default_factory=dict)   # bench/peaks.json entry
     setup_s: float = 0.0    # process start to the end of warm-up
     # tokens resident in the KV pool at t0 and t1 (engine.memory_stats)

@@ -8,9 +8,9 @@ events come from the TPU planes: ``XLA Modules`` holds one event per
 execution of a compiled program (``jit_<name>(...)``), ``XLA Ops`` one
 per operation.
 
-Everything here works on plain event tuples
-``(plane, line, name, start_ns, duration_ns)``, so a hand-built list
-checks the arithmetic without a trace file.
+Everything below ``load_events`` works on plain event tuples
+``(plane, line, name, start_ns, duration_ns[, stats])``, so a hand-built
+list checks the arithmetic without a trace file.
 """
 from __future__ import annotations
 
@@ -23,24 +23,29 @@ DEVICE_PREFIX = "/device:TPU:"
 MODULES, OPS = "XLA Modules", "XLA Ops"
 WINDOW_SPAN = "bench.window"
 SLEEP_SPAN = "bench.sleep"
+HOST_PREFIXES = ("engine.", "bench.")
 
 
 def load_events(trace_dir: str) -> list[tuple]:
-    """Every event of the newest ``.xplane.pb`` under ``trace_dir``."""
+    """The device planes' module and op events and the host's
+    ``engine.*``/``bench.*`` spans of the newest ``.xplane.pb`` under
+    ``trace_dir``, each with its stats."""
     from jax.profiler import ProfileData
 
     paths = sorted(glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True))
     if not paths:
         raise RuntimeError(f"no trace written under {trace_dir}")
-    data = ProfileData.from_file(paths[-1])
     out = []
-    for plane in data.planes:
-        keep_all = plane.name.startswith(DEVICE_PREFIX)
+    for plane in ProfileData.from_file(paths[-1]).planes:
+        device = plane.name.startswith(DEVICE_PREFIX)
         for line in plane.lines:
+            if device and line.name not in (MODULES, OPS):
+                continue
             for ev in line.events:
-                if keep_all or ev.name.startswith("bench."):
+                if device or ev.name.startswith(HOST_PREFIXES):
                     out.append((plane.name, line.name, ev.name,
-                                float(ev.start_ns), float(ev.duration_ns)))
+                                float(ev.start_ns), float(ev.duration_ns),
+                                {k: str(v) for k, v in ev.stats}))
     return out
 
 
@@ -88,6 +93,28 @@ def intersect(a, b) -> list[tuple[float, float]]:
     return out
 
 
+def self_times(ops) -> list[float]:
+    """Self time of each ``(start, end)`` op of one device line: its
+    length less the union of the ops nested in it."""
+    order = sorted(range(len(ops)), key=lambda i: (ops[i][0], -ops[i][1]))
+    own = [e - s for s, e in ops]
+    covered = [s for s, _ in ops]     # each op's children cover up to here
+    stack: list[int] = []
+    for i in order:
+        s, e = ops[i]
+        while stack and ops[stack[-1]][1] <= s:
+            stack.pop()
+        if stack:
+            p = stack[-1]
+            hi = min(e, ops[p][1])
+            lo = max(s, covered[p])
+            if hi > lo:
+                own[p] -= hi - lo
+                covered[p] = hi
+        stack.append(i)
+    return own
+
+
 @dataclass
 class Reduced:
     """One traced window, in nanoseconds on the trace's clock."""
@@ -96,7 +123,8 @@ class Reduced:
     devices: list[str]
     busy: dict            # device -> merged busy intervals in the window
     modules: list[tuple]  # (device, name, start, duration) in the window
-    ops: dict             # op name -> total ns, summed over devices
+    ops: dict             # op name -> self ns in the window, summed
+                          # over devices (a loop less the ops in it)
     spans: list[tuple]    # (name, start, end) host spans of the benchmark
 
     @property
@@ -156,6 +184,7 @@ class Reduced:
         return named
 
     def top_ops(self, top: int = 10) -> list[list]:
+        """The ops with the most self time, in seconds per device."""
         n_dev = max(len(self.devices), 1)
         best = sorted(self.ops.items(), key=lambda kv: -kv[1])[:top]
         return [[name, ns / n_dev / 1e9] for name, ns in best]
@@ -163,7 +192,7 @@ class Reduced:
 
 def reduce_events(events: list[tuple]) -> Reduced:
     """Cut the events to the ``bench.window`` span and reduce them."""
-    spans = [(n, s, s + d) for _, _, n, s, d in events
+    spans = [(n, s, s + d) for _, _, n, s, d, *_ in events
              if n.startswith("bench.")]
     win = [(s, e) for n, s, e in spans if n == WINDOW_SPAN]
     if not win:
@@ -172,18 +201,23 @@ def reduce_events(events: list[tuple]) -> Reduced:
     devices = sorted({p for p, *_ in events if p.startswith(DEVICE_PREFIX)})
     op_iv, mod_iv = defaultdict(list), defaultdict(list)
     modules, ops = [], defaultdict(float)
-    for plane, line, name, s, d in events:
+    for plane, line, name, s, d, *_ in events:
         if not plane.startswith(DEVICE_PREFIX):
             continue
         if line == OPS:
-            op_iv[plane].append((s, s + d))
-            if s < hi and s + d > lo:
-                ops[op_name(name)] += min(s + d, hi) - max(s, lo)
+            op_iv[plane].append((s, s + d, name))
         elif line == MODULES:
             mod_iv[plane].append((s, s + d))
             if lo <= s < hi:
                 modules.append((plane, name, s, d))
+    for plane in devices:
+        inside = [(max(s, lo), min(e, hi), n) for s, e, n in op_iv[plane]
+                  if s < hi and e > lo]
+        for (_, _, name), t in zip(inside, self_times(
+                [(s, e) for s, e, _ in inside])):
+            ops[op_name(name)] += t
     # busy is the union of operations; a plane without an ops line
     # falls back to its program executions
-    busy = {p: clip(merge(op_iv[p] or mod_iv[p]), lo, hi) for p in devices}
+    busy = {p: clip(merge([(s, e) for s, e, _ in op_iv[p]] or mod_iv[p]),
+                    lo, hi) for p in devices}
     return Reduced((lo, hi), devices, busy, modules, dict(ops), spans)

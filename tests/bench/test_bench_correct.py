@@ -12,6 +12,7 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
 
 import json
+import os
 
 import jax.numpy as jnp
 import pytest
@@ -99,6 +100,115 @@ def test_a_broken_step_is_not_correct(monkeypatch, fault):
                             _altered_token(engine_mod.sample_per_slot))
     res, checks = _run()
     assert not res["correct"], (fault, checks)
+
+
+def test_a_traced_run_keeps_the_program_trace(monkeypatch, tmp_path):
+    import jax
+
+    from bench import stats, trace
+    from test_bench_program_trace import _events
+
+    # the profiler is stubbed; the window's trace is the hand-built one
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda *a, **k: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    monkeypatch.setattr(trace, "load_events", lambda _: _events())
+    monkeypatch.setattr(run, "CACHE", tmp_path)
+    kept = []
+
+    class Kept(stats.Record):
+        def __init__(self, *a, **kw):
+            super().__init__(*a, **kw)
+            kept.append(self)
+    monkeypatch.setattr(stats, "Record", Kept)
+    res, _, diag = run.run_cell(_cell(), SEED, 3.0, True, "cpu",
+                                peaks={"bf16_flops": 1e12})
+    assert kept[0].program_trace.executions == {"_mixed_impl": 1}
+    got = {k: v["value"] for k, v in res["metrics"].items()}
+    assert got["kv_pool_ms.rate"] == pytest.approx(4.0)
+    assert got["attn_core_ms.rate"] == pytest.approx(4.0)
+    assert got["unscoped_ms.rate"] == pytest.approx(4.0)
+    assert got["host_idle_ms.rate"] == pytest.approx(16.0)
+    # matmul_ms reads attn.out and head, 4 ms each
+    assert got["matmul_ms.rate"] == pytest.approx(8.0)
+    assert any(line.startswith("self ms per run by scope") for line in diag)
+    assert res["breakdown"]["device_ops"][0][0] == "fusion"
+
+
+def test_cells_sharing_a_cache_each_read_their_own_hlo(monkeypatch, tmp_path,
+                                                     capsys):
+    """Two cells' traced runs in one checkout: each compiles its step
+    programs itself, past the other cell's entries in the shared cache,
+    and names its ops from its own dump alone."""
+    import dataclasses
+    import functools
+
+    import jax
+
+    from bench import trace
+    from test_bench_program_trace import DEV, MS, _events
+
+    # XLA reads its flags at its first compile, once a process: the dump
+    # flags a run sets below leave this one's backend as it is, and only
+    # the stand-in dumps of ``compiled`` appear
+    jax.jit(lambda x: x + 1)(1)
+    monkeypatch.setattr(run, "CACHE", tmp_path)
+    monkeypatch.setattr(run, "SHARED", tmp_path / "jax")
+    monkeypatch.setenv("XLA_FLAGS", "")
+    monkeypatch.setenv("TPU_LOG_DIR", "disabled")
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda *a, **k: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    # fusion.9 carries no op_name: only the run's dump names it
+    events = [e for e in _events() if e[1] != "XLA Ops"] + [
+        (DEV, "XLA Ops", "fusion.9", 6 * MS, 4 * MS, {"hlo_op": "fusion.9"})]
+    monkeypatch.setattr(trace, "load_events", lambda _: events)
+    monkeypatch.setattr(run, "find_chips",
+                        lambda n: (jax.devices()[0], len(jax.devices())))
+    monkeypatch.setattr(run, "run_cell", functools.partial(
+        run.run_cell, peaks={"bf16_flops": 1e12}))
+    views = []
+    monkeypatch.setattr(run, "use_cache", views.append)
+    (tmp_path / "jax").mkdir()
+    for name in ("jit__mixed_impl-other-cache", "jit_gather-c-cache"):
+        (tmp_path / "jax" / name).write_text("x")
+    warm_up = run.warm_up
+
+    def compiled(scope, key):
+        """What XLA and JAX leave when the run compiles its mixed step:
+        the dump, and the new cache entry in the run's view."""
+        def warm(engine, mix, vocab):
+            warm_up(engine, mix, vocab)
+            hlo = Path(os.environ["XLA_FLAGS"].split("--xla_dump_to=")[-1]
+                       .split()[0])
+            hlo.mkdir(parents=True, exist_ok=True)
+            (hlo / "module_0001.jit__mixed_impl.after_optimizations.txt"
+             ).write_text('  %fusion.9 = bf16[4]{0} fusion(%p), metadata='
+                          '{op_name="jit(_mixed_impl)/while/body/'
+                          f'{scope}/dot_general"}}\n')
+            assert sorted(p.name for p in views[-1].iterdir()) == [
+                "jit_gather-c-cache"]
+            (views[-1] / key).write_text("y")
+        return warm
+
+    read = {}
+    for name, scope, key in [("a.rate", "attn.core", "jit__mixed_impl-a-cache"),
+                             ("b.rate", "attn.kv_gather",
+                              "jit__mixed_impl-b-cache")]:
+        monkeypatch.setattr(run, "warm_up", compiled(scope, key))
+        cell = dataclasses.replace(_cell(), name=name)
+        run.serve(cell, SEED, 3.0, True)
+        out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+        read[name] = {k: v["value"] for k, v in out["metrics"].items()}
+        assert not run.traced_dir(cell).exists()
+    assert read["a.rate"]["attn_core_ms.rate"] == pytest.approx(4.0)
+    assert read["a.rate"]["kv_pool_ms.rate"] == 0.0
+    assert read["b.rate"]["kv_pool_ms.rate"] == pytest.approx(4.0)
+    assert read["b.rate"]["attn_core_ms.rate"] == 0.0
+    # each run's compiled entry joins the shared cache; no run's dir stays
+    assert sorted(p.name for p in (tmp_path / "jax").iterdir()) == [
+        "jit__mixed_impl-a-cache", "jit__mixed_impl-b-cache",
+        "jit__mixed_impl-other-cache", "jit_gather-c-cache"]
+    assert sorted(p.name for p in tmp_path.iterdir()) == ["jax", "traced"]
+    assert not any((tmp_path / "traced").iterdir())
 
 
 def test_every_cell_states_its_limits():

@@ -1,4 +1,5 @@
-"""The model-FLOP counter against hand counts."""
+"""The model-FLOP counter and the stated bytes against hand counts,
+through the configuration's family module."""
 import sys
 from pathlib import Path
 
@@ -10,10 +11,11 @@ import json
 import pytest
 
 from bench import flops
+from bench.manifest import family_module
 from bench.stats import Record
-from bench.weights import weight_bytes
 
 CONFIGS = Path(__file__).resolve().parents[2] / "bench" / "configs"
+QWEN2 = family_module("qwen2_dense")
 
 
 def _cfg(name):
@@ -29,36 +31,45 @@ def _cfg(name):
 ])
 def test_counts_match_hand_counts(name, matmul, per_key, head):
     cfg = _cfg(name)
-    assert flops.matmul_per_token(cfg) == matmul
-    assert flops.attention_per_key(cfg) == per_key
-    assert flops.head(cfg) == head
-    assert flops.decode_flops(cfg, 100) == matmul + 100 * per_key + head
+    fam = family_module(cfg["reference"])
+    assert fam.matmul_per_token(cfg) == matmul
+    assert fam.attention_per_key(cfg) == per_key
+    assert fam.head(cfg) == head
+    assert flops.decode_flops(fam, cfg, 100) == matmul + 100 * per_key + head
 
 
 @pytest.mark.parametrize("name", ["qwen1.5-0.5b", "codeqwen1.5-7b-l11"])
 def test_stated_bytes_match_the_shapes(name):
     cfg = _cfg(name)
     sv, b = cfg["serving"], cfg["bytes"]
-    assert b["params"] == weight_bytes(cfg)
+    assert family_module(cfg["reference"]).bytes(cfg) == b
     per_token = (cfg["num_hidden_layers"] * 2 * cfg["num_key_value_heads"]
                  * cfg["head_dim"] * 2)
     assert b["kv_per_token"] == per_token
     assert b["kv_pool"] == per_token * sv["max_batch"] * sv["max_len"]
 
 
+def test_an_int8_cache_states_its_scales_in_kv_bytes():
+    cfg = _cfg("qwen1.5-0.5b")
+    cfg["serving"] = dict(cfg["serving"], kv_dtype="int8")
+    # 24 layers x K and V x 16 heads x (64 int8 values + one f32 scale)
+    assert QWEN2.bytes(cfg)["kv_per_token"] == 24 * 2 * 16 * (64 + 4)
+
+
 def test_step_flops_split_prompts_over_their_prefill_dispatches():
     cfg = {"hidden_size": 4, "num_attention_heads": 2,
            "num_key_value_heads": 1, "head_dim": 2, "intermediate_size": 8,
            "num_hidden_layers": 1, "vocab_size": 10}
-    assert flops.matmul_per_token(cfg) == 288
-    assert flops.attention_per_key(cfg) == 16
-    assert flops.head(cfg) == 80
+    assert QWEN2.matmul_per_token(cfg) == 288
+    assert QWEN2.attention_per_key(cfg) == 16
+    assert QWEN2.head(cfg) == 80
     p = lambda s, t, c: ("progress", 1, s, t, {"count": c})  # noqa: E731
     events = [("admit", 1, 0, 1.0, {}), p(1, 1.1, 0),
               ("first_token", 1, 2, 1.15, {}), p(2, 1.2, 1),
               p(3, 1.3, 2), p(4, 1.4, 3)]
     rec = Record(events=events, due={1: 1.0}, prompt_len={1: 8}, t0=1.0,
-                 t1=2.0, t_drained=2.0, loop="rate", max_batch=1, config=cfg)
+                 t1=2.0, t_drained=2.0, loop="rate", max_batch=1, config=cfg,
+                 family=QWEN2)
     prompt = 288 * 8 + 16 * 8 * 9 // 2 + 80
     assert flops.step_flops(rec) == {
         0: ("mixed", prompt / 2), 1: ("mixed", prompt / 2),

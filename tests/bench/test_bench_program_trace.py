@@ -5,11 +5,14 @@ from pathlib import Path
 # the benchmark lives beside src/, outside the package path
 sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
 
+import os
+
 import pytest
 
-from bench import manifest
+from bench import manifest, run
 from bench.program_trace import (ATTN_CORE, KV_POOL, MATMUL, UNSCOPED,
-                                 host_idle_ms, reduce_program, self_times)
+                                 host_idle_ms, load_hlo, reduce_program,
+                                 roofline_share, self_times)
 from bench.stats import Record
 from repro.serving.events import SCOPE_NAMES
 
@@ -87,10 +90,25 @@ def test_idle_time_goes_to_the_innermost_span():
         "engine.harvest.fetch": 3, "bench.sleep": 30, "host": 30})
     assert pt.steps == 1
     assert pt.host_ns["engine.harvest"] == pytest.approx(33 * MS)
-    # admission, dispatch and the harvest outside its reads: 2 + 2 + 4
-    assert host_idle_ms(pt) == pytest.approx(8.0)
+    # inside engine.step: admission, dispatch, and the harvest with its
+    # wait and fetch: 2 + 2 + 4 + 5 + 3
+    assert host_idle_ms(pt) == pytest.approx(16.0)
     assert pt.gaps[0][0] in ("bench.sleep", "host")
     assert pt.gaps[0][1] == pytest.approx(0.068)   # 32 -> 100 ms
+
+
+@pytest.mark.parametrize("shift", [-1.0, -0.5, 0.5])
+def test_host_idle_ms_holds_when_the_device_clock_shifts(shift):
+    """The profiler's host and device clocks can disagree by a fraction of
+    a millisecond from run to run: the idle gap then moves between the
+    harvest's wait and the dispatch, and the metric must not move."""
+    base = reduce_program(_events(), SCOPE_NAMES)
+    moved = reduce_program(
+        [(p, ln, n, s + shift * MS if p == DEV else s, d, st)
+         for p, ln, n, s, d, st in _events()], SCOPE_NAMES)
+    assert moved.idle_ns["engine.dispatch"] \
+        != pytest.approx(base.idle_ns["engine.dispatch"])
+    assert host_idle_ms(moved) == pytest.approx(host_idle_ms(base))
 
 
 def test_a_trace_without_the_window_span_is_refused():
@@ -116,7 +134,7 @@ def test_device_readers_read_the_program_trace():
     got = {n: manifest.metric_reader(n)(rec) for n in NEW_READERS[:5]}
     assert got == pytest.approx({"kv_pool_ms": 4.0, "attn_core_ms": 4.0,
                                  "matmul_ms": 8.0, "unscoped_ms": 4.0,
-                                 "host_idle_ms": 8.0})
+                                 "host_idle_ms": 16.0})
 
 
 def test_padded_lane_share_over_the_window_mixed_dispatches():
@@ -128,3 +146,113 @@ def test_padded_lane_share_over_the_window_mixed_dispatches():
                 d(13.0, "mixed", 64, 12)])
     share = manifest.metric_reader("padded_lane_share.rate")(rec)
     assert share == pytest.approx(100.0 * (1 - 32 / 128))
+
+
+def test_roofline_share_takes_the_binding_bound():
+    pt = reduce_program(_events(), SCOPE_NAMES)
+    peaks = {"bf16_flops": 1e12, "hbm_bytes_per_s": 1e9}
+    # attn.core: 4 ms an execution; 2e9 FLOPs need 2 ms, 1e6 bytes 1 ms
+    assert roofline_share(pt, ATTN_CORE, 2e9, 1e6, peaks) \
+        == pytest.approx(50.0)
+    # bytes bind: 3e6 bytes need 3 ms
+    assert roofline_share(pt, ATTN_CORE, 2e9, 3e6, peaks) \
+        == pytest.approx(75.0)
+    # no op under the scope, or no trace: nothing to read
+    assert roofline_share(pt, ("attn.kv_write",), 2e9, 1e6, peaks) is None
+    assert roofline_share(None, ATTN_CORE, 2e9, 1e6, peaks) is None
+
+
+def test_ops_without_op_name_are_named_from_the_dumped_hlo(tmp_path):
+    (tmp_path / "module_0007.jit__mixed_impl.after_optimizations.txt"
+     ).write_text(
+        '  %fusion.9 = bf16[4]{0} fusion(%p), metadata={op_name='
+        '"jit(_mixed_impl)/while/body/attn.core/dot_general"}\n'
+        '  ROOT copy.2 = bf16[4]{0} copy(%fusion.9), metadata={op_name='
+        '"jit(_mixed_impl)/while/body/attn.kv_write/scatter"}\n')
+    hlo = load_hlo(tmp_path)
+    assert set(hlo["_mixed_impl"]) == {"fusion.9", "copy.2"}
+    assert hlo["_decode_impl"] == {}
+    events = [e for e in _events() if e[1] != "XLA Ops"] + [
+        (DEV, "XLA Ops", "%fusion.9 = bf16[4] fusion(%p)", 6 * MS, 4 * MS,
+         {}),
+        (DEV, "XLA Ops", "copy.2", 10 * MS, 2 * MS, {"hlo_op": "copy.2"})]
+    pt = reduce_program(events, SCOPE_NAMES, hlo)
+    assert pt.scope_ms(ATTN_CORE) == pytest.approx(4.0)
+    assert pt.scope_ms(KV_POOL) == pytest.approx(2.0)
+    assert pt.no_op_name_ns == 0.0
+    # without the dump the same ops fall to unscoped
+    bare = reduce_program(events, SCOPE_NAMES)
+    assert bare.scope_ms((UNSCOPED,)) == pytest.approx(6.0)
+
+
+def test_a_traced_run_compiles_its_step_programs_itself(monkeypatch,
+                                                        tmp_path):
+    monkeypatch.setenv("XLA_FLAGS", "--xla_foo=1")
+    shared, own = tmp_path / "jax", tmp_path / "traced" / "cell"
+    shared.mkdir()
+    for n in ("jit__mixed_impl-aa-cache", "jit__decode_impl-bb-cache",
+              "jit_gather-cc-cache", "jit_gather-dd-cache"):
+        (shared / n).write_text(n)
+    view = run.compile_steps_here(own, shared)
+    flags = os.environ["XLA_FLAGS"].split()
+    assert flags[0] == "--xla_foo=1"
+    assert f"--xla_dump_to={own / 'hlo'}" in flags
+    assert "--xla_dump_hlo_module_re=.*(_mixed_impl|_decode_impl).*" in flags
+    # the view holds every entry but the step programs'
+    assert sorted(p.name for p in view.iterdir()) == [
+        "jit_gather-cc-cache", "jit_gather-dd-cache"]
+    assert (view / "jit_gather-cc-cache").read_text() == "jit_gather-cc-cache"
+    # what the run compiles joins the shared cache; links and entries the
+    # shared cache holds already stay as they are
+    (view / "jit__mixed_impl-aa-cache").write_text("again")
+    (view / "jit_new-ee-cache").write_text("new")
+    run.adopt(view, shared)
+    assert sorted(p.name for p in shared.iterdir()) == [
+        "jit__decode_impl-bb-cache", "jit__mixed_impl-aa-cache",
+        "jit_gather-cc-cache", "jit_gather-dd-cache", "jit_new-ee-cache"]
+    assert (shared / "jit__mixed_impl-aa-cache").read_text() \
+        == "jit__mixed_impl-aa-cache"
+    assert not any(p.is_symlink() for p in shared.iterdir())
+
+
+@pytest.mark.parametrize("trace", [False, True])
+def test_only_a_traced_run_dumps_and_reads_a_view(monkeypatch, tmp_path,
+                                                  trace):
+    monkeypatch.setattr(run, "CACHE", tmp_path)
+    monkeypatch.setattr(run, "SHARED", tmp_path / "jax")
+    monkeypatch.setenv("XLA_FLAGS", "--xla_foo=1")
+    monkeypatch.setenv("TPU_LOG_DIR", "disabled")
+    used = []
+    monkeypatch.setattr(run, "use_cache", used.append)
+
+    def no_chip(n):
+        raise SystemExit(2)
+    monkeypatch.setattr(run, "find_chips", no_chip)
+    cell = manifest.load_cell("qwen1.5-0.5b.chat.rate")
+    with pytest.raises(SystemExit):
+        run.serve(cell, 1, 1.0, trace)
+    own = tmp_path / "traced" / cell.name
+    assert used == [own / "jax" if trace else tmp_path / "jax"]
+    assert ("--xla_dump_to" in os.environ["XLA_FLAGS"]) == trace
+    assert not own.exists()
+
+
+def test_each_step_program_counts_alike():
+    """Scope ms per execution of each step program, averaged over the
+    programs: the mix of executions a seed draws does not move it."""
+    top = "jit(_decode_impl)/jit(main)"
+
+    def decodes(k):
+        return [e for i in range(k) for e in [
+            (DEV, "XLA Modules", "jit__decode_impl(2)", (40 + 10 * i) * MS,
+             5 * MS, {}),
+            _op(40 + 10 * i, 42 + 10 * i,
+                f"{top}/while/body/attn.core/dot_general")]]
+    reads = []
+    for k in (1, 3):
+        pt = reduce_program(_events() + decodes(k), SCOPE_NAMES)
+        assert pt.executions == {"_mixed_impl": 1, "_decode_impl": k}
+        assert pt.scope_ms(ATTN_CORE, "_mixed_impl") == pytest.approx(4.0)
+        assert pt.scope_ms(ATTN_CORE, "_decode_impl") == pytest.approx(2.0)
+        reads.append(pt.scope_ms(ATTN_CORE))
+    assert reads == pytest.approx([3.0, 3.0])

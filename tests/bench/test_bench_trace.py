@@ -69,8 +69,25 @@ def test_idle_gaps_are_named_by_the_host_span():
 
 def test_top_ops_sum_over_the_window():
     ops = dict(reduce_events(_events()).top_ops())
-    assert ops["fusion.1"] == pytest.approx(0.022)
+    # self time: fusion.2 covers 15 -> 17 ms of the first fusion.1
+    assert ops["fusion.1"] == pytest.approx(0.020)
+    assert ops["fusion.2"] == pytest.approx(0.010)
     assert ops["copy.3"] == pytest.approx(0.005)
+
+
+def test_top_ops_rank_a_loop_by_its_own_time():
+    ms = 1_000_000.0
+    events = [(HOST, "python", "bench.window", 0.0, 100 * ms),
+              # the layer loop's while holds its body's ops
+              (DEV, "XLA Ops", "while.4", 0.0, 30 * ms),
+              (DEV, "XLA Ops", "fusion.7", 1 * ms, 12 * ms),
+              (DEV, "XLA Ops", "fusion.7", 14 * ms, 12 * ms),
+              (DEV, "XLA Ops", "reshape.5", 26 * ms, 2 * ms),
+              (DEV, "XLA Ops", "sort.1", 40 * ms, 5 * ms)]
+    top = reduce_events(events).top_ops()
+    assert [n for n, _ in top] == ["fusion.7", "sort.1", "while.4",
+                                   "reshape.5"]
+    assert dict(top)["while.4"] == pytest.approx(0.004)
 
 
 def test_a_trace_without_the_window_span_is_refused():
