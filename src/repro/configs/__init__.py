@@ -17,6 +17,7 @@ from repro.configs.base import (
     HybridConfig,
     MLAConfig,
     MoEConfig,
+    RopeScaling,
     SSMConfig,
     ShapeSpec,
     cell_is_applicable,

@@ -28,10 +28,63 @@ class MoEConfig:
     # of the MoE block (DeepSeek-V3 uses 3 dense layers).
     first_k_dense: int = 0
     dense_d_ff: int = 0
+    # Gate weights of the k picked experts, normalized over the k, are
+    # scaled by this (DeepSeek's routed_scaling_factor).
     router_scale: float = 1.0
-    # Expert capacity factor: C = ceil(S * k / E * capacity_factor); tokens
-    # routed past capacity are dropped (residual keeps them intact).
-    capacity_factor: float = 1.25
+    # "softmax": top-k of softmax(logits) (Mixtral / granite).  "sigmoid":
+    # DeepSeek-V3's noaux_tc router - sigmoid scores plus a per-expert
+    # correction bias that only selects: the experts fall into ``n_group``
+    # groups, a group scores the sum of its top two, the ``topk_group``
+    # best groups are kept and the top k is taken inside them.
+    scoring: str = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
+    # The experts this chip holds: ids [expert_offset, expert_offset +
+    # held_experts) of the router's num_experts (0: all of them).  The
+    # router scores every expert; only the held ones are computed.
+    expert_offset: int = 0
+    held_experts: int = 0
+
+    def __post_init__(self) -> None:
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown MoE scoring {self.scoring!r}")
+        if self.num_experts % self.n_group \
+                or not 1 <= self.topk_group <= self.n_group:
+            raise ValueError(
+                f"n_group={self.n_group} must divide num_experts="
+                f"{self.num_experts} and topk_group={self.topk_group} lie "
+                "in [1, n_group]")
+        if self.expert_offset < 0 \
+                or self.expert_offset + self.n_held > self.num_experts:
+            raise ValueError(
+                f"held experts [{self.expert_offset}, {self.expert_offset}"
+                f" + {self.n_held}) exceed num_experts={self.num_experts}")
+
+    @property
+    def n_held(self) -> int:
+        """Experts whose weights this chip holds and computes."""
+        return self.held_experts or self.num_experts
+
+
+@dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rotary scaling (arXiv:2309.00071), in DeepSeek-V3's form.
+
+    Rotary pairs that turn fewer than ``beta_slow`` times over
+    ``original_max_position_embeddings`` positions have their frequency
+    divided by ``factor``, pairs that turn more than ``beta_fast`` times
+    keep it, and a linear ramp blends the pairs between.  cos/sin are multiplied by
+    mscale(factor, mscale) / mscale(factor, mscale_all_dim), and MLA's
+    softmax scale by mscale(factor, mscale_all_dim) squared, with
+    mscale(s, m) = 0.1 m ln s + 1.
+    """
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -108,6 +161,7 @@ class ArchConfig:
     positional: str = "rope"  # rope | learned | none
     tie_embeddings: bool = False
     max_position_embeddings: int = 131_072
+    rope_scaling: RopeScaling | None = None  # None: plain rotary
     source: str = ""  # provenance tag from the assignment table
 
     moe: MoEConfig | None = None
@@ -243,6 +297,10 @@ def reduced(cfg: ArchConfig, **overrides: Any) -> ArchConfig:
             shared_expert_d_ff=32 if cfg.moe.num_shared_experts else 0,
             first_k_dense=min(1, cfg.moe.first_k_dense),
             dense_d_ff=128 if cfg.moe.first_k_dense else 0,
+            # the router's scoring and scale; 4 experts make no groups
+            # (group limiting is tested at 16 experts in 4 groups)
+            router_scale=cfg.moe.router_scale,
+            scoring=cfg.moe.scoring,
         )
     if cfg.mla is not None:
         small["mla"] = MLAConfig(

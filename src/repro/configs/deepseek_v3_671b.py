@@ -3,8 +3,10 @@
 61L d_model=7168 128H d_ff=2048(routed) vocab=129280, MoE 256e top-8,
 MLA (q_lora 1536 / kv_lora 512 / nope 128 / rope 64 / v 128),
 1 shared + 256 routed experts, first 3 layers dense (d_ff 18432), MTP depth 1.
+Router: sigmoid scores, noaux_tc selection (8 groups, top 4 groups),
+routed_scaling_factor 2.5.  YaRN rope: factor 40 over 4096 positions.
 """
-from repro.configs.base import ArchConfig, MLAConfig, MoEConfig
+from repro.configs.base import ArchConfig, MLAConfig, MoEConfig, RopeScaling
 
 CONFIG = ArchConfig(
     name="deepseek-v3-671b",
@@ -19,6 +21,11 @@ CONFIG = ArchConfig(
     activation="swiglu",
     norm="rmsnorm",
     rope_theta=10_000.0,
+    max_position_embeddings=163_840,
+    rope_scaling=RopeScaling(factor=40.0,
+                             original_max_position_embeddings=4_096,
+                             beta_fast=32.0, beta_slow=1.0, mscale=1.0,
+                             mscale_all_dim=1.0),
     source="arXiv:2412.19437",
     moe=MoEConfig(
         num_experts=256,
@@ -29,6 +36,9 @@ CONFIG = ArchConfig(
         first_k_dense=3,
         dense_d_ff=18_432,
         router_scale=2.5,
+        scoring="sigmoid",
+        n_group=8,
+        topk_group=4,
     ),
     mla=MLAConfig(
         q_lora_rank=1_536,

@@ -63,6 +63,11 @@ def quantize_leaf(leaf, kind: str) -> QTensor:
     return QTensor(q, scale)
 
 
+# one compiled program per leaf shape: the float32 view of a leaf is
+# fused into its reductions, never held whole beside the weights
+_quantize_leaf = jax.jit(quantize_leaf, static_argnums=1)
+
+
 def quantize_params(params, min_size: int = DEFAULT_QUANT_MIN_SIZE):
     """Real arrays -> int8 QTensors (kernels per-column, tables per-row)."""
 
@@ -70,7 +75,7 @@ def quantize_params(params, min_size: int = DEFAULT_QUANT_MIN_SIZE):
         kind = _eligible(path, leaf, min_size)
         if kind is None:
             return leaf
-        return quantize_leaf(leaf, kind)
+        return _quantize_leaf(leaf, kind)
 
     return _map_with_path(params, one)
 

@@ -48,7 +48,7 @@ import json
 import math
 from dataclasses import asdict, dataclass
 
-from repro.serving.events import EngineEvent
+from repro.serving.events import ENGINE_KINDS, EngineEvent
 
 _WALL_FIELDS = ("wall_s", "ttft_s_p50", "ttft_s_p99", "itl_s_p50",
                 "itl_s_p99", "goodput_req_s", "tokens_per_s")
@@ -198,8 +198,8 @@ class _ReqState:
 def reduce_events(events: list[EngineEvent],
                   slo: SLO | None = None) -> HarnessMetrics:
     """Scan an event stream (in emission order) into :class:`HarnessMetrics`."""
-    # dispatch events belong to no request (serving.events)
-    events = [e for e in events if e.kind != "dispatch"]
+    # dispatch and experts events belong to no request (serving.events)
+    events = [e for e in events if e.kind not in ENGINE_KINDS]
     if not events:
         raise ValueError("reduce_events needs a non-empty event stream")
     reqs: dict[int, _ReqState] = {}

@@ -25,7 +25,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ArchConfig, MLAConfig
+from repro.configs.base import ArchConfig
 from repro.core import masking
 from repro.core.kv_quant import (FLOAT_CODEC, CacheCodec, cache_put,
                                  gather_view, layer_view)
@@ -205,8 +205,8 @@ def gqa_qkv(x: jax.Array, p: dict, cfg: ArchConfig, positions: jax.Array,
     k = apply_dense(x, p["wk"]).reshape(b_, s, kv, hd)
     v = apply_dense(x, p["wv"]).reshape(b_, s, kv, hd)
     if rope and cfg.positional == "rope":
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+        k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
     q = constrain(q, ("batch", None, "heads", None))
     k = constrain(k, ("batch", None, "kv_heads", None))
     v = constrain(v, ("batch", None, "kv_heads", None))
@@ -282,10 +282,9 @@ def mla_prefill(x: jax.Array, p: dict, cfg: ArchConfig, *,
                 ) -> tuple[jax.Array, MLACache]:
     """MLA prefill: attention output + this layer's latent cache."""
     codec = codec or FLOAT_CODEC
-    m = cfg.mla
     b_, s, _ = x.shape
     o = mla_attention(x, p, cfg, positions=positions)
-    c_kv, k_rope = _mla_latent(x, p, m, positions, cfg.rope_theta)
+    c_kv, k_rope = _mla_latent(x, p, cfg, positions)
     pad = ((0, 0), (0, max_len - s), (0, 0))
     cq, cs = codec.store(c_kv, jnp.bfloat16)
     rq, rs = codec.store(k_rope, jnp.bfloat16)
@@ -589,22 +588,35 @@ def build_mla(b, cfg: ArchConfig) -> dict:
     }
 
 
-def _mla_q(x, p, m: MLAConfig, h: int, positions, theta):
+def _mla_q(x, p, cfg: ArchConfig, positions):
+    m, h = cfg.mla, cfg.num_heads
     b_, s, _ = x.shape
     cq = layers.rmsnorm(apply_dense(x, p["q_down"]), p["q_norm"]["scale"])
     q = apply_dense(cq, p["q_up"]).reshape(b_, s, h, m.qk_head_dim)
     q_nope, q_rope = q[..., : m.qk_nope_head_dim], q[..., m.qk_nope_head_dim:]
-    q_rope = apply_rope(q_rope, positions, theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
     return q_nope, q_rope
 
 
-def _mla_latent(x, p, m: MLAConfig, positions, theta):
+def _mla_latent(x, p, cfg: ArchConfig, positions):
+    m = cfg.mla
     b_, s, _ = x.shape
     ckv_full = apply_dense(x, p["kv_down"])
     c_kv = layers.rmsnorm(ckv_full[..., : m.kv_lora_rank], p["kv_norm"]["scale"])
     k_rope = ckv_full[..., m.kv_lora_rank:].reshape(b_, s, 1, m.qk_rope_head_dim)
-    k_rope = apply_rope(k_rope, positions, theta)[:, :, 0]
+    k_rope = apply_rope(k_rope, positions, cfg.rope_theta,
+                        cfg.rope_scaling)[:, :, 0]
     return c_kv, k_rope
+
+
+def mla_score_divisor(cfg: ArchConfig) -> float:
+    """Scores are divided by this: sqrt of the q/k head dim, over YaRN's
+    mscale(factor, mscale_all_dim) squared where the rope is scaled
+    (exactly sqrt(qk_head_dim) without scaling)."""
+    rs = cfg.rope_scaling
+    m2 = 1.0 if rs is None or not rs.mscale_all_dim \
+        else layers.yarn_mscale(rs.factor, rs.mscale_all_dim) ** 2
+    return math.sqrt(cfg.mla.qk_head_dim) / m2
 
 
 def mla_attention(x: jax.Array, p: dict, cfg: ArchConfig, *,
@@ -612,14 +624,14 @@ def mla_attention(x: jax.Array, p: dict, cfg: ArchConfig, *,
     """Train/prefill MLA: expand latents to per-head K/V (naive path)."""
     m, h = cfg.mla, cfg.num_heads
     b_, s, _ = x.shape
-    q_nope, q_rope = _mla_q(x, p, m, h, positions, cfg.rope_theta)
-    c_kv, k_rope = _mla_latent(x, p, m, positions, cfg.rope_theta)
+    q_nope, q_rope = _mla_q(x, p, cfg, positions)
+    c_kv, k_rope = _mla_latent(x, p, cfg, positions)
     k_nope = apply_dense(c_kv, p["k_up"]).reshape(b_, s, h, m.qk_nope_head_dim)
     v = apply_dense(c_kv, p["v_up"]).reshape(b_, s, h, m.v_head_dim)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     k = jnp.concatenate([k_nope, jnp.broadcast_to(
         k_rope[:, :, None], (b_, s, h, m.qk_rope_head_dim))], axis=-1)
-    scale = 1.0 / math.sqrt(m.qk_head_dim)
+    scale = 1.0 / mla_score_divisor(cfg)
     if s >= BLOCKWISE_THRESHOLD:
         o = blockwise_attention(q, k, v, causal=True, scale=scale)
     else:
@@ -635,20 +647,21 @@ def _mla_attend(x: jax.Array, p: dict, cfg: ArchConfig, q_nope: jax.Array,
     layouts, which is what keeps dense and paged decode bit-identical."""
     m, h = cfg.mla, cfg.num_heads
     b_, one = q_nope.shape[:2]
-    wk = p["k_up"]["kernel"].reshape(m.kv_lora_rank, h, m.qk_nope_head_dim)
+    # the int8 weight path keeps these kernels quantized: read them whole
+    wk = layers.maybe_dequant(p["k_up"]["kernel"], jnp.float32).reshape(
+        m.kv_lora_rank, h, m.qk_nope_head_dim)
     # absorb k_up into the query: q_lat [B,1,h,kv_lora] (f32: one token only)
-    q_lat = jnp.einsum("bqhd,lhd->bqhl", q_nope.astype(jnp.float32),
-                       wk.astype(jnp.float32))
+    q_lat = jnp.einsum("bqhd,lhd->bqhl", q_nope.astype(jnp.float32), wk)
     s_lat = jnp.einsum("bqhl,bkl->bhqk", q_lat, c_kv.astype(jnp.float32))
     s_rope = jnp.einsum("bqhd,bkd->bhqk", q_rope.astype(jnp.float32),
                         k_rope.astype(jnp.float32))
-    scores = (s_lat + s_rope) / math.sqrt(m.qk_head_dim)
+    scores = (s_lat + s_rope) / mla_score_divisor(cfg)
     scores = jnp.where(live, scores, NEG_INF)
     pr = jax.nn.softmax(scores, axis=-1)
     # attend in latent space, then expand once per step via v_up
     o_lat = jnp.einsum("bhqk,bkl->bqhl", pr.astype(c_kv.dtype), c_kv)
-    wv = jnp.transpose(p["v_up"]["kernel"].reshape(m.kv_lora_rank, h, m.v_head_dim),
-                       (1, 0, 2)).astype(x.dtype)
+    wv = jnp.transpose(layers.maybe_dequant(p["v_up"]["kernel"], x.dtype)
+                       .reshape(m.kv_lora_rank, h, m.v_head_dim), (1, 0, 2))
     o = jnp.einsum("bqhl,hld->bqhd", o_lat, wv)
     with jax.named_scope("attn.out"):
         return apply_dense(o.reshape(b_, one, h * m.v_head_dim), p["wo"])
@@ -660,12 +673,11 @@ def mla_decode(x: jax.Array, p: dict, cfg: ArchConfig, cache: MLACache,
     """Absorbed-matmul MLA decode: score and value contraction happen in the
     latent space, so per-step FLOPs/bytes scale with kv_lora_rank."""
     codec = codec or FLOAT_CODEC
-    m, h = cfg.mla, cfg.num_heads
     b_, one, _ = x.shape
     idx_vec = as_index_vector(cache_index, b_)
     positions = idx_vec[:, None]
-    q_nope, q_rope = _mla_q(x, p, m, h, positions, cfg.rope_theta)
-    c_new, kr_new = _mla_latent(x, p, m, positions, cfg.rope_theta)
+    q_nope, q_rope = _mla_q(x, p, cfg, positions)
+    c_new, kr_new = _mla_latent(x, p, cfg, positions)
     rows = jnp.arange(b_)
     cq, cs = codec.store(c_new[:, 0], cache.c_kv.dtype)
     rq, rs = codec.store(kr_new[:, 0], cache.k_rope.dtype)
@@ -690,14 +702,14 @@ def mla_decode_paged(x: jax.Array, p: dict, cfg: ArchConfig, cache: MLACache,
     ([L, NB, bs, rank] c_kv and [L, NB, bs, rope_dim] k_rope addressed
     through the same block tables, in place)."""
     codec = codec or FLOAT_CODEC
-    m, h = cfg.mla, cfg.num_heads
+    m = cfg.mla
     b_, one, _ = x.shape
     bs = cache.c_kv.shape[2]
     idx_vec = as_index_vector(cache_index, b_)
     positions = idx_vec[:, None]
     with jax.named_scope("attn.qkv"):
-        q_nope, q_rope = _mla_q(x, p, m, h, positions, cfg.rope_theta)
-        c_new, kr_new = _mla_latent(x, p, m, positions, cfg.rope_theta)
+        q_nope, q_rope = _mla_q(x, p, cfg, positions)
+        c_new, kr_new = _mla_latent(x, p, cfg, positions)
     with jax.named_scope("attn.kv_write"):
         blk, off = paged_write_slot(idx_vec, block_tables, bs)
         cq, cs = codec.store(c_new[:, 0], cache.c_kv.dtype)
@@ -725,11 +737,10 @@ def mla_mixed(x: jax.Array, p: dict, cfg: ArchConfig, cache: MLACache,
     """W-lane chunk/decode MLA against the dense latent cache (absorbed
     contraction; see ``gqa_mixed`` for the lane protocol)."""
     codec = codec or FLOAT_CODEC
-    m, h = cfg.mla, cfg.num_heads
     b_, w, _ = x.shape
     positions = start[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]
-    q_nope, q_rope = _mla_q(x, p, m, h, positions, cfg.rope_theta)
-    c_new, kr_new = _mla_latent(x, p, m, positions, cfg.rope_theta)
+    q_nope, q_rope = _mla_q(x, p, cfg, positions)
+    c_new, kr_new = _mla_latent(x, p, cfg, positions)
     s_max = cache.c_kv.shape[1]
     pos = jnp.where(masking.lane_mask(w, n_live), positions, s_max)
     rows = jnp.arange(b_)[:, None]
@@ -753,13 +764,13 @@ def mla_mixed_paged(x: jax.Array, p: dict, cfg: ArchConfig, cache: MLACache,
     """W-lane chunk/decode MLA against layer ``layer`` of the pooled
     latent block cache (in place)."""
     codec = codec or FLOAT_CODEC
-    m, h = cfg.mla, cfg.num_heads
+    m = cfg.mla
     b_, w, _ = x.shape
     bs = cache.c_kv.shape[2]
     positions = start[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]
     with jax.named_scope("attn.qkv"):
-        q_nope, q_rope = _mla_q(x, p, m, h, positions, cfg.rope_theta)
-        c_new, kr_new = _mla_latent(x, p, m, positions, cfg.rope_theta)
+        q_nope, q_rope = _mla_q(x, p, cfg, positions)
+        c_new, kr_new = _mla_latent(x, p, cfg, positions)
     t_max = block_tables.shape[1] * bs
     with jax.named_scope("attn.kv_write"):
         idx_w = jnp.where(masking.lane_mask(w, n_live), positions, t_max)

@@ -6,6 +6,8 @@ tiled-kernel path (``repro.kernels``) and the XLA path are interchangeable via
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -114,18 +116,61 @@ def apply_dense(x: jax.Array, p: dict) -> jax.Array:
 # --------------------------------------------------------------------------
 # Rotary position embeddings
 # --------------------------------------------------------------------------
-def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
+def yarn_mscale(scale: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature: 0.1 m ln s + 1 (1 where s <= 1)."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def _yarn_range(scaling, dim: int, theta: float) -> tuple[int, int]:
+    """The rotary pairs between ``beta_fast`` and ``beta_slow`` rotations
+    over the original context: [low, high], the published correction
+    range (floor / ceil, clipped to the pairs)."""
+    def pair(rotations):
+        return dim * math.log(scaling.original_max_position_embeddings
+                              / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    low = math.floor(pair(scaling.beta_fast))
+    high = math.ceil(pair(scaling.beta_slow))
+    return max(low, 0), min(high, dim - 1)
+
+
+def rope_frequencies(head_dim: int, theta: float,
+                     scaling=None) -> jax.Array:
+    """Inverse frequencies of the ``head_dim // 2`` rotary pairs; under
+    YaRN (``configs.base.RopeScaling``) pairs below the correction range
+    keep theirs, pairs above are divided by ``factor``, and a linear ramp
+    blends the pairs between."""
     half = head_dim // 2
-    return 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    inv = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if scaling is None:
+        return inv
+    low, high = _yarn_range(scaling, head_dim, theta)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / max(high - low, 0.001), 0.0, 1.0)
+    return inv / scaling.factor * ramp + inv * (1.0 - ramp)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: [..., S, H, D]; positions: broadcastable to [..., S]."""
+def rope_mscale(scaling) -> float:
+    """The factor on cos/sin: 1 without scaling."""
+    if scaling is None:
+        return 1.0
+    return yarn_mscale(scaling.factor, scaling.mscale) \
+        / yarn_mscale(scaling.factor, scaling.mscale_all_dim)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               scaling=None) -> jax.Array:
+    """x: [..., S, H, D]; positions: broadcastable to [..., S].  Rotates
+    (first half, second half) pairs; ``scaling`` is the config's
+    ``rope_scaling`` (None: plain rotary)."""
     d = x.shape[-1]
-    inv_freq = rope_frequencies(d, theta)  # [D/2]
+    inv_freq = rope_frequencies(d, theta, scaling)  # [D/2]
     angles = positions[..., :, None].astype(jnp.float32) * inv_freq  # [..., S, D/2]
     cos = jnp.cos(angles)[..., :, None, :]  # [..., S, 1, D/2]
     sin = jnp.sin(angles)[..., :, None, :]
+    if scaling is not None:
+        m = rope_mscale(scaling)
+        cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -138,7 +183,7 @@ def build_embedding(b, vocab: int, d: int) -> dict:
     return {"table": b.param((vocab, d), ("vocab", "embed"), scale=0.02)}
 
 
-def _maybe_dequant(table, dtype):
+def maybe_dequant(table, dtype):
     from repro.core.quant import QTensor
 
     if isinstance(table, QTensor):
@@ -159,5 +204,5 @@ def embed(tokens: jax.Array, p: dict, dtype=jnp.bfloat16) -> jax.Array:
 
 def unembed(x: jax.Array, p: dict) -> jax.Array:
     """Logits = x @ table^T, in f32 for a stable softmax/xent."""
-    table = _maybe_dequant(p["table"], jnp.float32)
+    table = maybe_dequant(p["table"], jnp.float32)
     return jnp.einsum("...d,vd->...v", x.astype(jnp.float32), table)

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.core import masking
 from repro.core.kv_quant import CacheCodec
 from repro.core.paging import PagingConfig, pool_row
 from repro.core.spec import CHUNKABLE_FAMILIES, KV_QUANTIZABLE_FAMILIES
@@ -282,9 +283,13 @@ class Model:
             outs.append(c)
         return x, jax.tree.map(lambda *ls: jnp.stack(ls), *outs)
 
-    def _run_cache_layers(self, attend, x, params, cache, paged: bool):
+    def _run_cache_layers(self, attend, x, params, cache, paged: bool,
+                          live: jax.Array | None = None):
         """Layer loop of the attention-cache decode and mixed steps;
-        ``attend(hn, attn_params, cache, layer) -> (o, cache)``.
+        ``attend(hn, attn_params, cache, layer) -> (o, cache)``.  Returns
+        ``(x, cache, counts)``: ``counts`` is int32 [expert rows, experts
+        hit] summed over the MoE layers (``moe.apply_moe``, which routes
+        only the lanes ``live`` [B, W] marks), None without experts.
 
         ``paged``: the whole layer-stacked pool rides the loop carry and
         each layer writes and reads its own rows through its layer
@@ -300,56 +305,77 @@ class Model:
         does not fit qwen1.5-0.5b's 32 x 2048 serving cache on one v5e."""
         cfg = self.cfg
 
-        def body(h, lp, c, layer):
+        def body(h, lp, c, layer, n, moe_layer=None):
             hn = layers.apply_norm(h, lp["ln1"], cfg.norm)
             o, c = attend(hn, lp["attn"], c, layer)
             h = h + o
             hn = layers.apply_norm(h, lp["ln2"], cfg.norm)
             if "moe" in lp:
-                return h + moe.apply_moe(hn, lp["moe"], cfg), c
-            return h + moe.apply_ffn(hn, lp["ffn"], cfg.activation), c
+                y, got = moe.apply_moe(hn, lp["moe"], cfg, live, moe_layer)
+                return h + y, c, n + got
+            return h + moe.apply_ffn(hn, lp["ffn"], cfg.activation), c, n
 
         prefix = params.get("dense_prefix", [])
         stacked = params["layers"]
+        n_ex = None if cfg.moe is None else jnp.zeros((2,), jnp.int32)
         if not paged:
             return self._run_prefix_then_stack(body, x, prefix, stacked,
-                                               cache)
+                                               cache, n_ex)
         for i, lp in enumerate(prefix):
-            x, cache = body(x, lp, cache, i)
+            x, cache, n_ex = body(x, lp, cache, i, n_ex)
         n = jax.tree.leaves(stacked)[0].shape[0]
         if self.opt.unroll_layers:
             for i in range(n):
-                x, cache = body(x, jax.tree.map(lambda l, i=i: l[i], stacked),
-                                cache, len(prefix) + i)
-            return x, cache
+                x, cache, n_ex = body(
+                    x, jax.tree.map(lambda l, i=i: l[i], stacked), cache,
+                    len(prefix) + i, n_ex)
+            return x, cache, n_ex
+
+        # the experts' weights stay whole outside the scan's slices: the
+        # grouped matmul kernel reads one layer's in place
+        whole = {k: v for k, v in stacked.get("moe", {}).items()
+                 if k in moe.EXPERT_WEIGHTS}
+        if whole:
+            stacked = dict(stacked, moe={k: v for k, v in
+                                         stacked["moe"].items()
+                                         if k not in whole})
 
         def step(carry, inp):
-            (h, c), (lp, layer) = carry, inp
-            return body(h, lp, c, layer), None
+            (h, c, got), (lp, layer) = carry, inp
+            if whole:
+                lp = dict(lp, moe=dict(lp["moe"], **whole))
+                return body(h, lp, c, layer, got, layer - len(prefix)), None
+            return body(h, lp, c, layer, got), None
 
         ids = jnp.arange(n, dtype=jnp.int32) + len(prefix)
-        (x, cache), _ = jax.lax.scan(step, (x, cache), (stacked, ids))
-        return x, cache
+        (x, cache, n_ex), _ = jax.lax.scan(step, (x, cache, n_ex),
+                                           (stacked, ids))
+        return x, cache, n_ex
 
-    def _run_prefix_then_stack(self, body, x, prefix, stacked, cache):
+    def _run_prefix_then_stack(self, body, x, prefix, stacked, cache, n_ex):
         """Dense-cache layer loop: the unrolled MoE dense prefix layers
         hold their own cache slices at the front of the stacked cache,
         the scanned main stack follows, and the prefix caches are
         re-stacked on the way out."""
-        def step(h, inp):
-            return body(h, *inp, None)
+        def step(carry, inp):
+            h, c, got = body(carry[0], *inp, None, carry[1])
+            return (h, got), c
 
         if not prefix:
-            return self._run_stack_cache(step, x, stacked, cache)
+            (x, n_ex), new = self._run_stack_cache(step, (x, n_ex), stacked,
+                                                   cache)
+            return x, new, n_ex
         new_pref = []
         for i, lp in enumerate(prefix):
-            x, c = body(x, lp, jax.tree.map(lambda l, i=i: l[i], cache), None)
+            x, c, n_ex = body(x, lp, jax.tree.map(lambda l, i=i: l[i], cache),
+                              None, n_ex)
             new_pref.append(c)
-        x, new_main = self._run_stack_cache(
-            step, x, stacked, jax.tree.map(lambda l: l[len(prefix):], cache))
+        (x, n_ex), new_main = self._run_stack_cache(
+            step, (x, n_ex), stacked,
+            jax.tree.map(lambda l: l[len(prefix):], cache))
         stacked_pref = jax.tree.map(lambda *ls: jnp.stack(ls), *new_pref)
         return x, jax.tree.map(lambda a, b_: jnp.concatenate([a, b_]),
-                               stacked_pref, new_main)
+                               stacked_pref, new_main), n_ex
 
     def _dense_body(self, x, lp, positions, causal, window=None):
         cfg = self.cfg
@@ -368,7 +394,7 @@ class Model:
         x = x + h
         h = layers.apply_norm(x, lp["ln2"], cfg.norm)
         if "moe" in lp:
-            h = moe.apply_moe(h, lp["moe"], cfg)
+            h = moe.apply_moe(h, lp["moe"], cfg)[0]
         else:
             h = moe.apply_ffn(h, lp["ffn"], cfg.activation)
         return x + h
@@ -671,7 +697,7 @@ class Model:
             h = constrain(h, (None, "seq", None))
             hn = layers.apply_norm(h, lp["ln2"], cfg.norm)
             if "moe" in lp:
-                return h + moe.apply_moe(hn, lp["moe"], cfg)
+                return h + moe.apply_moe(hn, lp["moe"], cfg)[0]
             return h + moe.apply_ffn(hn, lp["ffn"], cfg.activation)
 
         if cfg.family == "ssm":
@@ -742,14 +768,21 @@ class Model:
     # jit-region
     def decode_step(self, params: dict, cache, tokens: jax.Array,
                     cache_index: jax.Array,
-                    block_tables: jax.Array | None = None):
+                    block_tables: jax.Array | None = None,
+                    live: jax.Array | None = None,
+                    expert_counts: bool = False):
         """tokens: [B, 1] -> (logits [B, 1, vocab], new cache).
 
         ``cache_index``: scalar, or [B] per-slot indices (serving).
         ``block_tables``: [B, blocks_per_slot] int32 selects the paged
         cache layout (``cache`` must then be the pooled block layout from
-        ``init_cache(..., paging=...)``); None keeps the dense layout."""
+        ``init_cache(..., paging=...)``); None keeps the dense layout.
+        ``live`` ([B] bool, default all): the slots MoE layers route.
+        ``expert_counts``: also return the held experts' int32 [rows,
+        experts hit] (``_run_cache_layers``) as a third output."""
         cfg = self.cfg
+        n_ex = None
+        live = None if live is None else live[:, None]
         if block_tables is not None and cfg.family not in ("dense", "vlm",
                                                            "moe"):
             raise ValueError(
@@ -775,8 +808,8 @@ class Model:
                                                  codec=self.codec)
                 return attn.mla_decode(hn, ap, cfg, c, cache_index,
                                        codec=self.codec)
-            x, new_cache = self._run_cache_layers(attend, x, params, cache,
-                                                  block_tables is not None)
+            x, new_cache, n_ex = self._run_cache_layers(
+                attend, x, params, cache, block_tables is not None, live)
         elif cfg.family == "hybrid":
             new_cache = []
             for lp, kind, st in zip(params["layers"], self._hybrid_kinds(), cache):
@@ -816,18 +849,21 @@ class Model:
                 return attn.gqa_decode(hn, ap, cfg, c, cache_index,
                                        grouped=self.opt.grouped_gqa,
                                        codec=self.codec)
-            x, new_cache = self._run_cache_layers(attend, x, params, cache,
-                                                  block_tables is not None)
-        return self._unembed(params, x), new_cache
+            x, new_cache, n_ex = self._run_cache_layers(
+                attend, x, params, cache, block_tables is not None, live)
+        out = self._unembed(params, x), new_cache
+        return out + (n_ex,) if expert_counts else out
 
     @_with_backend
     # jit-region
     def mixed_step(self, params: dict, cache, tokens: jax.Array,
                    start: jax.Array, n_live: jax.Array,
                    block_tables: jax.Array | None = None,
-                   prefill_lanes: jax.Array | None = None):
+                   prefill_lanes: jax.Array | None = None,
+                   expert_counts: bool = False):
         """Chunked-prefill/decode mixed step: tokens [B, W] -> (logits
-        [B, W, vocab], new cache).
+        [B, W, vocab], new cache; with ``expert_counts`` the held
+        experts' int32 [rows, experts hit] third, as in ``decode_step``).
 
         Lane ``l`` of slot ``b`` sits at cache position ``start[b] + l``;
         only the first ``n_live[b]`` lanes are real.  A decoding slot uses
@@ -876,9 +912,11 @@ class Model:
                                   grouped=self.opt.grouped_gqa,
                                   codec=self.codec)
 
-        x, new_cache = self._run_cache_layers(attend, x, params, cache,
-                                              block_tables is not None)
-        return self._unembed(params, x), new_cache
+        x, new_cache, n_ex = self._run_cache_layers(
+            attend, x, params, cache, block_tables is not None,
+            masking.lane_mask(w, attn.as_index_vector(n_live, b_)))
+        out = self._unembed(params, x), new_cache
+        return out + (n_ex,) if expert_counts else out
 
 
 # ---------------------------------------------------------------------------

@@ -1,35 +1,44 @@
-"""Mixture-of-experts FFN with capacity-bounded scatter dispatch.
+"""Mixture-of-experts FFN: a router over every expert, a dropless
+token-batched dispatch to the experts this chip holds.
 
-Design notes (TPU adaptation of the paper's FFN_PM tiling):
+* **Router** (``route``): softmax top-k (Mixtral / granite), or
+  DeepSeek-V3's sigmoid ``noaux_tc`` router, whose correction bias picks
+  the experts but never weighs them, inside the ``topk_group`` best of
+  ``n_group`` expert groups.  The k gate weights are normalized over the
+  k and scaled by ``router_scale``.  Scores are float32.
+* **Held experts**: ``MoEConfig.expert_offset`` / ``held_experts`` name
+  the experts whose weights live here (expert parallelism: each chip
+  holds a slice of every MoE layer).  The router keeps its whole width;
+  the layer computes the part of the result its own experts give, and a
+  token routed only elsewhere gets nothing from them.  Parameters hold
+  the held experts alone.
+* **Dispatch** is token-batched and dropless: the step's tokens are
+  flattened, every live (token, expert) pair that lands on a held expert
+  is sorted by expert, one grouped matmul per weight matrix
+  (``kernels.grouped_matmul``, a Pallas kernel that reads only the
+  experts hit) runs the held experts over their rows, and the weighted
+  rows are added back to their tokens.  No capacity: a token's output depends on that token
+  alone, so a served stream matches a reference whatever the batch.
+  Dead lanes (``live`` False: padded mixed-step lanes, idle slots) go to
+  no expert.
+* The shared experts, which every chip computes alike, are added once.
 
-* Dispatch is *gather/scatter based*, not the one-hot-einsum dispatch of
-  the Mixtral reference — the einsum form costs O(T^2 k/E) matmul FLOPs,
-  which would swamp the expert compute in the roofline.  Scatter costs
-  zero MXU FLOPs; only the router and the expert matmuls hit the MXU, so
-  HLO FLOPs track 6·N_active·D.
-* Capacity is per sequence (`C = ceil(S*k/E * capacity_factor)`), so the
-  batch dimension stays cleanly sharded over the data axis and the expert
-  dimension over the model axis (expert parallelism).
-* Tokens over capacity are dropped (standard capacity-factor semantics);
-  the residual connection keeps them intact.
+``apply_moe`` also returns what the held experts computed, int32
+``[rows, experts hit]``: (token, expert) pairs computed, and held
+experts with at least one row.  Named scopes ``moe.route``,
+``moe.dispatch``, ``moe.experts``, ``moe.combine`` and ``moe.shared``
+sit inside ``ffn`` (``serving.events.SCOPE_NAMES``).
 """
 from __future__ import annotations
-
-import math
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, MoEConfig
 from repro.distributed.sharding import constrain
+from repro.kernels.grouped_matmul import grouped_matmul
 from repro.models import layers
 from repro.models.layers import build_dense, apply_dense, is_gated
-
-
-def capacity(seq_len: int, m: MoEConfig) -> int:
-    c = math.ceil(seq_len * m.experts_per_token / m.num_experts
-                  * m.capacity_factor)
-    return min(max(c, min(seq_len, 4)), seq_len)
 
 
 def build_ffn(b, cfg: ArchConfig, d_ff: int, use_bias: bool = False) -> dict:
@@ -42,96 +51,137 @@ def build_ffn(b, cfg: ArchConfig, d_ff: int, use_bias: bool = False) -> dict:
     return p
 
 
+def _ffn(x: jax.Array, p: dict, activation: str) -> jax.Array:
+    h = apply_dense(x, p["w1"])
+    if is_gated(activation):
+        h = layers.activate(apply_dense(x, p["wg"]), activation) * h
+    else:
+        h = layers.activate(h, activation)
+    h = constrain(h, ("batch",) + (None,) * (h.ndim - 2) + ("ffn",))
+    return apply_dense(h, p["w2"])
+
+
 def apply_ffn(x: jax.Array, p: dict, activation: str) -> jax.Array:
     with jax.named_scope("ffn"):
-        h = apply_dense(x, p["w1"])
-        if is_gated(activation):
-            h = layers.activate(apply_dense(x, p["wg"]), activation) * h
-        else:
-            h = layers.activate(h, activation)
-        h = constrain(h, ("batch",) + (None,) * (h.ndim - 2) + ("ffn",))
-        return apply_dense(h, p["w2"])
+        return _ffn(x, p, activation)
 
 
 def build_moe(b, cfg: ArchConfig) -> dict:
     m = cfg.moe
-    d = cfg.d_model
+    d, e = cfg.d_model, m.n_held
     p = {
         "router": b.param((d, m.num_experts), ("embed", "experts")),
-        "w1": b.param((m.num_experts, d, m.expert_d_ff),
-                      ("experts", "embed", "ffn")),
-        "w2": b.param((m.num_experts, m.expert_d_ff, d),
-                      ("experts", "ffn", "embed")),
+        "w1": b.param((e, d, m.expert_d_ff), ("experts", "embed", "ffn")),
+        "w2": b.param((e, m.expert_d_ff, d), ("experts", "ffn", "embed")),
     }
+    if m.scoring == "sigmoid":
+        p["router_bias"] = b.param((m.num_experts,), ("experts",),
+                                   init="zeros")
     if is_gated(cfg.activation):
-        p["wg"] = b.param((m.num_experts, d, m.expert_d_ff),
-                          ("experts", "embed", "ffn"))
+        p["wg"] = b.param((e, d, m.expert_d_ff), ("experts", "embed", "ffn"))
     if m.num_shared_experts:
         p["shared"] = build_ffn(
             b, cfg, m.num_shared_experts * m.shared_expert_d_ff)
     return p
 
 
-def route(x: jax.Array, router_w: jax.Array, m: MoEConfig
+def route(x: jax.Array, p: dict, m: MoEConfig
           ) -> tuple[jax.Array, jax.Array]:
-    """Top-k routing.  Returns (weights [.., k], expert ids [.., k]).
+    """Top-k routing over every expert.  Returns (gate weights [.., k]
+    float32, expert ids [.., k]).
 
-    Softmax gating re-normalized over the selected k (Mixtral/granite
-    style), scaled by ``router_scale`` (DeepSeek's routed_scaling_factor).
-    """
+    ``softmax``: top k of the softmax.  ``sigmoid`` (DeepSeek-V3
+    ``noaux_tc``): scores s = sigmoid(logits); the selection scores
+    s + ``router_bias`` rank the groups (each the sum of its two best)
+    and, inside the ``topk_group`` best groups, the experts; the weights
+    are the picked s without the bias.  Either way the k weights are
+    normalized over the k and scaled by ``router_scale``."""
     logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32),
-                        router_w.astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = jax.lax.top_k(probs, m.experts_per_token)
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
-    return (m.router_scale * top_p), top_i
-
-
-def _dispatch_one(x, top_w, top_i, p, m: MoEConfig, activation: str, cap: int):
-    """Per-sequence expert dispatch.  x: [S, d]; top_*: [S, k]."""
-    s, d = x.shape
-    k = m.experts_per_token
-    flat_e = top_i.reshape(s * k)                        # expert of each slot
-    flat_w = top_w.reshape(s * k)
-    # position of each slot within its expert (order-preserving)
-    onehot = jax.nn.one_hot(flat_e, m.num_experts, dtype=jnp.int32)
-    pos = jnp.cumsum(onehot, axis=0) - onehot            # [S*k, E]
-    flat_pos = jnp.take_along_axis(pos, flat_e[:, None], axis=1)[:, 0]
-    dropped = flat_pos >= cap
-    # scatter tokens into the [E, C, d] expert buffers ('drop' discards o.o.b.)
-    src = jnp.repeat(x, k, axis=0)                       # [S*k, d] token copies
-    e_idx = jnp.where(dropped, m.num_experts, flat_e)    # row E == trash
-    buf = jnp.zeros((m.num_experts, cap, d), x.dtype)
-    buf = buf.at[e_idx, jnp.minimum(flat_pos, cap - 1)].set(src, mode="drop")
-    # expert FFNs, batched over E
-    h = jnp.einsum("ecd,edf->ecf", buf, p["w1"].astype(x.dtype))
-    if "wg" in p:
-        g = jnp.einsum("ecd,edf->ecf", buf, p["wg"].astype(x.dtype))
-        h = layers.activate(g, activation) * h
+                        p["router"].astype(jnp.float32))
+    if m.scoring == "softmax":
+        top_s, top_i = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                     m.experts_per_token)
     else:
-        h = layers.activate(h, activation)
-    out_buf = jnp.einsum("ecf,efd->ecd", h, p["w2"].astype(x.dtype))
-    # gather back and combine with routing weights
-    got = out_buf[e_idx.clip(0, m.num_experts - 1), jnp.minimum(flat_pos, cap - 1)]
-    got = jnp.where(dropped[:, None], 0.0, got) * flat_w[:, None].astype(x.dtype)
-    return got.reshape(s, k, d).sum(axis=1)
+        scores = jax.nn.sigmoid(logits)
+        choice = scores + p["router_bias"].astype(jnp.float32)
+        if m.n_group > 1:
+            grouped = choice.reshape(*choice.shape[:-1], m.n_group, -1)
+            group_score = jax.lax.top_k(grouped, 2)[0].sum(-1)
+            _, keep = jax.lax.top_k(group_score, m.topk_group)
+            kept = jnp.any(keep[..., :, None]
+                           == jnp.arange(m.n_group), axis=-2)   # [.., G]
+            choice = jnp.where(kept[..., None], grouped,
+                               -jnp.inf).reshape(choice.shape)
+        _, top_i = jax.lax.top_k(choice, m.experts_per_token)
+        top_s = jnp.take_along_axis(scores, top_i, axis=-1)
+    top_s = top_s / jnp.sum(top_s, axis=-1, keepdims=True)
+    return m.router_scale * top_s, top_i
 
 
-def apply_moe(x: jax.Array, p: dict, cfg: ArchConfig) -> jax.Array:
-    """x: [B, S, d] -> [B, S, d].  Routed experts + optional shared expert."""
+# the held experts' weights, [e, ...] a layer
+EXPERT_WEIGHTS = ("w1", "wg", "w2")
+
+
+def _held_experts(x: jax.Array, top_w: jax.Array, top_i: jax.Array,
+                  live: jax.Array, p: dict, m: MoEConfig, activation: str,
+                  layer=None):
+    """The held experts' part of the routed output for tokens ``x``
+    [T, d] (``top_*`` [T, k], ``live`` [T]): [T, d] float32, and int32
+    [rows, experts hit].  ``layer``: see ``apply_moe``."""
+    t, d = x.shape
+    k, e = m.experts_per_token, m.n_held
+    with jax.named_scope("moe.dispatch"):
+        local = top_i.reshape(t * k) - m.expert_offset
+        held = (local >= 0) & (local < e) & jnp.repeat(live, k)
+        key = jnp.where(held, local, e)              # e: not computed here
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.zeros((e + 1,), jnp.int32).at[key].add(1)[:e]
+        token = order // k
+        rows = x[token]                              # [T*k, d], by expert
+        valid = held[order]
+    with jax.named_scope("moe.experts"):
+        h = grouped_matmul(rows, p["w1"], sizes, layer)
+        if "wg" in p:
+            h = layers.activate(grouped_matmul(rows, p["wg"], sizes, layer),
+                                activation) * h
+        else:
+            h = layers.activate(h, activation)
+        y = grouped_matmul(h, p["w2"], sizes, layer)
+    with jax.named_scope("moe.combine"):
+        # rows past the groups hold no held pair and are left unwritten:
+        # they are cut, not weighted
+        w = top_w.reshape(t * k)[order][:, None]
+        y = jnp.where(valid[:, None], y.astype(jnp.float32) * w, 0.0)
+        out = jnp.zeros((t, d), jnp.float32).at[token].add(y)
+    counts = jnp.stack([sizes.sum(), (sizes > 0).sum()]).astype(jnp.int32)
+    return out, counts
+
+
+def apply_moe(x: jax.Array, p: dict, cfg: ArchConfig,
+              live: jax.Array | None = None, layer=None
+              ) -> tuple[jax.Array, jax.Array]:
+    """x: [B, S, d] -> ([B, S, d], int32 [rows, experts hit]).  The held
+    routed experts plus the shared expert; ``live`` ([B, S] bool, default
+    all) marks the tokens that are routed at all.  With ``layer``, the
+    ``EXPERT_WEIGHTS`` of ``p`` are stacked over the MoE layers and this
+    layer's are ``[layer]`` (the serving layer scan passes them whole,
+    ``kernels.grouped_matmul``)."""
     m = cfg.moe
     b_, s, d = x.shape
-    cap = capacity(s, m)
+    flat = x.reshape(b_ * s, d)
+    live = jnp.ones((b_ * s,), bool) if live is None \
+        else live.reshape(b_ * s)
     with jax.named_scope("ffn"):
-        top_w, top_i = route(x, p["router"], m)
-        routed = jax.vmap(
-            lambda xi, wi, ii: _dispatch_one(xi, wi, ii, p, m,
-                                             cfg.activation, cap)
-        )(x, top_w, top_i)
-        routed = constrain(routed, ("batch", None, None))
+        with jax.named_scope("moe.route"):
+            top_w, top_i = route(flat, p, m)
+        routed, counts = _held_experts(flat, top_w, top_i, live, p, m,
+                                       cfg.activation, layer)
+        out = constrain(routed.astype(x.dtype).reshape(b_, s, d),
+                        ("batch", None, None))
         if "shared" in p:
-            routed = routed + apply_ffn(x, p["shared"], cfg.activation)
-        return routed
+            with jax.named_scope("moe.shared"):
+                out = out + _ffn(x, p["shared"], cfg.activation)
+        return out, counts
 
 
 def load_balance_loss(x: jax.Array, router_w: jax.Array, m: MoEConfig) -> jax.Array:

@@ -35,7 +35,7 @@ from jax.profiler import TraceAnnotation
 from repro.core.paging import blocks_for_tokens
 from repro.core.spec import MeshSpec, RuntimeSpec
 from repro.serving.engine import Request, ServingEngine
-from repro.serving.events import EngineEvent, EventBus
+from repro.serving.events import ENGINE_KINDS, EngineEvent, EventBus
 
 
 class EngineCluster:
@@ -79,7 +79,7 @@ class EngineCluster:
         def cb(e: EngineEvent) -> None:
             if not self.events.active:
                 return
-            if e.kind == "dispatch":   # no request: say whose step it was
+            if e.kind in ENGINE_KINDS:   # no request: say whose step
                 self.events.publish(EngineEvent(
                     e.kind, e.uid, self.stats["decode_steps"], e.t,
                     {**e.data, "replica": idx}))

@@ -161,6 +161,9 @@ class SlotState(NamedTuple):
     # speculative-decoding accounting (zeros when speculation is off)
     acc: jax.Array         # [B] i32 accepted draft tokens, cumulative
     spec_steps: jax.Array  # [B] i32 fused steps this slot spec-decoded in
+    # MoE models: [2] i32 (token, expert) rows the held experts computed
+    # and held experts hit, summed over layers and steps (None otherwise)
+    experts: jax.Array | None = None
 
 
 class _Compilations(dict):
@@ -472,6 +475,12 @@ class ServingEngine:
                 # every other operand follows the committed arrays
                 self.params = jax.device_put(self.params, self._placement)
                 self.cache = jax.device_put(self.cache, self._placement)
+        # MoE models count their held experts' work in the slot state
+        # (``SlotState.experts``); each harvest publishes what it added
+        self._experts = self._traced_model is not None \
+            and self._traced_model.cfg.moe is not None
+        self._experts_seen = [0, 0]
+        self._unsynced = 0        # dispatches since the last harvest
         self.state: SlotState = self._init_state(
             rng if rng is not None else jax.random.PRNGKey(0))
         if self._placement is not None:
@@ -530,7 +539,8 @@ class ServingEngine:
             prompt_len=jnp.zeros((B,), jnp.int32),
             pf_pos=jnp.zeros((B,), jnp.int32),
             acc=jnp.zeros((B,), jnp.int32),
-            spec_steps=jnp.zeros((B,), jnp.int32))
+            spec_steps=jnp.zeros((B,), jnp.int32),
+            experts=jnp.zeros((2,), jnp.int32) if self._experts else None)
 
     def _emit(self, kind: str, uid: int, **data) -> None:
         """Publish one lifecycle event (no-op without subscribers).  The
@@ -818,7 +828,8 @@ class ServingEngine:
             prompt_len=state.prompt_len.at[slot].set(plen),
             pf_pos=state.pf_pos.at[slot].set(plen),  # bucketed: prefilled
             acc=state.acc.at[slot].set(0),
-            spec_steps=state.spec_steps.at[slot].set(0))
+            spec_steps=state.spec_steps.at[slot].set(0),
+            experts=state.experts)
 
     def _admit_chunk_impl(self, state: SlotState, slot, toks, plen, budget,
                           eos, temp, top_k, top_p, topo,
@@ -849,7 +860,8 @@ class ServingEngine:
             prompt_len=state.prompt_len.at[slot].set(plen),
             pf_pos=state.pf_pos.at[slot].set(start),
             acc=state.acc.at[slot].set(0),
-            spec_steps=state.spec_steps.at[slot].set(0))
+            spec_steps=state.spec_steps.at[slot].set(0),
+            experts=state.experts)
 
     def _cow_impl(self, cache, src, dst):
         """Fork pool block ``src`` into ``dst`` across every cache leaf
@@ -892,9 +904,12 @@ class ServingEngine:
                     paged_attn_impl=self.spec.execution.paged_attn_impl,
                     interpret=self._interpret)
             else:
-                logits, cache = self._traced_model.decode_step(
+                out = self._traced_model.decode_step(
                     params, cache, state.last, state.index,
-                    block_tables=block_tables)
+                    block_tables=block_tables, live=state.active,
+                    expert_counts=self._experts)
+                logits, cache = out[:2]
+                state = self._add_expert_counts(state, out)
             with jax.named_scope("sample"):
                 toks = sample_per_slot(logits[:, 0], keys, state.temp,
                                        state.top_k, state.top_p)
@@ -960,9 +975,12 @@ class ServingEngine:
                     paged_attn_impl=self.spec.execution.paged_attn_impl,
                     interpret=self._interpret)
             else:
-                logits, cache = self._traced_model.mixed_step(
+                out = self._traced_model.mixed_step(
                     params, cache, toks, start, n_live,
-                    block_tables=block_tables, prefill_lanes=prefilling)
+                    block_tables=block_tables, prefill_lanes=prefilling,
+                    expert_counts=self._experts)
+                logits, cache = out[:2]
+                state = self._add_expert_counts(state, out)
 
             # sampling lane: a completing prompt's last live lane, else 0
             completes = prefilling & \
@@ -997,6 +1015,13 @@ class ServingEngine:
                     rng=rng,
                     pf_pos=pf_pos)
             return self._pin_outputs(cache, state)
+
+    def _add_expert_counts(self, state: SlotState, out) -> SlotState:
+        """Add a model step's held-expert counts (its third output, MoE
+        models only) to the running totals in ``state``."""
+        if not self._experts:
+            return state
+        return state._replace(experts=state.experts + out[2])
 
     def _spec_impl(self, params, cache, state: SlotState, block_tables,
                    chunk_len, decode_only: bool):
@@ -1559,6 +1584,7 @@ class ServingEngine:
             else:
                 self.cache = cache
             self.stats["decode_steps"] += 1
+            self._unsynced += 1
             self.stats["max_step_prefill_tokens"] = max(
                 self.stats["max_step_prefill_tokens"], granted)
             occ = self._occupied()
@@ -1614,16 +1640,22 @@ class ServingEngine:
         pulled (one more bulk get) only for slots that actually finished,
         sliced to the longest finished stream — the transfer scales with
         the tokens produced, not with max_len."""
+        st = self.state
         with TraceAnnotation("engine.harvest.wait"):
-            if self.speculation is not None:
-                done_h, count_h, acc_h, ss_h = jax.device_get(
-                    (self.state.done, self.state.count, self.state.acc,
-                     self.state.spec_steps))
-            else:
-                done_h, count_h = jax.device_get(
-                    (self.state.done, self.state.count))
-                acc_h = ss_h = None
+            done_h, count_h, acc_h, ss_h, ex_h = jax.device_get(
+                (st.done, st.count,
+                 *((st.acc, st.spec_steps) if self.speculation is not None
+                   else (None, None)), st.experts))
         self.stats["device_gets"] += 1
+        if ex_h is not None:
+            # totals since the last harvest (int32 on the device: the
+            # difference is taken modulo 2**32)
+            rows, hit = ((int(t) - s) % 2**32
+                         for t, s in zip(ex_h, self._experts_seen))
+            self._experts_seen = [int(t) for t in ex_h]
+            self._emit("experts", -1, expert_rows=rows, experts_hit=hit,
+                       dispatches=self._unsynced)
+        self._unsynced = 0
         occ = self._occupied()
         slots = [i for i in occ if done_h[i]]
         for i in occ:   # sync point: tighten the index upper bounds

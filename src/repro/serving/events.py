@@ -36,7 +36,7 @@ Lifecycle of one request::
 * ``preempt``      — the slot was recompute-preempted; the request
   re-enters admission later.  data: ``banked`` (tokens carried over).
 
-One more kind belongs to no request (``uid == -1``):
+Two more kinds belong to no request (``uid == -1``, ``ENGINE_KINDS``):
 
 * ``dispatch``     — one per step program launched, right after the
   launch (its stamp is the host's, not the device's).  data:
@@ -49,6 +49,14 @@ One more kind belongs to no request (``uid == -1``):
   counts).  It carries the same ``step`` as the ``progress`` events
   of the harvest that follows it.  An :class:`EngineCluster` relays
   it with ``replica`` added.
+* ``experts``      — MoE models only, one per harvest, after its read:
+  what the held experts computed since the previous harvest, summed
+  over the MoE layers.  data: ``expert_rows`` ((token, expert) pairs
+  routed to a held expert and computed), ``experts_hit`` (held experts
+  with at least one row, counted per layer and step) and
+  ``dispatches`` (step programs launched since the previous harvest).
+  The totals ride in the device-side slot state and come back in the
+  harvest's one read of done/count.  Speculative steps are not counted.
 
 Every event carries the engine's logical clock (``step`` = fused
 dispatches so far) and a ``time.perf_counter()`` wall stamp.  Step
@@ -66,7 +74,9 @@ from dataclasses import dataclass, field
 from typing import Any, Callable
 
 EVENT_KINDS = ("submit", "admit", "first_token", "progress", "finish",
-               "preempt", "dispatch")
+               "preempt", "dispatch", "experts")
+# the kinds that belong to no request (uid -1)
+ENGINE_KINDS = ("dispatch", "experts")
 
 # Host spans (``jax.profiler.TraceAnnotation``) the engine opens around
 # its phases.  They cost ~1 µs each while no profiler runs; under one
@@ -103,6 +113,11 @@ SCOPE_NAMES = (
     "attn.core",         # scores and values, or the Pallas kernel
     "attn.out",          # the output projection
     "ffn",               # dense FFN or MoE
+    "moe.route",         # in ffn: router scores and top-k selection
+    "moe.dispatch",      # in ffn: held (token, expert) pairs sorted
+    "moe.experts",       # in ffn: the held experts' grouped matmuls
+    "moe.combine",       # in ffn: weighted rows added back to tokens
+    "moe.shared",        # in ffn: the shared expert
     "head",              # final norm + vocabulary head (Model._unembed)
     "sample",            # the sampler over the step's logits
     "slot_update",       # the SlotState writes after sampling

@@ -1,4 +1,3 @@
-import dataclasses
 import os
 import re
 
@@ -18,23 +17,13 @@ import pytest  # noqa: E402
 from repro.configs import REGISTRY, reduced  # noqa: E402
 
 
-def no_drop(cfg):
-    """Reduced MoE configs with lossless capacity (for equivalence tests)."""
-    if cfg.moe is None:
-        return cfg
-    return dataclasses.replace(
-        cfg, moe=dataclasses.replace(cfg.moe,
-                                     capacity_factor=float(cfg.moe.num_experts)))
-
-
 @pytest.fixture(scope="session")
 def rng():
     return jax.random.PRNGKey(0)
 
 
-def reduced_cfg(name, lossless_moe=False):
-    cfg = reduced(REGISTRY[name])
-    return no_drop(cfg) if lossless_moe else cfg
+def reduced_cfg(name):
+    return reduced(REGISTRY[name])
 
 
 # Instructions that move a whole KV pool (or one layer of it) instead of

@@ -280,7 +280,7 @@ def test_auto_falls_back_for_unchunkable(params):
 # MLA + the Pallas chunk kernel
 # ---------------------------------------------------------------------------
 def test_mla_chunked_dense_matches_paged():
-    cfg = reduced_cfg("deepseek-v3-671b", lossless_moe=True)
+    cfg = reduced_cfg("deepseek-v3-671b")
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     streams = {}

@@ -38,7 +38,7 @@ FAST_FAMS = ["qwen1.5-0.5b", "falcon-mamba-7b", "granite-moe-1b-a400m",
 
 
 def _check_prefill_then_decode(name: str, steps: int) -> None:
-    cfg = reduced_cfg(name, lossless_moe=True)
+    cfg = reduced_cfg(name)
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     B, P = 2, 5

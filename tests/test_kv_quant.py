@@ -101,7 +101,7 @@ def test_bad_kv_dtype_rejected():
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("name", ["qwen1.5-0.5b", "deepseek-v3-671b"])
 def test_init_cache_int8_structure(name):
-    cfg = reduced_cfg(name, lossless_moe=True)
+    cfg = reduced_cfg(name)
     model = Model(cfg, ModelOptions(kv_dtype="int8"))
     cache = model.init_cache(2, 16)
     abstract = model.init_cache(2, 16, abstract=True)
@@ -127,7 +127,7 @@ def test_init_cache_int8_rejects_recurrent_families():
 def test_int8_cache_decode_tracks_float_cache(name):
     """prefill + token-by-token decode with the int8 cache stays within
     quantization tolerance of the float cache at every step."""
-    cfg = reduced_cfg(name, lossless_moe=True)
+    cfg = reduced_cfg(name)
     fm = Model(cfg, ModelOptions(kv_dtype="compute"))
     qm = Model(cfg, ModelOptions(kv_dtype="int8"))
     params = fm.init(jax.random.PRNGKey(0))
@@ -153,7 +153,7 @@ def test_int8_paged_decode_tracks_float_dense(name):
     """Paged int8 decode (block-table gather + scale gather) stays within
     quantization tolerance of the float dense cache."""
     from repro.core.paging import PagingConfig
-    cfg = reduced_cfg(name, lossless_moe=True)
+    cfg = reduced_cfg(name)
     fm = Model(cfg, ModelOptions(kv_dtype="compute"))
     qm = Model(cfg, ModelOptions(kv_dtype="int8"))
     params = fm.init(jax.random.PRNGKey(0))
@@ -210,7 +210,7 @@ def _serve(cfg, params, kv_dtype, layout="dense", policy="auto",
 
 @pytest.mark.parametrize("name", ["qwen1.5-0.5b", "deepseek-v3-671b"])
 def test_int8_cache_streams_match_float(name):
-    cfg = reduced_cfg(name, lossless_moe=True)
+    cfg = reduced_cfg(name)
     params = Model(cfg).init(jax.random.PRNGKey(0))
     base, _ = _serve(cfg, params, "compute")
     for kwargs in ({}, {"layout": "paged"}, {"policy": "bucketed"},

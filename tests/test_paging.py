@@ -338,7 +338,7 @@ def test_sample_per_slot_support():
 # MLA paged layout
 # ---------------------------------------------------------------------------
 def test_mla_paged_matches_dense():
-    cfg = reduced_cfg("deepseek-v3-671b", lossless_moe=True)
+    cfg = reduced_cfg("deepseek-v3-671b")
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     streams = {}
@@ -365,7 +365,7 @@ def test_paged_matches_dense_streams(name, kv_dtype, unroll):
     streams: with a MoE dense prefix, under the int8 codec, and on the
     unrolled layer loop."""
     from repro.core.spec import MemorySpec, RuntimeSpec
-    cfg = reduced_cfg(name, lossless_moe=True)
+    cfg = reduced_cfg(name)
     params = Model(cfg).init(jax.random.PRNGKey(0))
     reqs = [([5, 6, 7], 6), (list(range(1, 20)), 5), ([9, 8], 7)]
     streams = {}

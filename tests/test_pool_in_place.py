@@ -25,7 +25,7 @@ from repro.serving.sampling import SamplingParams
 
 
 def _engine(name, kv_dtype, mesh=MeshSpec()):
-    cfg = reduced_cfg(name, lossless_moe=True)
+    cfg = reduced_cfg(name)
     eng = ServingEngine(RuntimeSpec(
         arch=cfg, mesh=mesh,
         memory=MemorySpec(cache_layout="paged", max_batch=4, max_len=64,

@@ -11,7 +11,9 @@ Shapes follow the one-chip serving configuration: 8 slots, 16 query and
 W = 16 lane chunk; the matmuls are the FFN and vocabulary projections
 at 128 rows (8 slots x 16 lanes).
 
-The model's decode and mixed steps are compiled too, at two layers of
+The MoE layer's grouped expert matmul compiles at DeepSeek-V3's widths
+(d 7168, expert width 2048, 8 held experts).  The model's decode and
+mixed steps are compiled too, at two layers of
 the benchmark's widths (qwen1.5-0.5b: 32 slots x 2048 positions;
 CodeQwen1.5-7B's 4 KV heads x 128: 16 x 8192) with the pool donated,
 bf16 and int8: the chip's layouts must let every layer write and gather
@@ -118,6 +120,21 @@ def test_tiled_matmul_compiles(one_chip, monkeypatch, k, n):
     monkeypatch.setattr(ops, "interpret_default", lambda: False)
     compiled = _compile(ops.tiled_matmul, one_chip,
                         ((B * W, k), jnp.bfloat16), ((k, n), jnp.bfloat16))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows", [16 * 8, 16 * 16 * 8])
+@pytest.mark.parametrize("k,n", [(7168, 2048), (2048, 7168)])
+def test_grouped_matmul_compiles(one_chip, rows, k, n):
+    """The expert matmul at DeepSeek-V3's widths over the 8 experts one
+    chip holds: a decode step's 16 slots x 8 picks and a mixed step's 16
+    x 16 lanes x 8 rows, sorted by expert."""
+    from repro.kernels.grouped_matmul import grouped_matmul
+
+    compiled = _compile(
+        functools.partial(grouped_matmul, interpret=False), one_chip,
+        ((rows, k), jnp.bfloat16), ((8, k, n), jnp.bfloat16),
+        ((8,), jnp.int32))
     assert "tpu_custom_call" in compiled.as_text()
 
 

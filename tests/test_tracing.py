@@ -1,22 +1,28 @@
 """The serving engine's own marks for a profiler and for event readers.
 
 * the mixed and decode step programs carry every ``SCOPE_NAMES`` entry
-  in their compiled HLO ``op_name`` metadata (GQA and MLA paged paths);
+  in their compiled HLO ``op_name`` metadata (GQA and MLA paged paths;
+  the ``moe.*`` scopes where the model has experts);
 * one ``dispatch`` event per step program launched, with the grants,
   lanes and live lanes of that launch (and relayed by a cluster with
   its replica);
+* an MoE model's ``experts`` event per harvest holds what a host recount
+  of the routing gives;
 * under ``jax.profiler`` the engine's phase spans nest under
   ``engine.step`` and carry their kwargs.
 """
+import dataclasses
 import glob
 import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from conftest import reduced_cfg
 from repro.core.spec import MemorySpec, MeshSpec, RuntimeSpec, SchedulerSpec
+from repro.models import moe
 from repro.models.model import Model
 from repro.serving.cluster import EngineCluster
 from repro.serving.engine import ServingEngine
@@ -35,8 +41,8 @@ def _spec(cfg, policy="chunked", mesh=None, **sched):
         scheduler=SchedulerSpec(policy=policy, **sched))
 
 
-def _engine(name="qwen1.5-0.5b", policy="chunked", **sched):
-    cfg = reduced_cfg(name, lossless_moe=True)
+def _engine(name="qwen1.5-0.5b", policy="chunked", cfg=None, **sched):
+    cfg = cfg or reduced_cfg(name)
     eng = ServingEngine(_spec(cfg, policy, **sched),
                         sampling=SamplingParams())
     eng.load(Model(cfg).init(jax.random.PRNGKey(0)))
@@ -56,7 +62,9 @@ def test_step_programs_carry_every_scope(name, program):
     text = lowered.compile().as_text()
     parts = {p for op in re.findall(r'op_name="([^"]*)"', text)
              for p in op.split("/")}
-    assert set(SCOPE_NAMES) <= parts, set(SCOPE_NAMES) - parts
+    want = {sc for sc in SCOPE_NAMES
+            if eng.model.cfg.moe is not None or not sc.startswith("moe.")}
+    assert want <= parts, want - parts
 
 
 def _drain(eng):
@@ -161,3 +169,45 @@ def test_engine_spans_nest_under_step(tmp_path):
     programs = {st["program"] for n, _, _, st in spans
                 if n == "engine.dispatch"}
     assert programs == {"mixed", "decode"}
+
+
+def test_experts_event_matches_a_host_recount_of_the_routing(monkeypatch):
+    """A layer holding experts 2-3 of 4: each harvest's ``experts`` event
+    gives the (token, expert) pairs of live lanes routed to them and the
+    held experts hit, summed over the MoE layers of the step, as the
+    routing itself (recorded from inside the step) says."""
+    base = reduced_cfg("deepseek-v3-671b")
+    cfg = dataclasses.replace(base, num_layers=3, moe=dataclasses.replace(
+        base.moe, expert_offset=2, held_experts=2))
+    calls = []
+    held = moe._held_experts
+
+    def recording(x, top_w, top_i, live, *args):
+        jax.debug.callback(
+            lambda i, l: calls.append((np.asarray(i), np.asarray(l))),
+            top_i, live)
+        return held(x, top_w, top_i, live, *args)
+
+    monkeypatch.setattr(moe, "_held_experts", recording)
+    eng = _engine(cfg=cfg)
+    log = EventLog()
+    eng.events.subscribe(log)
+    for i, p in enumerate(PROMPTS):
+        eng.submit(p, max_new_tokens=3 + i)
+    want = []
+    while eng.queue or any(r is not None for r in eng.slot_req):
+        n0 = len(calls)
+        eng.step()
+        rows = hit = 0
+        for ids, live in calls[n0:]:       # one call per MoE layer
+            local = ids[live] - 2
+            mine = local[(local >= 0) & (local < 2)]
+            rows += mine.size
+            hit += len(set(mine.tolist()))
+        assert len(calls) - n0 == 2
+        want.append((rows, hit))
+    got = [(e.data["expert_rows"], e.data["experts_hit"])
+           for e in log.events if e.kind == "experts"]
+    assert got == want and sum(r for r, _ in got) > 0
+    assert all(e.uid == -1 and e.data["dispatches"] == 1
+               for e in log.events if e.kind == "experts")
