@@ -5,6 +5,8 @@ Paper module -> kernel map:
   QK_PM + softmax + SV_PM    -> flash_attention (fused, online softmax)
   paged KV decode            -> paged_attention (block-table gather fused
                                 into the flash-decode grid)
+  MLA (absorbed, latent)     -> mla_attention (online softmax over each
+                                slot's live key blocks)
   FFN1/2/3_PM + bias + act   -> ffn (ffn1 / ffn1_gated) + tiled_matmul
   LN unit (Alg. 8)           -> layernorm (layernorm / rmsnorm)
   Fig. 4 tiling discipline   -> tiled_matmul (K-tiled accumulation)

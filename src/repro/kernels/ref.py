@@ -80,3 +80,20 @@ def int8_matmul_ref(qx_values, qx_scale, qw_values, qw_scale,
     acc = jnp.matmul(qx_values.astype(jnp.int32), qw_values.astype(jnp.int32))
     out = acc.astype(jnp.float32) * qx_scale * qw_scale.reshape(1, -1)
     return out.astype(out_dtype)
+
+
+def mla_attend_ref(q_lat, q_rope, c_kv, k_rope, start, *, scale):
+    """Absorbed latent attention as one einsum chain over the whole view:
+    f32 scores ``[B, h, W, t_max]``, lane ``l`` of slot ``b`` masked to
+    positions ``<= start[b] + l``, an f32 softmax, probabilities in
+    ``c_kv.dtype`` against the latent view.  -> [B, W, h, rank]."""
+    w, t_max = q_lat.shape[1], c_kv.shape[1]
+    s_lat = jnp.einsum("bqhl,bkl->bhqk", q_lat.astype(jnp.float32),
+                       c_kv.astype(jnp.float32))
+    s_rope = jnp.einsum("bqhd,bkd->bhqk", q_rope.astype(jnp.float32),
+                        k_rope.astype(jnp.float32))
+    pos = start[:, None] + jnp.arange(w)[None, :]
+    live = jnp.arange(t_max)[None, None, :] <= pos[:, :, None]
+    s = jnp.where(live[:, None], (s_lat + s_rope) * scale, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhqk,bkl->bqhl", p.astype(c_kv.dtype), c_kv)

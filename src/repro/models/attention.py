@@ -31,6 +31,7 @@ from repro.core.kv_quant import (FLOAT_CODEC, CacheCodec, cache_put,
                                  gather_view, layer_view)
 from repro.core.paging import NULL_BLOCK
 from repro.distributed.sharding import constrain
+from repro.kernels.mla_attention import latent_attention
 from repro.kernels.runtime import interpret_default
 from repro.models import layers
 from repro.models.layers import apply_rope, build_dense, apply_dense
@@ -641,30 +642,29 @@ def mla_attention(x: jax.Array, p: dict, cfg: ArchConfig, *,
 
 def _mla_attend(x: jax.Array, p: dict, cfg: ArchConfig, q_nope: jax.Array,
                 q_rope: jax.Array, c_kv: jax.Array, k_rope: jax.Array,
-                live: jax.Array) -> jax.Array:
-    """Absorbed-matmul contraction over a sequence-major latent view
-    (c_kv [B, S, rank], k_rope [B, S, rope_dim]) — shared by both cache
+                start: jax.Array) -> jax.Array:
+    """Absorbed-matmul attention over a sequence-major latent view
+    (c_kv [B, S, rank], k_rope [B, S, rope_dim]): lane ``l`` of slot
+    ``b`` sits at position ``start[b] + l`` and sees positions up to it.
+    The scores, softmax and latent values run in the ``latent_attention``
+    kernel over each slot's live key blocks.  Shared by both cache
     layouts, which is what keeps dense and paged decode bit-identical."""
     m, h = cfg.mla, cfg.num_heads
-    b_, one = q_nope.shape[:2]
+    b_, w = q_nope.shape[:2]
     # the int8 weight path keeps these kernels quantized: read them whole
     wk = layers.maybe_dequant(p["k_up"]["kernel"], jnp.float32).reshape(
         m.kv_lora_rank, h, m.qk_nope_head_dim)
-    # absorb k_up into the query: q_lat [B,1,h,kv_lora] (f32: one token only)
+    # absorb k_up into the query: q_lat [B, W, h, kv_lora]
     q_lat = jnp.einsum("bqhd,lhd->bqhl", q_nope.astype(jnp.float32), wk)
-    s_lat = jnp.einsum("bqhl,bkl->bhqk", q_lat, c_kv.astype(jnp.float32))
-    s_rope = jnp.einsum("bqhd,bkd->bhqk", q_rope.astype(jnp.float32),
-                        k_rope.astype(jnp.float32))
-    scores = (s_lat + s_rope) / mla_score_divisor(cfg)
-    scores = jnp.where(live, scores, NEG_INF)
-    pr = jax.nn.softmax(scores, axis=-1)
     # attend in latent space, then expand once per step via v_up
-    o_lat = jnp.einsum("bhqk,bkl->bqhl", pr.astype(c_kv.dtype), c_kv)
+    o_lat = latent_attention(q_lat, q_rope, c_kv, k_rope, start,
+                             scale=1.0 / mla_score_divisor(cfg),
+                             interpret=interpret_default())
     wv = jnp.transpose(layers.maybe_dequant(p["v_up"]["kernel"], x.dtype)
                        .reshape(m.kv_lora_rank, h, m.v_head_dim), (1, 0, 2))
     o = jnp.einsum("bqhl,hld->bqhd", o_lat, wv)
     with jax.named_scope("attn.out"):
-        return apply_dense(o.reshape(b_, one, h * m.v_head_dim), p["wo"])
+        return apply_dense(o.reshape(b_, w, h * m.v_head_dim), p["wo"])
 
 
 def mla_decode(x: jax.Array, p: dict, cfg: ArchConfig, cache: MLACache,
@@ -685,11 +685,9 @@ def mla_decode(x: jax.Array, p: dict, cfg: ArchConfig, cache: MLACache,
                             cq, cs)
     k_rope, r_sc = cache_put(cache.k_rope, cache.r_scale, (rows, idx_vec),
                               rq, rs)
-    s_max = c_kv.shape[1]
-    live = (jnp.arange(s_max)[None] <= idx_vec[:, None])[:, None, None, :]
     out = _mla_attend(x, p, cfg, q_nope, q_rope,
                       codec.load(c_kv, c_sc, x.dtype),
-                      codec.load(k_rope, r_sc, x.dtype), live)
+                      codec.load(k_rope, r_sc, x.dtype), idx_vec)
     return out, MLACache(c_kv, k_rope, c_sc, r_sc)
 
 
@@ -725,8 +723,7 @@ def mla_decode_paged(x: jax.Array, p: dict, cfg: ArchConfig, cache: MLACache,
         kr_g = gather_view(codec, k_rope, r_sc, block_tables,
                            (b_, t_max, m.qk_rope_head_dim), x.dtype, layer)
     with jax.named_scope("attn.core"):   # attn.out inside
-        live = (jnp.arange(t_max)[None] <= idx_vec[:, None])[:, None, None, :]
-        out = _mla_attend(x, p, cfg, q_nope, q_rope, ckv_g, kr_g, live)
+        out = _mla_attend(x, p, cfg, q_nope, q_rope, ckv_g, kr_g, idx_vec)
     return out, MLACache(c_kv, k_rope, c_sc, r_sc)
 
 
@@ -749,10 +746,9 @@ def mla_mixed(x: jax.Array, p: dict, cfg: ArchConfig, cache: MLACache,
     c_kv, c_sc = cache_put(cache.c_kv, cache.c_scale, (rows, pos), cq, cs)
     k_rope, r_sc = cache_put(cache.k_rope, cache.r_scale, (rows, pos),
                               rq, rs)
-    live = masking.chunk_causal_mask(s_max, start, w)[:, None]  # [B,1,W,S]
     out = _mla_attend(x, p, cfg, q_nope, q_rope,
                       codec.load(c_kv, c_sc, x.dtype),
-                      codec.load(k_rope, r_sc, x.dtype), live)
+                      codec.load(k_rope, r_sc, x.dtype), start)
     return out, MLACache(c_kv, k_rope, c_sc, r_sc)
 
 
@@ -787,6 +783,5 @@ def mla_mixed_paged(x: jax.Array, p: dict, cfg: ArchConfig, cache: MLACache,
         kr_g = gather_view(codec, k_rope, r_sc, block_tables,
                            (b_, t_max, m.qk_rope_head_dim), x.dtype, layer)
     with jax.named_scope("attn.core"):   # attn.out inside
-        live = masking.chunk_causal_mask(t_max, start, w)[:, None]
-        out = _mla_attend(x, p, cfg, q_nope, q_rope, ckv_g, kr_g, live)
+        out = _mla_attend(x, p, cfg, q_nope, q_rope, ckv_g, kr_g, start)
     return out, MLACache(c_kv, k_rope, c_sc, r_sc)

@@ -86,6 +86,7 @@ from repro.core.jitutil import strict_jit
 from repro.core.kv_quant import fork_block
 from repro.core.spec import (CHUNKABLE_FAMILIES, ExecutionSpec, MemorySpec,
                              RuntimeSpec)
+from repro.kernels.mla_attention import live_keys
 from repro.kernels.runtime import interpret_default
 from repro.models import backend
 from repro.models.model import Model
@@ -1594,10 +1595,15 @@ class ServingEngine:
                 n_dec = sum(not grants[s] and self._pf[s] >= self._plen[s]
                             for s in occ)
                 width = self.chunk_size if granted else self.spec_horizon
+                t_max = self.max_len if self.paging is None \
+                    else self.blocks_per_slot * self.paging.block_size
                 self._emit("dispatch", -1, program=program, slots=len(occ),
                            prefill_tokens=granted,
                            lanes=self.max_batch * width,
-                           live_lanes=granted + n_dec * self.spec_horizon)
+                           live_lanes=granted + n_dec * self.spec_horizon,
+                           keys=len(occ) * t_max,
+                           live_keys=sum(live_keys(self._idx_ub[s], width,
+                                                   t_max) for s in occ))
             for slot in occ:
                 if grants[slot]:
                     self._pf[slot] += grants[slot]

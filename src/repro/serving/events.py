@@ -46,8 +46,14 @@ Two more kinds belong to no request (``uid == -1``, ``ENGINE_KINDS``):
   W the chunk size for mixed, 1 — or the speculative horizon — for
   decode) and ``live_lanes`` (granted prompt tokens plus W per
   decoding slot; a slot that finished since the last harvest still
-  counts).  It carries the same ``step`` as the ``progress`` events
-  of the harvest that follows it.  An :class:`EngineCluster` relays
+  counts), ``keys`` (occupied slots x the attended view's positions)
+  and ``live_keys`` (over occupied slots, the positions in the key
+  blocks that latent attention's kernel reads: up to the slot's last
+  lane, from the host's upper bound on its index plus W, rounded up to
+  the kernel's key block; an upper bound, since the bound runs ahead of
+  a slot that finished since the last harvest; GQA's gather path reads
+  all ``keys``).  It carries the same ``step`` as the ``progress``
+  events of the harvest that follows it.  An :class:`EngineCluster` relays
   it with ``replica`` added.
 * ``experts``      — MoE models only, one per harvest, after its read:
   what the held experts computed since the previous harvest, summed

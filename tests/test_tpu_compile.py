@@ -12,7 +12,8 @@ W = 16 lane chunk; the matmuls are the FFN and vocabulary projections
 at 128 rows (8 slots x 16 lanes).
 
 The MoE layer's grouped expert matmul compiles at DeepSeek-V3's widths
-(d 7168, expert width 2048, 8 held experts).  The model's decode and
+(d 7168, expert width 2048, 8 held experts), and so does its latent
+attention (128 heads, rank 512, 16 slots x 8192 positions).  The model's decode and
 mixed steps are compiled too, at two layers of
 the benchmark's widths (qwen1.5-0.5b: 32 slots x 2048 positions;
 CodeQwen1.5-7B's 4 KV heads x 128: 16 x 8192) with the pool donated,
@@ -135,6 +136,23 @@ def test_grouped_matmul_compiles(one_chip, rows, k, n):
         functools.partial(grouped_matmul, interpret=False), one_chip,
         ((rows, k), jnp.bfloat16), ((8, k, n), jnp.bfloat16),
         ((8,), jnp.int32))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("lanes", [W, 1])
+def test_mla_attention_compiles(one_chip, lanes):
+    """Latent attention at DeepSeek-V3's widths (128 heads, rank 512,
+    rope 64) over the cell's 16 slots x 8192 positions: a mixed step's
+    16 lanes and a decode step's one."""
+    from repro.kernels.mla_attention import latent_attention
+
+    slots, positions, heads, rank, rope = 16, 8192, 128, 512, 64
+    compiled = _compile(
+        functools.partial(latent_attention, scale=0.135, interpret=False),
+        one_chip, ((slots, lanes, heads, rank), jnp.float32),
+        ((slots, lanes, heads, rope), jnp.bfloat16),
+        ((slots, positions, rank), jnp.bfloat16),
+        ((slots, positions, rope), jnp.bfloat16), ((slots,), jnp.int32))
     assert "tpu_custom_call" in compiled.as_text()
 
 
