@@ -4,10 +4,10 @@ online-softmax loop.
 The ``flash_attention`` kernel tiles a *contiguous* KV sequence; serving
 with a paged cache makes the sequence non-contiguous — a slot's tokens
 live in scattered pool blocks addressed by its block table.  The XLA
-reference path (``models.attention.gqa_decode_paged`` impl="gather")
-first materializes the contiguous view in HBM and then attends; this
-kernel removes that copy by letting the *grid itself* walk the block
-table: the tables are scalar-prefetched (SMEM), and the KV BlockSpec
+reference path (``models.attention.gqa_mixed_paged`` at W == 1,
+impl="gather") first materializes the contiguous view in HBM and then
+attends; this kernel removes that copy by letting the *grid itself* walk
+the block table: the tables are scalar-prefetched (SMEM), and the KV BlockSpec
 index map reads ``table[seq, j]`` to DMA pool block ``j`` of each
 sequence straight into VMEM — the ADAPTOR discipline of computing
 addresses in registers while tiles stream through on-chip memory.

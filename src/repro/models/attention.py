@@ -337,7 +337,9 @@ def gqa_decode(x: jax.Array, p: dict, cfg: ArchConfig, cache: KVCache,
                window: int | None = None,
                grouped: bool = False,
                codec: CacheCodec | None = None) -> tuple[jax.Array, KVCache]:
-    """One-token decode against a [B, S_max, kv, hd] cache.
+    """One-token decode against a [B, S_max, kv, hd] cache: the path of
+    the families that cannot chunk (hybrid's rolling-window layers,
+    enc-dec self-attention); the others decode through ``gqa_mixed``.
 
     ``cache_index`` is the number of tokens already in the cache — a
     scalar, or a [B] vector for per-slot serving (continuous batching).
@@ -375,84 +377,18 @@ def _pool_rows(x: jax.Array, pool: jax.Array) -> jax.Array:
 
 def paged_write_slot(idx_vec: jax.Array, block_tables: jax.Array,
                      block_size: int) -> tuple[jax.Array, jax.Array]:
-    """(physical block, in-block offset) for each slot's next cache write.
+    """(physical block, in-block offset) for each lane's cache write.
 
-    ``idx_vec`` is [B] (one write per slot) or [B, W] (the mixed step's
-    chunk lanes).  An index past the addressable range (cache full, slot
-    finished but not yet harvested, dead chunk lane) is routed to the
-    null block, so the fused step stays safe with zero host intervention.
+    ``idx_vec`` is [B, W], one position per lane of the mixed step.  An
+    index past the addressable range (cache full, dead lane) is routed to
+    the null block, so the fused step stays safe with zero host
+    intervention.
     """
     t_max = block_tables.shape[1] * block_size
     safe = jnp.minimum(idx_vec, t_max - 1)
-    if idx_vec.ndim == 1:
-        blk = jnp.take_along_axis(block_tables, (safe // block_size)[:, None],
-                                  axis=1)[:, 0]
-    else:
-        blk = jnp.take_along_axis(block_tables, safe // block_size, axis=1)
+    blk = jnp.take_along_axis(block_tables, safe // block_size, axis=1)
     blk = jnp.where(idx_vec < t_max, blk, NULL_BLOCK)
     return blk, safe % block_size
-
-
-def gqa_decode_paged(x: jax.Array, p: dict, cfg: ArchConfig, cache: KVCache,
-                     cache_index: jax.Array, block_tables: jax.Array,
-                     layer: jax.Array | int, *,
-                     grouped: bool = False,
-                     impl: str = "gather",
-                     codec: CacheCodec | None = None
-                     ) -> tuple[jax.Array, KVCache]:
-    """One-token decode against layer ``layer`` of the layer-stacked pool
-    [L, NB, bs, ...]: one scatter at (layer, block, offset) writes the
-    new row in place and one gather at (layer, block_tables) reads the
-    slot's view, so no per-layer pool array is formed.
-
-    ``block_tables``: [B, blocks_per_slot] int32 — logical block i of a
-    slot lives in pool row ``block_tables[slot, i]`` (0 = null block).
-    ``impl``: "gather" (XLA gather + the dense contraction, bit-identical
-    to the dense layout) or "pallas" (the fused paged-decode kernel).
-    With an int8 codec the per-(block entry, kv-head) scales ride the
-    same block tables: gathered beside the values on the XLA path, walked
-    by the same scalar-prefetched index maps inside the Pallas kernel.
-    """
-    codec = codec or FLOAT_CODEC
-    b_, one, _ = x.shape
-    kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
-    bs = cache.k.shape[2]
-    idx_vec = as_index_vector(cache_index, b_)
-    with jax.named_scope("attn.qkv"):
-        q, k_new, v_new = gqa_qkv(x, p, cfg, idx_vec[:, None])
-    with jax.named_scope("attn.kv_write"):
-        blk, off = paged_write_slot(idx_vec, block_tables, bs)
-        kq, ks = codec.store(k_new[:, 0], cache.k.dtype)
-        vq, vs = codec.store(v_new[:, 0], cache.v.dtype)
-        k, k_sc = cache_put(cache.k, cache.k_scale, (blk, off),
-                            _pool_rows(kq, cache.k), ks, layer)
-        v, v_sc = cache_put(cache.v, cache.v_scale, (blk, off),
-                            _pool_rows(vq, cache.v), vs, layer)
-    t_max = block_tables.shape[1] * bs
-    if impl == "pallas":
-        from repro.kernels.paged_attention import paged_decode_attention
-        with jax.named_scope("attn.core"):
-            lengths = jnp.minimum(idx_vec + 1, t_max)
-            pool = (*k.shape[1:3], kv, hd)
-            o = paged_decode_attention(
-                q[:, 0], k[layer].reshape(pool), v[layer].reshape(pool),
-                block_tables, lengths,
-                k_scale=layer_view(k_sc, layer),
-                v_scale=layer_view(v_sc, layer),
-                interpret=interpret_default())
-            o = o.reshape(b_, one, cfg.num_heads * hd)
-    else:
-        with jax.named_scope("attn.kv_gather"):
-            kg = gather_view(codec, k, k_sc, block_tables,
-                             (b_, t_max, kv, hd), x.dtype, layer)
-            vg = gather_view(codec, v, v_sc, block_tables,
-                             (b_, t_max, kv, hd), x.dtype, layer)
-        with jax.named_scope("attn.core"):
-            live = jnp.arange(t_max)[None, :] <= idx_vec[:, None]
-            o = _gqa_attend(q, kg, vg, live, cfg, grouped)
-    with jax.named_scope("attn.out"):
-        o = apply_dense(o, p["wo"])
-    return o, KVCache(k, v, k_sc, v_sc)
 
 
 # ---------------------------------------------------------------------------
@@ -469,7 +405,7 @@ def gqa_mixed(x: jax.Array, p: dict, cfg: ArchConfig, cache: KVCache,
     decoding slot uses one, a prefilling slot up to a chunk, an idle slot
     none).  Chunk K/V are written *before* the attend, so one
     causal-vs-cache mask covers intra-chunk causality and the prior
-    cache — the math reduces exactly to ``gqa_decode`` at W == 1, and
+    cache — at W == 1 it is the dense layout's decode step, and
     replaying a prompt chunk-by-chunk reproduces ``gqa_prefill``'s
     logits bit-for-bit below ``BLOCKWISE_THRESHOLD`` (above it bucketed
     prefill switches to the streaming softmax, whose accumulation order
@@ -499,17 +435,22 @@ def gqa_mixed_paged(x: jax.Array, p: dict, cfg: ArchConfig, cache: KVCache,
                     block_tables: jax.Array, layer: jax.Array | int, *,
                     grouped: bool = False,
                     impl: str = "gather",
-                    interpret: bool | None = None,
                     codec: CacheCodec | None = None
                     ) -> tuple[jax.Array, KVCache]:
     """W-lane chunk/decode attention against layer ``layer`` of the
-    layer-stacked block pool (written and gathered in place, as in
-    ``gqa_decode_paged``).
+    layer-stacked pool [L, NB, bs, ...]: one scatter at (layer, block,
+    offset) writes the live lanes' rows in place and one gather at
+    (layer, block_tables) reads each slot's view, so no per-layer pool
+    array is formed.  Decode is this step at W == 1.
 
+    ``block_tables``: [B, blocks_per_slot] int32 — logical block i of a
+    slot lives in pool row ``block_tables[slot, i]`` (0 = null block).
     ``impl="gather"`` materializes the block-table view and reuses the
     dense contraction (bit-identical to ``gqa_mixed``); ``"pallas"``
-    streams pool blocks through the fused chunked-prefill kernel (the
-    int8 codec's scales ride its scalar-prefetched block-table walk).
+    streams pool blocks through a fused kernel picked by the lane width:
+    the paged-decode kernel at W == 1, the chunked-prefill kernel above
+    it (the int8 codec's scales ride either's scalar-prefetched
+    block-table walk).
     """
     codec = codec or FLOAT_CODEC
     b_, w, _ = x.shape
@@ -531,16 +472,20 @@ def gqa_mixed_paged(x: jax.Array, p: dict, cfg: ArchConfig, cache: KVCache,
                             _pool_rows(vq, cache.v), vs, layer)
     if impl == "pallas":
         from repro.kernels.chunked_prefill import chunked_prefill_attention
-        if interpret is None:
-            interpret = interpret_default()
+        from repro.kernels.paged_attention import paged_decode_attention
         with jax.named_scope("attn.core"):
             pool = (*k.shape[1:3], kv, hd)
-            o = chunked_prefill_attention(q, k[layer].reshape(pool),
-                                          v[layer].reshape(pool),
-                                          block_tables, start,
-                                          k_scale=layer_view(k_sc, layer),
-                                          v_scale=layer_view(v_sc, layer),
-                                          interpret=interpret)
+            kp, vp = k[layer].reshape(pool), v[layer].reshape(pool)
+            opts = dict(k_scale=layer_view(k_sc, layer),
+                        v_scale=layer_view(v_sc, layer),
+                        interpret=interpret_default())
+            if w == 1:
+                o = paged_decode_attention(q[:, 0], kp, vp, block_tables,
+                                           jnp.minimum(start + 1, t_max),
+                                           **opts)
+            else:
+                o = chunked_prefill_attention(q, kp, vp, block_tables, start,
+                                              **opts)
             o = o.reshape(b_, w, cfg.num_heads * hd)
     else:
         with jax.named_scope("attn.kv_gather"):
@@ -665,66 +610,6 @@ def _mla_attend(x: jax.Array, p: dict, cfg: ArchConfig, q_nope: jax.Array,
     o = jnp.einsum("bqhl,hld->bqhd", o_lat, wv)
     with jax.named_scope("attn.out"):
         return apply_dense(o.reshape(b_, w, h * m.v_head_dim), p["wo"])
-
-
-def mla_decode(x: jax.Array, p: dict, cfg: ArchConfig, cache: MLACache,
-               cache_index: jax.Array,
-               codec: CacheCodec | None = None) -> tuple[jax.Array, MLACache]:
-    """Absorbed-matmul MLA decode: score and value contraction happen in the
-    latent space, so per-step FLOPs/bytes scale with kv_lora_rank."""
-    codec = codec or FLOAT_CODEC
-    b_, one, _ = x.shape
-    idx_vec = as_index_vector(cache_index, b_)
-    positions = idx_vec[:, None]
-    q_nope, q_rope = _mla_q(x, p, cfg, positions)
-    c_new, kr_new = _mla_latent(x, p, cfg, positions)
-    rows = jnp.arange(b_)
-    cq, cs = codec.store(c_new[:, 0], cache.c_kv.dtype)
-    rq, rs = codec.store(kr_new[:, 0], cache.k_rope.dtype)
-    c_kv, c_sc = cache_put(cache.c_kv, cache.c_scale, (rows, idx_vec),
-                            cq, cs)
-    k_rope, r_sc = cache_put(cache.k_rope, cache.r_scale, (rows, idx_vec),
-                              rq, rs)
-    out = _mla_attend(x, p, cfg, q_nope, q_rope,
-                      codec.load(c_kv, c_sc, x.dtype),
-                      codec.load(k_rope, r_sc, x.dtype), idx_vec)
-    return out, MLACache(c_kv, k_rope, c_sc, r_sc)
-
-
-def mla_decode_paged(x: jax.Array, p: dict, cfg: ArchConfig, cache: MLACache,
-                     cache_index: jax.Array, block_tables: jax.Array,
-                     layer: jax.Array | int,
-                     codec: CacheCodec | None = None
-                     ) -> tuple[jax.Array, MLACache]:
-    """MLA decode against layer ``layer`` of the pooled latent blocks
-    ([L, NB, bs, rank] c_kv and [L, NB, bs, rope_dim] k_rope addressed
-    through the same block tables, in place)."""
-    codec = codec or FLOAT_CODEC
-    m = cfg.mla
-    b_, one, _ = x.shape
-    bs = cache.c_kv.shape[2]
-    idx_vec = as_index_vector(cache_index, b_)
-    positions = idx_vec[:, None]
-    with jax.named_scope("attn.qkv"):
-        q_nope, q_rope = _mla_q(x, p, cfg, positions)
-        c_new, kr_new = _mla_latent(x, p, cfg, positions)
-    with jax.named_scope("attn.kv_write"):
-        blk, off = paged_write_slot(idx_vec, block_tables, bs)
-        cq, cs = codec.store(c_new[:, 0], cache.c_kv.dtype)
-        rq, rs = codec.store(kr_new[:, 0], cache.k_rope.dtype)
-        c_kv, c_sc = cache_put(cache.c_kv, cache.c_scale, (blk, off),
-                               cq, cs, layer)
-        k_rope, r_sc = cache_put(cache.k_rope, cache.r_scale, (blk, off),
-                                 rq, rs, layer)
-    t_max = block_tables.shape[1] * bs
-    with jax.named_scope("attn.kv_gather"):
-        ckv_g = gather_view(codec, c_kv, c_sc, block_tables,
-                            (b_, t_max, m.kv_lora_rank), x.dtype, layer)
-        kr_g = gather_view(codec, k_rope, r_sc, block_tables,
-                           (b_, t_max, m.qk_rope_head_dim), x.dtype, layer)
-    with jax.named_scope("attn.core"):   # attn.out inside
-        out = _mla_attend(x, p, cfg, q_nope, q_rope, ckv_g, kr_g, idx_vec)
-    return out, MLACache(c_kv, k_rope, c_sc, r_sc)
 
 
 def mla_mixed(x: jax.Array, p: dict, cfg: ArchConfig, cache: MLACache,

@@ -74,11 +74,11 @@ class ModelOptions:
     # int8_matmul path).  Applied at trace time, so jitted callers bake
     # the choice into their compiled executable.
     matmul_backend: str = "xla"
-    # Paged decode attention: "gather" (XLA block-table gather + the dense
-    # contraction, bit-identical to the dense layout) or "pallas" (the
-    # fused paged-decode kernel with the gather folded into the
-    # flash-decode loop).  Only consulted when decode_step receives
-    # block tables.
+    # Paged attention: "gather" (XLA block-table gather + the dense
+    # contraction, bit-identical to the dense layout) or "pallas" (fused
+    # kernels with the gather folded into the online-softmax loop: the
+    # paged-decode kernel at one lane a slot, the chunked-prefill kernel
+    # above it).  Only consulted when a step receives block tables.
     paged_attn_impl: str = "gather"
     # KV-cache storage codec: "compute" (bf16 values, historical) or
     # "int8" (quantize-on-write with per-row f32 scales; see
@@ -777,14 +777,21 @@ class Model:
         ``block_tables``: [B, blocks_per_slot] int32 selects the paged
         cache layout (``cache`` must then be the pooled block layout from
         ``init_cache(..., paging=...)``); None keeps the dense layout.
-        ``live`` ([B] bool, default all): the slots MoE layers route.
-        ``expert_counts``: also return the held experts' int32 [rows,
-        experts hit] (``_run_cache_layers``) as a third output."""
+        ``live`` ([B] bool, default all): the slots that write their K/V
+        row and that MoE layers route; a slot that is not live writes
+        nothing.  ``expert_counts``: also return the held experts' int32
+        [rows, experts hit] (``_run_cache_layers``) as a third output.
+
+        The attention-cache families decode through ``mixed_step`` at
+        one lane; the others keep their sequential state steps here."""
         cfg = self.cfg
-        n_ex = None
-        live = None if live is None else live[:, None]
-        if block_tables is not None and cfg.family not in ("dense", "vlm",
-                                                           "moe"):
+        if cfg.family in CHUNKABLE_FAMILIES:
+            n_live = jnp.ones(tokens.shape[:1], jnp.int32) if live is None \
+                else live.astype(jnp.int32)
+            return self.mixed_step(params, cache, tokens, start=cache_index,
+                                   n_live=n_live, block_tables=block_tables,
+                                   expert_counts=expert_counts)
+        if block_tables is not None:
             raise ValueError(
                 f"paged decode unsupported for family {cfg.family!r}")
         idx_vec = attn.as_index_vector(cache_index, tokens.shape[0])
@@ -800,16 +807,6 @@ class Model:
                 out, st2 = ssm.ssm_decode(hn, lp["ssm"], cfg, st)
                 return h + out, st2
             x, new_cache = self._run_stack_cache(body, x, params["layers"], cache)
-        elif cfg.mla is not None:
-            def attend(hn, ap, c, layer):
-                if block_tables is not None:
-                    return attn.mla_decode_paged(hn, ap, cfg, c, cache_index,
-                                                 block_tables, layer,
-                                                 codec=self.codec)
-                return attn.mla_decode(hn, ap, cfg, c, cache_index,
-                                       codec=self.codec)
-            x, new_cache, n_ex = self._run_cache_layers(
-                attend, x, params, cache, block_tables is not None, live)
         elif cfg.family == "hybrid":
             new_cache = []
             for lp, kind, st in zip(params["layers"], self._hybrid_kinds(), cache):
@@ -824,7 +821,7 @@ class Model:
                 hn = layers.apply_norm(x, lp["ln2"], cfg.norm)
                 x = x + moe.apply_ffn(hn, lp["ffn"], cfg.activation)
                 new_cache.append(st2)
-        elif cfg.encdec is not None:
+        else:   # enc-dec: self-attention cache beside the cross K/V
             def body(h, inp):
                 lp, (c_self, c_cross) = inp
                 hn = layers.apply_norm(h, lp["ln1"], cfg.norm)
@@ -839,20 +836,8 @@ class Model:
             x, new_cache = self._run_stack_cache(
                 body, x, params["layers"], (cache["self"], cache["cross"]))
             new_cache = {"self": new_cache[0], "cross": new_cache[1]}
-        else:
-            def attend(hn, ap, c, layer):
-                if block_tables is not None:
-                    return attn.gqa_decode_paged(
-                        hn, ap, cfg, c, cache_index, block_tables, layer,
-                        grouped=self.opt.grouped_gqa,
-                        impl=self.opt.paged_attn_impl, codec=self.codec)
-                return attn.gqa_decode(hn, ap, cfg, c, cache_index,
-                                       grouped=self.opt.grouped_gqa,
-                                       codec=self.codec)
-            x, new_cache, n_ex = self._run_cache_layers(
-                attend, x, params, cache, block_tables is not None, live)
         out = self._unembed(params, x), new_cache
-        return out + (n_ex,) if expert_counts else out
+        return out + (None,) if expert_counts else out
 
     @_with_backend
     # jit-region

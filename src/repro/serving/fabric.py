@@ -417,12 +417,11 @@ class DecodeFabric:
 
     def _attend(self, q: jax.Array, k: jax.Array, v: jax.Array,
                 live: jax.Array) -> jax.Array:
-        """Scores over live cache positions only: ``live`` is [B, S_kv],
-        or [B, W, S_kv] per-lane masks (the chunked mixed step)."""
+        """Scores over live cache positions only: ``live`` is the mixed
+        step's [B, W, S_kv] per-lane masks."""
         s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) \
             / jnp.sqrt(jnp.float32(self.hd))
-        m = live[:, None, None, :] if live.ndim == 2 else live[:, None]
-        s = jnp.where(m, s, masking.NEG_INF)
+        s = jnp.where(live[:, None], s, masking.NEG_INF)
         p = jax.nn.softmax(s, axis=-1)
         return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
 
@@ -517,71 +516,12 @@ class DecodeFabric:
                     interpret: bool) -> tuple[jax.Array, KVCache]:
         """tokens [B, 1] + per-slot registers topo [B, N_REGS] -> (masked
         logits [B, 1, V_max], new cache).  One topology per slot; register
-        values are data, so this traces exactly once."""
-        mx = self.mx
-        B = tokens.shape[0]
-        mid, h_live = topo[:, REG_MODEL], topo[:, REG_HEADS]
-        l_live, d_live = topo[:, REG_LAYERS], topo[:, REG_DMODEL]
-        f_live, v_live = topo[:, REG_DFF], topo[:, REG_VOCAB]
-        idx = jnp.asarray(index, jnp.int32)
-        emb = self._embed_rows(table, mid, tokens[:, 0])
-        x = (emb * masking.slot_mask(mx.d_model_max, d_live, emb.dtype)
-             )[:, None]
-        positions = idx[:, None]
-        he = masking.slot_mask(mx.heads_max, h_live)[:, None, :, None] \
-            .astype(self.compute_dtype)
-        dm = masking.slot_mask(mx.d_model_max, d_live)[:, None] \
-            .astype(self.compute_dtype)
-        if block_tables is not None:
-            bs = cache.k.shape[2]
-            t_max = block_tables.shape[1] * bs
-            blk, off = paged_write_slot(idx, block_tables, bs)
-            live = jnp.arange(t_max)[None, :] <= idx[:, None]
-        else:
-            rows = jnp.arange(B)
-            live = jnp.arange(cache.k.shape[2])[None, :] <= idx[:, None]
-
-        def body(h, inp):
-            i, c = inp
-            lp = self._gather_layer(table, mid, i)
-            xn = self._norm(h, lp["ln1"], d_live)
-            q, k_new, v_new = self._qkv(xn, lp, positions, he)
-            kq, ksc = self.codec.store(k_new[:, 0], c.k.dtype)
-            vq, vsc = self.codec.store(v_new[:, 0], c.v.dtype)
-            if block_tables is not None:
-                k, k_sc = cache_put(c.k, c.k_scale, (blk, off), kq, ksc)
-                v, v_sc = cache_put(c.v, c.v_scale, (blk, off), vq, vsc)
-                if paged_attn_impl == "pallas":
-                    from repro.kernels.paged_attention import \
-                        paged_decode_attention
-                    lengths = jnp.minimum(idx + 1, t_max)
-                    o = paged_decode_attention(
-                        q[:, 0], k, v, block_tables, lengths,
-                        live_kv=h_live, k_scale=k_sc, v_scale=v_sc,
-                        interpret=interpret)[:, None]
-                else:
-                    shp = (B, t_max, mx.heads_max, self.hd)
-                    kg = gather_view(self.codec, k, k_sc, block_tables,
-                                     shp, q.dtype)
-                    vg = gather_view(self.codec, v, v_sc, block_tables,
-                                     shp, q.dtype)
-                    o = self._attend(q, kg, vg, live)
-            else:
-                k, k_sc = cache_put(c.k, c.k_scale, (rows, idx), kq, ksc)
-                v, v_sc = cache_put(c.v, c.v_scale, (rows, idx), vq, vsc)
-                o = self._attend(q, self.codec.load(k, k_sc, q.dtype),
-                                 self.codec.load(v, v_sc, q.dtype), live)
-            a = self._mm((o * he).reshape(B, 1, -1), lp["wo"]) * dm
-            h1 = h + a
-            f = self._ffn(self._norm(h1, lp["ln2"], d_live), lp,
-                          f_live) * dm
-            h2 = h1 + f
-            out = jnp.where((i < l_live)[:, None, None], h2, h)
-            return out, KVCache(k, v, k_sc, v_sc)
-
-        x, new_cache = jax.lax.scan(
-            body, x, (jnp.arange(mx.layers_enc_max), cache))
-        return self._unembed(x, table, mid, d_live, v_live), new_cache
+        values are data, so this traces exactly once.  ``mixed_step`` at
+        one lane, live in every slot."""
+        return self.mixed_step(table, cache, tokens, index,
+                               jnp.ones(tokens.shape[:1], jnp.int32), topo,
+                               block_tables, paged_attn_impl,
+                               interpret=interpret)
 
     # ------------------------------------------------------------------
     # Fused mixed chunk/decode step (chunked prefill on the fabric)
@@ -595,7 +535,7 @@ class DecodeFabric:
         """tokens [B, W] + per-slot registers topo [B, N_REGS] -> (masked
         logits [B, W, V_max], new cache).
 
-        The W-lane generalization of ``decode_step``: lane ``l`` of slot
+        ``decode_step`` is this step at W == 1.  Lane ``l`` of slot
         ``b`` sits at cache position ``start[b] + l`` and only the first
         ``n_live[b]`` lanes are real — a decoding slot uses one lane, a
         prefilling slot a chunk of its prompt, an idle slot none.  Chunk
@@ -651,7 +591,15 @@ class DecodeFabric:
             if block_tables is not None:
                 k, k_sc = cache_put(c.k, c.k_scale, (blk, off), kq, ksc)
                 v, v_sc = cache_put(c.v, c.v_scale, (blk, off), vq, vsc)
-                if paged_attn_impl == "pallas":
+                if paged_attn_impl == "pallas" and W == 1:
+                    from repro.kernels.paged_attention import \
+                        paged_decode_attention
+                    o = paged_decode_attention(
+                        q[:, 0], k, v, block_tables,
+                        jnp.minimum(start + 1, t_max), live_kv=h_live,
+                        k_scale=k_sc, v_scale=v_sc,
+                        interpret=interpret)[:, None]
+                elif paged_attn_impl == "pallas":
                     from repro.kernels.chunked_prefill import \
                         chunked_prefill_attention
                     o = chunked_prefill_attention(
